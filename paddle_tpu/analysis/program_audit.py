@@ -68,11 +68,16 @@ HOST_CALLBACK_PRIMITIVES = frozenset({
     "outside_call",
 })
 
-# Cross-device communication primitives (jaxpr-level collective census).
+# Cross-device communication primitives (jaxpr-level collective census):
+# the names jax 0.9.0's ``jax._src.lax.parallel`` binds.  ``psum`` under
+# ``shard_map``'s default ``check_vma=True`` traces as ``psum_invariant``;
+# ``pmean`` is psum + div and ``psum_scatter`` is ``reduce_scatter``.
 COLLECTIVE_PRIMITIVES = frozenset({
-    "psum", "psum2", "pmax", "pmin", "pmean", "ppermute", "pbroadcast",
-    "all_gather", "all_to_all", "reduce_scatter", "psum_scatter",
-    "pgather",
+    "psum", "psum_invariant", "unreduced_psum", "pmax", "pmin",
+    "ppermute", "pbroadcast", "psend", "precv", "pgather",
+    "all_gather", "all_gather_invariant", "all_gather_reduced",
+    "all_to_all", "ragged_all_to_all", "reduce_scatter",
+    "unreduced_reduce_scatter",
 })
 
 # HLO op names GSPMD may insert for sharded programs (the compiled-module

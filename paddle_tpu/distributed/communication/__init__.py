@@ -112,7 +112,6 @@ def all_reduce(tensor, op=ReduceOp.SUM, group=None, sync_op=True):
     mesh = get_mesh()
     axes = group.axis_names if group is not None and group.axis_names else None
     if mesh is not None and axes:
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         def body(x):
@@ -126,8 +125,8 @@ def all_reduce(tensor, op=ReduceOp.SUM, group=None, sync_op=True):
             if op == ReduceOp.MIN:
                 return jax.lax.pmin(x, axes)
             raise ValueError(op)
-        sm = shard_map(body, mesh=mesh, in_specs=P(), out_specs=P(),
-                       check_rep=False)
+        sm = jax.shard_map(body, mesh=mesh, in_specs=P(), out_specs=P(),
+                           check_vma=False)
         tensor._data = sm(tensor._data)
         return _Task(tensor._data)
     # multihost replicated eager allreduce over the group members

@@ -21,7 +21,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ..core.state import STATE
 from ..core.tensor import Tensor
 from ..jit import (bind_layer_state, bind_optimizer_state, layer_state,
-                   optimizer_state)
+                   optimizer_state, param_positions)
 from .env import data_axes, get_mesh
 
 
@@ -57,21 +57,24 @@ class DistributedTrainStep:
         for ZeRO stage 1/2 (params replicated, state sharded: reference
         dygraph_sharding_optimizer.py:44), the param's ``_opt_state_spec``
         recorded by apply_fsdp_annotations(stage<=2)."""
-        by_id = {}
+        pos = param_positions(self.optimizer)   # the traced state's keys
+        by_pos = {}
         for k, p in self.model.named_parameters():
+            if id(p) not in pos:
+                continue
             oss = getattr(p, "_opt_state_spec", None)
-            by_id[id(p)] = (NamedSharding(self.mesh, oss) if oss is not None
-                            else param_shardings[k])
+            by_pos[pos[id(p)]] = (NamedSharding(self.mesh, oss)
+                                  if oss is not None else param_shardings[k])
         acc = {}
         for name, store in opt_state["acc"].items():
             acc[name] = {}
-            for pid, v in store.items():
-                if pid in by_id and hasattr(v, "ndim") and v.ndim > 0:
-                    acc[name][pid] = by_id[pid]
+            for i, v in store.items():
+                if i in by_pos and hasattr(v, "ndim") and v.ndim > 0:
+                    acc[name][i] = by_pos[i]
                 else:
-                    acc[name][pid] = NamedSharding(self.mesh, P())
-        master = {pid: by_id.get(pid, NamedSharding(self.mesh, P()))
-                  for pid in opt_state["master"]}
+                    acc[name][i] = NamedSharding(self.mesh, P())
+        master = {i: by_pos.get(i, NamedSharding(self.mesh, P()))
+                  for i in opt_state["master"]}
         return {"acc": acc, "master": master}
 
     def _data_sharding(self, x):

@@ -26,6 +26,11 @@ _HYBRID_DEGREES = {"pp": 1, "dp": 1, "sharding": 1, "sep": 1, "mp": 1}
 
 AXIS_ORDER = ("pp", "dp", "sharding", "sep", "mp")
 
+#: mesh axis names a batch dimension shards over, wherever a mesh is
+#: handed in directly rather than built here (``CompiledTrainStep(mesh=)``'s
+#: default ``batch_axes``; the flash kernel's per-shard wrap)
+DATA_AXES = ("dp", "sharding", "batch", "data")
+
 
 _INITIALIZED = [False]
 

@@ -2,6 +2,11 @@
 
 Usage:
     python -m paddle_tpu.distributed.launch --nproc_per_node=N train.py args
+
+On a TPU host ONE process owns every local chip (a second process that
+needs them fails or hangs), so ``--nproc_per_node`` stays 1 there and the
+chips are driven as one mesh inside that process — see
+``chip_smoke.py --chips 4``.  ``N > 1`` is for CPU hosts.
 """
 
 from __future__ import annotations

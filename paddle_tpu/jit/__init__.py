@@ -17,6 +17,7 @@ CINN's role.  Guards/retrace are keyed by jax's abstract signature
 
 from __future__ import annotations
 
+import contextlib
 import functools
 
 import jax
@@ -78,20 +79,42 @@ def bind_layer_state(layer, params, buffers):
             b._data = buffers[k]
 
 
+def param_positions(opt):
+    """``{id(param): position in the optimizer's parameter list}``.
+
+    The optimizer keys its accumulator stores by ``id(p)``, and an address
+    differs in every process.  Carried through ``jit`` as dict keys it
+    would order the state pytree — hence the program's parameters, its HLO
+    and its persistent-compile-cache key — differently on every run, so a
+    train step would never be found in the cache again.  The traced state
+    is therefore keyed by POSITION (:func:`optimizer_state` /
+    :func:`bind_optimizer_state` translate at the boundary)."""
+    return {id(p): i for i, p in enumerate(opt._parameter_list or [])
+            if p is not None}
+
+
 def optimizer_state(opt):
+    """The optimizer's accumulators / master weights as a jit-able pytree,
+    keyed by parameter position (see :func:`param_positions`)."""
     if STATE.tracing_depth == 0:
         _counters.inc("jit.host.optimizer_state")
-    accs = {name: dict(store) for name, store in opt._accumulators.items()}
-    masters = dict(opt._master_weights)
+    pos = param_positions(opt)
+    accs = {name: {pos[pid]: v for pid, v in store.items()}
+            for name, store in opt._accumulators.items()}
+    masters = {pos[pid]: v for pid, v in opt._master_weights.items()}
     return {"acc": accs, "master": masters}
 
 
 def bind_optimizer_state(opt, state):
+    """Inverse of :func:`optimizer_state`: position keys back to the
+    ``id(p)`` keys the optimizer's update rules look up."""
     if STATE.tracing_depth == 0:
         _counters.inc("jit.host.bind_optimizer_state")
-    opt._accumulators = {name: dict(store)
+    params = opt._parameter_list
+    opt._accumulators = {name: {id(params[i]): v for i, v in store.items()}
                          for name, store in state["acc"].items()}
-    opt._master_weights = dict(state["master"])
+    opt._master_weights = {id(params[i]): v
+                           for i, v in state["master"].items()}
 
 
 class StaticFunction:
@@ -401,14 +424,15 @@ class CompiledTrainStep:
         """Resolve one PartitionSpec per carry leaf.  Precedence per
         parameter/buffer name: ``shard_rules`` regex > ``annotate_param``
         placements > replicated; optimizer accumulators and master weights
-        inherit their parameter's spec (matched by ``id``, the accumulator
-        store key)."""
+        inherit their parameter's spec (matched by position in the
+        optimizer's parameter list, the traced state's key)."""
         from ..distributed.sharding_utils import (infer_partition_specs,
                                                   validate_spec)
         mesh = self.mesh
         self._rep = NamedSharding(mesh, _P())
         if batch_axes is None:
-            batch_axes = tuple(a for a in ("dp", "sharding", "batch", "data")
+            from ..distributed.env import DATA_AXES
+            batch_axes = tuple(a for a in DATA_AXES
                                if a in mesh.shape and mesh.shape[a] > 1)
         elif isinstance(batch_axes, str):
             batch_axes = (batch_axes,)
@@ -426,7 +450,8 @@ class CompiledTrainStep:
         flat.update({k: b._data for k, b in named_b})
         ruled = infer_partition_specs(flat, mesh, shard_rules or (),
                                       default=None)
-        self._param_specs, self._buffer_specs, self._byid = {}, {}, {}
+        self._param_specs, self._buffer_specs, self._bypos = {}, {}, {}
+        pos = param_positions(self.optimizer)
         for k, p in named_p:
             spec = ruled[k]
             if spec is None:
@@ -434,7 +459,8 @@ class CompiledTrainStep:
                 spec = validate_spec(placed, p._data.shape, mesh, name=k,
                                      quiet=placed is None)
             self._param_specs[k] = spec
-            self._byid[id(p)] = spec
+            if id(p) in pos:
+                self._bypos[pos[id(p)]] = spec
         for k, b in named_b:
             spec = ruled[k]
             if spec is None:
@@ -465,11 +491,11 @@ class CompiledTrainStep:
         new_buffers = {k: self._pin(v, self._buffer_specs.get(k))
                        for k, v in new_buffers.items()}
         new_opt = {
-            "acc": {an: {pid: self._pin(v, self._byid.get(pid))
-                         for pid, v in store.items()}
+            "acc": {an: {i: self._pin(v, self._bypos.get(i))
+                         for i, v in store.items()}
                     for an, store in new_opt["acc"].items()},
-            "master": {pid: self._pin(v, self._byid.get(pid))
-                       for pid, v in new_opt["master"].items()}}
+            "master": {i: self._pin(v, self._bypos.get(i))
+                       for i, v in new_opt["master"].items()}}
         return new_params, new_buffers, new_opt
 
     def _constrain_batch(self, args):
@@ -514,11 +540,11 @@ class CompiledTrainStep:
         buffers = {k: self._mesh_put(v, self._buffer_specs.get(k))
                    for k, v in buffers.items()}
         opt_state = {
-            "acc": {an: {pid: self._mesh_put(v, self._byid.get(pid))
-                         for pid, v in store.items()}
+            "acc": {an: {i: self._mesh_put(v, self._bypos.get(i))
+                         for i, v in store.items()}
                     for an, store in opt_state["acc"].items()},
-            "master": {pid: self._mesh_put(v, self._byid.get(pid))
-                       for pid, v in opt_state["master"].items()}}
+            "master": {i: self._mesh_put(v, self._bypos.get(i))
+                       for i, v in opt_state["master"].items()}}
         sstate = jax.tree_util.tree_map(
             lambda v: self._mesh_put(v, None), sstate)
         key = jax.device_put(key, self._rep)
@@ -821,8 +847,16 @@ class CompiledTrainStep:
         return jax.jit(window_fn,
                        donate_argnums=donate + ((7,) if donate else ()))
 
+    def _mesh_scope(self):
+        """Ambient-mesh context for one call (trace, audit and dispatch
+        alike): what GSPMD cannot partition — a Mosaic kernel — reads the
+        mesh from here at trace time and wraps itself in ``shard_map``
+        (``kernels.flash_attention``)."""
+        return (jax.set_mesh(self.mesh) if self.mesh is not None
+                else contextlib.nullcontext())
+
     def __call__(self, *args):
-        with _trace.span("jit.step"):
+        with _trace.span("jit.step"), self._mesh_scope():
             from ..io import Window
             if len(args) == 1 and isinstance(args[0], Window):
                 return self._call_window(tuple(args[0]), args[0].k)
@@ -1195,7 +1229,6 @@ class CompiledTrainStep:
                 f"{shown}{ctx}")
 
 
-import contextlib
 
 
 @contextlib.contextmanager

@@ -3,7 +3,7 @@
 Two things every kernel in this package needs and each used to hand-roll:
 
 * **Block-shape preflight** — Mosaic reports an illegal BlockSpec as an
-  opaque lowering error deep inside XLA (BENCH_r01 died on one).  The
+  opaque lowering error deep inside XLA (the round-1 bench died on one).  The
   validators here run *before* ``pallas_call`` and raise a ``ValueError``
   that names the offending dimension, the kernel, and the constraint, so
   a bad configuration fails at the call site in plain English.

@@ -32,14 +32,16 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from .flash_attention import _INTERPRET, _on_tpu, reference_attention
+from ..device import on_tpu
+from ..profiler import counters
+from .flash_attention import _INTERPRET
 from ._shapes import NEG_INF, check_divides, check_equal
 
 
 def _chunk_attention(q, k, v, causal, scale):
     """(out, lse) for one q-chunk x kv-chunk pair, [B, S, H, D] layout.
     lse is [B, S, H] (fp32)."""
-    if (_on_tpu() or _INTERPRET[0]) and q.shape[1] % 128 == 0 \
+    if (on_tpu() or _INTERPRET[0]) and q.shape[1] % 128 == 0 \
             and k.shape[1] % 128 == 0:
         from .flash_attention import flash_attention_with_lse
         qt = jnp.swapaxes(q, 1, 2)
@@ -47,7 +49,9 @@ def _chunk_attention(q, k, v, causal, scale):
         vt = jnp.swapaxes(v, 1, 2)
         out, lse = flash_attention_with_lse(qt, kt, vt, causal, scale)
         return jnp.swapaxes(out, 1, 2), jnp.swapaxes(lse, 1, 2)
-    # jnp fallback (CPU tests / odd chunk sizes)
+    # jnp fallback (CPU tests / odd chunk sizes), counted like the flash
+    # entry point's so a chip run can assert it never happened
+    counters.inc("kernels.flash.reference_calls")  # trace-time only
     logits = jnp.einsum("bshd,bthd->bhst", q.astype(jnp.float32) * scale,
                         k.astype(jnp.float32))
     if causal:

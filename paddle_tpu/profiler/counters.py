@@ -134,6 +134,9 @@ Well-known names (see README "Observability" for the full table):
   kernels.paged.pallas_programs / kernels.paged.xla_fallbacks
       (trace-time: paged decode programs compiled with the fused Pallas
       backend vs the plain-XLA gather twin; 0 in steady state)
+  kernels.flash.reference_calls (trace-time: flash/ring attention calls
+      that took the jnp reference instead of the Pallas kernel — off-TPU,
+      or a sequence that does not tile by 128; chip_smoke.py asserts 0)
   resilience.saves / resilience.save_ms / resilience.restores
   resilience.resharded_restores (restores onto a different mesh shape)
   resilience.retries / resilience.corrupt_detected

@@ -228,19 +228,19 @@ class CheckpointManager:
             # gather every leaf to one host ndarray — the opposite of a
             # per-shard save); keys stay positional "p<i>" exactly like the
             # host path below, so restore is layout-agnostic
-            pos = {id(p): f"p{i}"
-                   for i, p in enumerate(opt._parameter_list or [])}
-            byid = getattr(train_step, "_byid", {})
+            # (the carry is keyed by parameter position already:
+            # jit.optimizer_state)
+            bypos = getattr(train_step, "_bypos", {})
             dev_opt = train_step._state[2]
             for accname, store in dev_opt["acc"].items():
-                for pid, v in store.items():
-                    key = f"opt/acc/{accname}/{pos.get(pid, str(pid))}"
+                for i, v in store.items():
+                    key = f"opt/acc/{accname}/p{i}"
                     arrays[key] = _capture(v)
-                    specs[key] = _spec_json(byid.get(pid))
-            for pid, v in dev_opt["master"].items():
-                key = f"opt/master/{pos.get(pid, str(pid))}"
+                    specs[key] = _spec_json(bypos.get(i))
+            for i, v in dev_opt["master"].items():
+                key = f"opt/master/p{i}"
                 arrays[key] = _capture(v)
-                specs[key] = _spec_json(byid.get(pid))
+                specs[key] = _spec_json(bypos.get(i))
             lr = opt._learning_rate
             opt_step = int(opt._step_count)
             lr_sd = lr.state_dict() if hasattr(lr, "state_dict") else None

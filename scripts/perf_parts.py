@@ -154,5 +154,7 @@ def pure_jax():
 
 
 if __name__ == "__main__":
+    from paddle_tpu.core import compile_cache
+    compile_cache.enable()
     pure_jax()
     framework()

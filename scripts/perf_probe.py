@@ -51,6 +51,8 @@ def run(batch, seq, fused_loss, iters=20, recompute=False):
 
 
 if __name__ == "__main__":
+    from paddle_tpu.core import compile_cache
+    compile_cache.enable()
     for b, fused, rc in [(8, True, False), (16, True, True), (32, True, True)]:
         try:
             run(b, 1024, fused, recompute=rc)

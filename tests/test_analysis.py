@@ -299,21 +299,24 @@ class TestAuditorFixtures:
         assert any(f.rule == "f64-promotion" for f in rep.findings)
 
     def test_collective_census_caught(self):
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
         mesh = Mesh(np.array(jax.devices()[:2]), ("i",))
-        fn = jax.jit(shard_map(lambda x: jax.lax.psum(x, "i"), mesh=mesh,
-                               in_specs=P("i"), out_specs=P()))
+        # default check_vma=True: jax 0.9.0 traces this psum as
+        # `psum_invariant` — the name the auditor's census must know
+        fn = jax.jit(jax.shard_map(lambda x: jax.lax.psum(x, "i"),
+                                   mesh=mesh, in_specs=P("i"),
+                                   out_specs=P()))
         rep = paudit.audit_program("t.coll", fn, jnp.ones((2,)),
                                    expect_no_collectives=True,
                                    compile_program=False)
         assert any(f.rule == "collective-budget" for f in rep.findings)
-        assert rep.collective_counts.get("psum2", 0) >= 1
+        assert rep.collective_counts.get("psum_invariant", 0) >= 1
         # mesh programs with collectives *allowed* report the census only
         rep2 = paudit.audit_program("t.coll.ok", fn, jnp.ones((2,)),
                                     expect_no_collectives=False,
                                     compile_program=False)
-        assert rep2.ok and rep2.collective_counts.get("psum2", 0) >= 1
+        assert rep2.ok and rep2.collective_counts.get(
+            "psum_invariant", 0) >= 1
 
     def test_hbm_budget_caught(self):
         fn = jax.jit(lambda x: x @ x)

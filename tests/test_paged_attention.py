@@ -233,13 +233,18 @@ class TestKernelMode:
         assert flag("FLAGS_paged_kernel") == "off"
         assert pa.kernel_mode() == "off"
 
-    def test_pallas_falls_back_off_tpu(self):
+    def test_pallas_raises_off_tpu(self):
         set_flags({"FLAGS_paged_kernel": "pallas"})
         try:
-            if pa._on_tpu():
+            if pa.on_tpu():
                 assert pa.kernel_mode() == "pallas"
             else:
-                assert pa.kernel_mode() == "off"     # no TPU, no interpret
+                # no TPU, no interpret: a requested backend that cannot
+                # run is an error, never a quiet switch to the XLA twin
+                with pytest.raises(RuntimeError, match="needs a TPU"):
+                    pa.kernel_mode()
+                with pytest.raises(RuntimeError, match="needs a TPU"):
+                    _paged(_model())
                 pa._INTERPRET[0] = True
                 assert pa.kernel_mode() == "pallas"  # tests force interpret
         finally:
