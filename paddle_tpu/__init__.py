@@ -16,7 +16,11 @@ Architecture (vs SURVEY.md layer map):
 
 from __future__ import annotations
 
-import warnings as _warnings
+import time as _time
+
+_IMPORT_T0_NS = _time.perf_counter_ns()  # the ``setup.import`` span begins
+
+import warnings as _warnings  # noqa: E402
 
 _warnings.filterwarnings(
     "ignore", message="Explicitly requested dtype.*truncated")
@@ -115,3 +119,13 @@ _late_bind()
 
 # paddle compat alias for scaler
 from .amp import GradScaler  # noqa: F401,E402
+
+# last: the compile layer starts to listen (jax.monitoring), and the import
+# that ends here is kept as a lifecycle span of the process, with the first
+# touch of the device inside it (the backend's start, where it falls here)
+from .core import compile_cache as _compile_cache  # noqa: F401,E402
+from .profiler import host_tracer as _host_tracer  # noqa: E402
+from .tensor import random as _random  # noqa: E402
+_host_tracer.lifecycle_since("setup.first_device_touch",
+                             *_random.FIRST_TOUCH_NS)
+_host_tracer.lifecycle_since("setup.import", _IMPORT_T0_NS)

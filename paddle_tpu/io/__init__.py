@@ -547,15 +547,14 @@ class DevicePrefetcher:
         if self.start_offset:
             # replay-to-offset: drain skipped batches host-side only — no
             # device_put, no staging, just advancing the loader cursor
-            with _trace.span("io.skip_replay"):
-                skipped = 0
-                for _ in range(self.start_offset):
-                    try:
-                        next(it)
-                    except StopIteration:
-                        break
-                    skipped += 1
-                _counters.inc("io.skipped_batches", skipped)
+            skipped = 0
+            for _ in range(self.start_offset):
+                try:
+                    next(it)
+                except StopIteration:
+                    break
+                skipped += 1
+            _counters.inc("io.skipped_batches", skipped)
         while True:
             with _trace.span("io.prefetcher"):
                 t0 = _time.perf_counter_ns()

@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import contextlib
 import functools
+import time
 
 import jax
 import jax.numpy as jnp
@@ -856,6 +857,7 @@ class CompiledTrainStep:
                 else contextlib.nullcontext())
 
     def __call__(self, *args):
+        self._call_t0_ns = time.perf_counter_ns()
         with _trace.span("jit.step"), self._mesh_scope():
             from ..io import Window
             if len(args) == 1 and isinstance(args[0], Window):
@@ -865,6 +867,20 @@ class CompiledTrainStep:
                 # axis = window length on every array leaf)
                 return self._call_window(args, None)
             return self._call_impl(args)
+
+    def _note_dispatch(self, traces_before):
+        """Count the dispatch that just returned as ``jit.cache_hits`` or,
+        if the step body was traced for it, ``jit.cache_misses``; such a
+        call is the first of a traced variant, kept from its start
+        (hydrate, trace, compile or load, first dispatch) as the lifecycle
+        span ``jit.first_call``."""
+        traced = _counters.get("jit.traces") - traces_before
+        if not traced:
+            _counters.inc("jit.cache_hits")
+            return
+        _counters.inc("jit.cache_misses")
+        _trace.lifecycle_since("jit.first_call", self._call_t0_ns,
+                               traces=traced)
 
     def _ensure_state(self):
         from ..core.state import param_version
@@ -923,15 +939,14 @@ class CompiledTrainStep:
                 and self.optimizer._step_count > 0):
             losses = self._dispatch_window(args_data, lrs, k)
         else:
-            with _trace.span("jit.window_fallback"):
-                _counters.inc("jit.fused_fallback_steps", k)
-                per_step = []
-                for i in range(k):
-                    sliced = jax.tree_util.tree_map(
-                        lambda x, _i=i: x[_i] if hasattr(x, "shape") else x,
-                        args_data)
-                    per_step.append(self._dispatch_single(sliced, lrs[i]))
-                losses = jnp.stack(per_step)
+            _counters.inc("jit.fused_fallback_steps", k)
+            per_step = []
+            for i in range(k):
+                sliced = jax.tree_util.tree_map(
+                    lambda x, _i=i: x[_i] if hasattr(x, "shape") else x,
+                    args_data)
+                per_step.append(self._dispatch_single(sliced, lrs[i]))
+            losses = jnp.stack(per_step)
         if hydrated:
             self.sync()
         from ..distributed.elastic import heartbeat
@@ -999,9 +1014,7 @@ class CompiledTrainStep:
                                            args_data)
         if _dt is not None:
             _devicetime.observe(_dt, (loss, new_params, new_opt))
-        _counters.inc("jit.cache_hits"
-                      if _counters.get("jit.traces") == traces_before
-                      else "jit.cache_misses")
+        self._note_dispatch(traces_before)
         # bump AFTER the call: at trace time opt.step() does its own bump, so
         # t-based rules (NAdam/RAdam) see the same count an eager step would
         self.optimizer._step_count += 1
@@ -1069,9 +1082,7 @@ class CompiledTrainStep:
                                            args_data)
         if _dt is not None:
             _devicetime.observe(_dt, (losses, new_params, new_opt))
-        _counters.inc("jit.cache_hits"
-                      if _counters.get("jit.traces") == traces_before
-                      else "jit.cache_misses")
+        self._note_dispatch(traces_before)
         self.optimizer._step_count += k
         self._state = (new_params, new_buffers, new_opt, new_sstate, new_rng)
         self._synced = False
@@ -1110,7 +1121,6 @@ class CompiledTrainStep:
         """Queue one dispatch's lazy metric refs (device arrays — NOT read
         here) plus host-side context; :meth:`metrics_flush` materializes
         them at the next sync boundary."""
-        import time
         if not self._tok_cached:
             self._tokens_per_step = self._infer_tokens(args_data, stacked)
             self._tok_cached = True

@@ -192,6 +192,7 @@ def _flash_fwd(q, k, v, causal, scale):
             dimension_semantics=("parallel", "parallel", "parallel"),
             vmem_limit_bytes=64 * 1024 * 1024),
         interpret=_INTERPRET[0],
+        name="flash_fwd",
     )(q, k, v)
     return out, lse
 
@@ -319,6 +320,7 @@ def _flash_bwd(q, k, v, out, lse, do, causal, scale, dlse=None):
             dimension_semantics=("parallel", "parallel", "parallel"),
             vmem_limit_bytes=64 * 1024 * 1024),
         interpret=_INTERPRET[0],
+        name="flash_dq",
     )(q, k, v, do, lse, delta)
 
     dk, dv = pl.pallas_call(
@@ -349,6 +351,7 @@ def _flash_bwd(q, k, v, out, lse, do, causal, scale, dlse=None):
             dimension_semantics=("parallel", "parallel", "parallel"),
             vmem_limit_bytes=64 * 1024 * 1024),
         interpret=_INTERPRET[0],
+        name="flash_dkv",
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 

@@ -254,6 +254,7 @@ def paged_decode_attention(q, pool_k, pool_v, bt, pos, scale_k=None,
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=64 * 1024 * 1024),
         interpret=_INTERPRET[0],
+        name="paged_decode_attn",
     )(bt, pos, *args)
 
 

@@ -62,5 +62,6 @@ def _rms_norm_pallas(x, w, eps=1e-6, block_rows=256):
         out_specs=pl.BlockSpec((block_rows, h), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, h), x.dtype),
         interpret=_INTERPRET[0],
+        name="rms_norm",
     )(x2, w)
     return out.reshape(orig_shape)

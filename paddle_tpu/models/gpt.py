@@ -20,6 +20,7 @@ TPU-native design decisions:
 from __future__ import annotations
 
 import math
+import time
 
 import jax
 import jax.numpy as jnp
@@ -34,6 +35,7 @@ from ..kernels._shapes import NEG_INF
 from ..kernels.flash_attention import flash_attention_fwd, reference_attention
 from ..kernels.rope import rope_tables
 from ..nn.layer.layers import Layer
+from ..profiler import host_tracer as _trace
 
 
 class GPTConfig:
@@ -207,6 +209,7 @@ def _mm_lora(x, lw, name, al, aids):
 
 class GPTForCausalLM(Layer):
     def __init__(self, config: GPTConfig):
+        t0_ns = time.perf_counter_ns()
         super().__init__()
         self.config = c = config
         import numpy as np
@@ -255,6 +258,7 @@ class GPTForCausalLM(Layer):
         self.lnf_b = mk((H,), zeros, P())
         if not c.tie_word_embeddings:
             self.lm_head = mk((H, V), init, P(None, "mp"))
+        _trace.lifecycle_since("setup.model_init", t0_ns)
 
     # -- pure block ----------------------------------------------------------
     def _block_fn(self, c, training, dkey):

@@ -4,9 +4,12 @@ chrome-trace export and stats tables, plus the always-on counter registry).
 
 Pieces:
 
-* ``host_tracer`` — thread-aware ``RecordEvent`` span collection, gated by
-  ``FLAGS_host_trace_level`` (0 = zero-cost no-op), exported as valid
-  chrome://tracing JSON and summarized as a Paddle-style stats table.
+* ``host_tracer`` — the one span primitive (``span``; ``RecordEvent`` wraps
+  it): thread-aware, on ``perf_counter_ns``, live whenever someone is
+  profiling (a ``host_tracer.start()`` session or any ``jax.profiler``
+  session, whose ``.xplane.pb`` it then also lands in), gated by
+  ``FLAGS_host_trace_level`` (0 = off), exported as valid chrome://tracing
+  JSON and summarized as a Paddle-style stats table.
 * ``counters`` — process-global counter/gauge registry fed by the jit /
   static / io / distributed / optimizer hot paths (compile counts, cache
   hits, retraces, host syncs, device_put bytes, prefetch stalls, ...).
@@ -58,7 +61,7 @@ Pieces:
 
 Device-side (XPlane) tracing via ``jax.profiler`` is started only when a
 device target (TPU/GPU) is explicitly requested — host tracing alone never
-touches the jax profiler.
+starts the jax profiler; it only asks whether one is running.
 """
 
 from __future__ import annotations
@@ -350,27 +353,17 @@ def summary(sorted_by="total", time_unit="ms"):
 
 class RecordEvent:
     """User-facing host trace span (reference: platform/profiler
-    RecordEvent).  Records into the host tracer; additionally annotates the
-    XPlane timeline when a device trace is running."""
+    RecordEvent): ``host_tracer.span`` with explicit ``begin``/``end``."""
 
     def __init__(self, name, event_type=None):
         self.name = name
         self._span = None
-        self._ann = None
 
     def begin(self):
         self._span = span(self.name)
         self._span.__enter__()
-        prof = _LAST_PROFILER
-        if prof is not None and prof._device_trace:
-            import jax
-            self._ann = jax.profiler.TraceAnnotation(self.name)
-            self._ann.__enter__()
 
     def end(self):
-        if self._ann is not None:
-            self._ann.__exit__(None, None, None)
-            self._ann = None
         if self._span is not None:
             self._span.__exit__(None, None, None)
             self._span = None

@@ -1,20 +1,43 @@
-"""Host-side span tracer.
+"""Host-side span tracer: the one span primitive of the package.
 
 Reference analogue: ``HostTracer`` collecting ``RecordEvent`` annotations
 (platform/profiler/host_tracer.cc) merged into an event tree and exported by
 ``ChromeTracingLogger`` (profiler/chrometracing_logger.h:32) plus the
 aggregate stats tables.
 
-Design: a span is a wall-clock [begin, end) interval on one thread.  Sites
-call ``span("jit.step")`` in a ``with`` block; when tracing is off (either
-``FLAGS_host_trace_level`` is 0 or no collection session is active) ``span``
-returns a shared no-op singleton — no allocation, no record, one integer
-compare — so steady-state training pays nothing.  When on, completed spans
-are appended to the session list as ``(name, tid, start_ns, end_ns, depth)``
-tuples; nesting depth comes from a per-thread stack, which also serves as
-the "span context" the NaN/Inf guard reports.
+Design: a span is a ``perf_counter_ns`` [begin, end) interval on one thread.
+Sites call ``span("jit.step")`` in a ``with`` block.  A span is live whenever
+someone is profiling: a ``host_tracer.start()`` session is open, or a
+``jax.profiler`` session is running (the benchmark's tracer, ``POST
+/profile``, ``Profiler(targets=[TPU])``: anything that calls
+``jax.profiler.start_trace``).  Otherwise, or when the site's ``level``
+exceeds ``FLAGS_host_trace_level``, ``span`` returns a shared no-op singleton
+(no allocation, no record: one integer compare and one ``is_enabled()``
+call), so steady-state training and serving pay nothing.
 
-Export: ``to_chrome_trace()`` renders the session as chrome://tracing /
+A live span does two things.  Under a profiler session it enters
+``jax.profiler.TraceAnnotation(name)``, so it lands on the host thread of
+the same ``.xplane.pb`` as the device's ops, on the device trace's clock.
+And it is kept in memory as ``(name, tid, start_ns, end_ns, depth,
+counts)``: nesting depth comes from a per-thread stack, which also serves
+as the "span context" the NaN/Inf guard reports; ``counts`` is a small
+dict (or ``None``) of what the site counted, given when the span opens or
+with ``note(**counts)`` before it closes.  The store is bounded
+(``STORE_LIMIT``, oldest dropped) and read with ``events()``.
+
+A **lifecycle span** (``level=0``) marks something that happens a handful
+of times in a process: the package's import, a model's or an engine's
+construction, the first build of a program, a train step's first call.
+It is always kept, profiler or not and whatever the flag says, because
+set-up is over before any profiler starts: in a small store of its own
+(``lifecycle()``, ``LIFECYCLE_LIMIT``, never cleared by ``start()``), and
+in the session store and the trace as well when someone is profiling.  A
+site that learns only afterwards that a call was a first one (or that is
+older than this module, as the package's own import is) stamps
+``perf_counter_ns`` itself and hands the stamps to ``lifecycle_since``.  A
+lifecycle span must never sit on a per-step or per-token path.
+
+Export: ``to_chrome_trace()`` renders events as chrome://tracing /
 perfetto "X" complete events (one pid, real thread ids, metadata rows);
 ``summary()`` renders the Paddle-style stats table (count/total/avg/max/min
 per span name).
@@ -22,31 +45,30 @@ per span name).
 
 from __future__ import annotations
 
+import collections
 import json
 import os
 import threading
 import time
 
+from jax.profiler import TraceAnnotation
+
 from ..core import flags as _flags
 
-# _ENABLED[0] is the single hot-path gate: the flag level while a collection
-# session is active, 0 otherwise.  Recomputed on session start/stop and on
-# FLAGS_host_trace_level changes (flag observer).
-_ENABLED = [0]
+# span() tests _LEVEL[0] (the flag), then _COLLECTING[0] (a start() session)
+# or the profiler's own switch.
 _LEVEL = [1]
 _COLLECTING = [False]
-_EVENTS: list[tuple] = []
+STORE_LIMIT = 1 << 16
+LIFECYCLE_LIMIT = 1 << 10
+_EVENTS: collections.deque = collections.deque(maxlen=STORE_LIMIT)
+_LIFECYCLE: collections.deque = collections.deque(maxlen=LIFECYCLE_LIMIT)
 _THREAD_NAMES: dict[int, str] = {}
 _TLS = threading.local()
 
 
-def _recompute():
-    _ENABLED[0] = _LEVEL[0] if _COLLECTING[0] else 0
-
-
 def _on_level_change(value):
     _LEVEL[0] = int(value)
-    _recompute()
 
 
 _flags.register_flag_observer("FLAGS_host_trace_level", _on_level_change)
@@ -64,6 +86,7 @@ class _NullSpan:
     """Shared do-nothing context manager — the disabled-tracing fast path."""
 
     __slots__ = ()
+    live = False   # a site asks before it counts something only for note()
 
     def __enter__(self):
         return self
@@ -71,17 +94,32 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
+    def note(self, **counts):
+        pass
+
 
 _NULL = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("name", "_t0", "_depth")
+    __slots__ = ("name", "counts", "live", "_ann", "_lifecycle", "_t0",
+                 "_depth")
 
-    def __init__(self, name):
+    def __init__(self, name, counts, profiled, live, lifecycle):
         self.name = name
+        self.counts = counts
+        self._ann = TraceAnnotation(name) if profiled else None
+        self.live = live   # kept in the session store (someone is profiling)
+        self._lifecycle = lifecycle
         self._t0 = 0
         self._depth = 0
+
+    def note(self, **counts):
+        """Counts taken where the work happened, attached to this span."""
+        if self.counts is None:
+            self.counts = counts
+        else:
+            self.counts.update(counts)
 
     def __enter__(self):
         stack = getattr(_TLS, "stack", None)
@@ -89,31 +127,63 @@ class _Span:
             stack = _TLS.stack = []
         self._depth = len(stack)
         stack.append(self.name)
+        if self._ann is not None:
+            self._ann.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
         end = time.perf_counter_ns()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         stack = _TLS.stack
         if stack and stack[-1] is self.name:
             stack.pop()
-        tid = threading.get_ident()
-        if tid not in _THREAD_NAMES:
-            _THREAD_NAMES[tid] = threading.current_thread().name
-        _EVENTS.append((self.name, tid, self._t0, end, self._depth))
+        _keep((self.name, _tid(), self._t0, end, self._depth, self.counts),
+              self.live, self._lifecycle)
         return False
 
 
-def span(name: str, level: int = 1):
-    """Open a trace span; returns the no-op singleton when tracing is off or
-    the site's ``level`` exceeds ``FLAGS_host_trace_level``."""
-    if _ENABLED[0] < level:
+def _tid():
+    tid = threading.get_ident()
+    if tid not in _THREAD_NAMES:
+        _THREAD_NAMES[tid] = threading.current_thread().name
+    return tid
+
+
+def _keep(event, live, lifecycle):
+    if live:
+        _EVENTS.append(event)
+    if lifecycle:
+        _LIFECYCLE.append(event)
+
+
+def span(name: str, level: int = 1, **counts):
+    """Open a trace span; returns the no-op singleton when nobody is
+    profiling or the site's ``level`` exceeds ``FLAGS_host_trace_level``.
+    ``level=0`` is a lifecycle span: always kept (module docstring)."""
+    if _LEVEL[0] < level:
         return _NULL
-    return _Span(name)
+    profiled = TraceAnnotation.is_enabled()
+    live = profiled or _COLLECTING[0]
+    if not (live or level == 0):
+        return _NULL
+    return _Span(name, counts or None, profiled, live, level == 0)
+
+
+def lifecycle_since(name: str, t0_ns: int, t1_ns: int | None = None,
+                    **counts):
+    """Keep the lifecycle span ``name`` that began at ``t0_ns`` and ended at
+    ``t1_ns`` (``perf_counter_ns`` stamps the site took itself), or ends
+    now."""
+    _keep((name, _tid(), t0_ns, t1_ns or time.perf_counter_ns(),
+           len(getattr(_TLS, "stack", ())), counts or None),
+          enabled(0), True)
 
 
 def enabled(level: int = 1) -> bool:
-    return _ENABLED[0] >= level
+    return _LEVEL[0] >= level and (_COLLECTING[0]
+                                   or TraceAnnotation.is_enabled())
 
 
 def current_stack() -> list:
@@ -124,17 +194,15 @@ def current_stack() -> list:
 
 # -- collection sessions ----------------------------------------------------
 def start():
-    """Begin a collection session; drops any previous session's events."""
+    """Begin a collection session; drops whatever the store held."""
     _EVENTS.clear()
     _THREAD_NAMES.clear()
     _COLLECTING[0] = True
-    _recompute()
 
 
 def stop() -> list:
     """End the session; returns the collected event tuples."""
     _COLLECTING[0] = False
-    _recompute()
     return list(_EVENTS)
 
 
@@ -143,12 +211,19 @@ def is_collecting() -> bool:
 
 
 def events() -> list:
-    """Snapshot of the current session's events (live if still collecting)."""
+    """Snapshot of the store: the spans completed since the last ``start()``
+    (or, with no session of this module's, under profiler sessions), the
+    newest ``STORE_LIMIT`` of them."""
     return list(_EVENTS)
 
 
 def span_count() -> int:
     return len(_EVENTS)
+
+
+def lifecycle() -> list:
+    """Snapshot of the lifecycle spans of this process, oldest first."""
+    return list(_LIFECYCLE)
 
 
 # -- export -----------------------------------------------------------------
@@ -163,11 +238,11 @@ def to_chrome_trace(evts=None, process_name="paddle_tpu") -> dict:
     for tid, tname in sorted(_THREAD_NAMES.items()):
         out.append({"ph": "M", "name": "thread_name", "pid": pid, "tid": tid,
                     "args": {"name": tname}})
-    for name, tid, t0, t1, depth in evts:
+    for name, tid, t0, t1, depth, counts in evts:
         out.append({"ph": "X", "name": name, "cat": "host", "pid": pid,
                     "tid": tid, "ts": t0 / 1000.0,
                     "dur": max(t1 - t0, 0) / 1000.0,
-                    "args": {"depth": depth}})
+                    "args": {"depth": depth, **(counts or {})}})
     return {"traceEvents": out, "displayTimeUnit": "ms"}
 
 
@@ -189,7 +264,7 @@ def summary(evts=None, sorted_by="total", time_unit="ms") -> str:
         return "(no host trace events recorded)"
     div = {"s": 1e9, "ms": 1e6, "us": 1e3, "ns": 1.0}.get(time_unit, 1e6)
     agg: dict[str, list] = {}
-    for name, _tid, t0, t1, _d in evts:
+    for name, _tid, t0, t1, _d, _c in evts:
         dur = max(t1 - t0, 0)
         st = agg.get(name)
         if st is None:

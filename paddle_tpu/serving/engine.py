@@ -127,8 +127,9 @@ class Request:
         self.error = None         # the exception, when finish_reason="error"
         self.tokens = []          # generated tokens (includes eos if hit)
         self.slot = None
-        self.arrival_ns = time.monotonic_ns()
-        self.last_emit_ns = None  # monotonic_ns of the last emitted token
+        # perf_counter_ns: the clock of spans, request traces and histograms
+        self.arrival_ns = time.perf_counter_ns()
+        self.last_emit_ns = None  # perf_counter_ns of the last emitted token
         self.deadline = deadline  # absolute time.monotonic() or None
         self._cancel = False
         self._engine = engine
@@ -262,20 +263,21 @@ class LLMEngine:
         # the arena owns every declared device-resident leaf (weights, KV
         # pools, scale pools) with resolved NamedSharding specs; with
         # mesh=None it is a bit-identical pass-through
-        self.arena = StateArena(mesh=mesh, shard_rules=shard_rules)
-        if weight_dtype == "int8":
-            from ..quantization import ptq_int8_decode_state
-            self._w = self.arena.declare_tree(
-                "weights", ptq_int8_decode_state(model))
-        else:
-            self._w = self.arena.declare_tree(
-                "weights", model.decode_state())
+        with span("serving.engine_init", level=0):
+            self.arena = StateArena(mesh=mesh, shard_rules=shard_rules)
+            if weight_dtype == "int8":
+                from ..quantization import ptq_int8_decode_state
+                self._w = self.arena.declare_tree(
+                    "weights", ptq_int8_decode_state(model))
+            else:
+                self._w = self.arena.declare_tree(
+                    "weights", model.decode_state())
 
-        B, S = self.max_slots, self.max_seq_len
-        nh = c.num_heads
-        hd = c.hidden_size // nh
-        dt = jnp.dtype(c.dtype)
-        self._init_kv(c, B, S, nh, hd, dt)
+            B, S = self.max_slots, self.max_seq_len
+            nh = c.num_heads
+            hd = c.hidden_size // nh
+            dt = jnp.dtype(c.dtype)
+            self._init_kv(c, B, S, nh, hd, dt)
 
         # host mirrors of the per-slot decode inputs
         key_size = jax.random.key_data(jax.random.key(0)).shape[0]
@@ -462,9 +464,10 @@ class LLMEngine:
                         do_sample, temp, top_k, top_p)
                     return ck, cv, tok, new_key
                 return jax.jit(prefill)
-            fn = self.arena.program(_model_programs(model),
-                                    self.arena.decorate("prefill_slot"),
-                                    build)
+            key = self.arena.decorate("prefill_slot")
+            with span("serving.program_build", level=0, key=key,
+                      bucket=bucket):
+                fn = self.arena.program(_model_programs(model), key, build)
             self._prefill_jits[bucket] = fn
             counters.set_gauge("serving.prefill_programs",
                                len(self._prefill_jits))
@@ -522,9 +525,10 @@ class LLMEngine:
                                     greedy).astype(jnp.int32)
                     return nxt, ck, cv, jax.random.key_data(new_keys)
                 return jax.jit(decode, donate_argnums=(1, 2))
-            self._decode_jit = self.arena.program(
-                _model_programs(model),
-                self.arena.decorate("decode_slots"), build)
+            key = self.arena.decorate("decode_slots")
+            with span("serving.program_build", level=0, key=key):
+                self._decode_jit = self.arena.program(
+                    _model_programs(model), key, build)
         return self._decode_jit
 
     # -- request intake ------------------------------------------------------
@@ -699,7 +703,7 @@ class LLMEngine:
         replay prefix check) see ``req.tokens`` already advanced past this
         token when one step emits several (prefill + same-step decode)."""
         req.tokens.append(int(tok))
-        now_ns = time.monotonic_ns()
+        now_ns = time.perf_counter_ns()
         if len(req.tokens) == 1:
             self._observe("serving.ttft_ns", now_ns - req.arrival_ns)
             if self.adapter_slots:
@@ -736,7 +740,7 @@ class LLMEngine:
                 self._finish(req, "deadline", events)
                 continue
             self._observe("serving.queue_wait_ns",
-                          time.monotonic_ns() - req.arrival_ns,
+                          time.perf_counter_ns() - req.arrival_ns,
                           sum_counter=True)
             tr = req.trace
             if tr is not None:
@@ -749,11 +753,11 @@ class LLMEngine:
                 T = int(req.prompt.shape[0])
                 bucket = bucket_length(T, self.min_bucket, self.max_seq_len)
                 self._observe("serving.prefill_occupancy", T / bucket)
-                ids = np.zeros((1, bucket), np.int32)
-                ids[0, :T] = req.prompt
-                key_data = np.asarray(
-                    jax.random.key_data(jax.random.key(req.seed)))
-                with span("serving.prefill"):
+                with span("serving.prefill.operands"):
+                    ids = np.zeros((1, bucket), np.int32)
+                    ids[0, :T] = req.prompt
+                    key_data = np.asarray(
+                        jax.random.key_data(jax.random.key(req.seed)))
                     pf = self._prefill_for(bucket)
                     pname = self.arena.decorate(f"serving.prefill[b{bucket}]")
                     iname = self.arena.decorate(f"serving.insert[b{bucket}]")
@@ -763,6 +767,7 @@ class LLMEngine:
                              np.int32(req.top_k), np.float32(req.top_p))
                     self._maybe_capture(pname, pf, *pargs)
                     self._maybe_audit(pname, pf, *pargs)
+                with span("serving.prefill.dispatch"):
                     _dt = _devicetime.note(pname)
                     kc, vc, tok, new_key = pf(*pargs)
                     _devicetime.observe(_dt, (kc, vc, tok))
@@ -793,9 +798,10 @@ class LLMEngine:
             req.state = "running"
             req.slot = slot
             self._slots[slot] = req
-            self._tok[slot] = int(tok)
-            self._pos[slot] = T
-            self._keys[slot] = np.asarray(new_key)
+            with span("serving.prefill.wait"):    # the prefill's read-back
+                self._tok[slot] = int(tok)
+                self._pos[slot] = T
+                self._keys[slot] = np.asarray(new_key)
             self._temp[slot] = req.temperature
             self._topk[slot] = req.top_k
             self._topp[slot] = req.top_p
@@ -812,7 +818,7 @@ class LLMEngine:
         t0 = time.perf_counter()
         tr_on = rtrace.enabled()
         t0_tr = time.perf_counter_ns() if tr_on else 0
-        with span("serving.decode"):
+        with span("serving.decode.operands"):
             dec = self._decode()
             op = self.arena.operand
             dname = self.arena.decorate("serving.decode")
@@ -824,9 +830,11 @@ class LLMEngine:
             self._maybe_capture(dname, dec, *dargs)
             self._maybe_audit(dname, dec, *dargs,
                               donate_argnums=(1, 2))
+        with span("serving.decode.dispatch"):
             _dt = _devicetime.note(dname)
             nxt, self._ck, self._cv, new_keys = dec(*dargs)
             _devicetime.observe(_dt, nxt)
+        with span("serving.decode.wait"):
             nxt = np.asarray(nxt)
         if tr_on:
             t1_tr = time.perf_counter_ns()
@@ -834,15 +842,17 @@ class LLMEngine:
                 if r.trace is not None:
                     r.trace.add_span("decode.iter", t0_tr, t1_tr,
                                      batch=len(active))
-        self._keys = np.array(new_keys)  # mutable host copy
+        with span("serving.decode.wait"):    # the second read-back
+            self._keys = np.array(new_keys)  # mutable host copy
         # one token emitted per active slot this launch
         self._note_decode(len(active), time.perf_counter() - t0)
         counters.inc("serving.decode_steps")
         counters.inc("serving.decode_tokens", len(active))
-        for s, req in active:
-            self._tok[s] = nxt[s]
-            self._pos[s] += 1
-            self._emit(req, nxt[s], events)
+        with span("serving.decode.emit"):
+            for s, req in active:
+                self._tok[s] = nxt[s]
+                self._pos[s] += 1
+                self._emit(req, nxt[s], events)
 
     def step(self):
         """One scheduler iteration: sweep cancels/deadlines, admit from
@@ -852,10 +862,13 @@ class LLMEngine:
         'finished', ...}) produced."""
         with span("serving.step"):
             events = []
-            self._sweep(events)
-            self._admit(events)
+            with span("serving.sweep"):
+                self._sweep(events)
+            with span("serving.admit"):
+                self._admit(events)
             self._decode_step(events)
-            self._admit(events)  # freed slots are immediately rehandable
+            with span("serving.admit"):
+                self._admit(events)  # freed slots are immediately rehandable
         counters.set_gauge(
             "serving.slot_occupancy",
             sum(r is not None for r in self._slots) / self.max_slots)

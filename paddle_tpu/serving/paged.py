@@ -368,8 +368,10 @@ class PagedLLMEngine(LLMEngine):
                         do_sample, temp, top_k, top_p)
                     return pk, pv, tok, new_key
                 return jax.jit(pchunk, donate_argnums=(5, 6))
-            fn = self.arena.program(_model_programs(model),
-                                    self._prog_key("prefill_paged"), build)
+            key = self._prog_key("prefill_paged")
+            with span("serving.program_build", level=0, key=key,
+                      bucket=bucket):
+                fn = self.arena.program(_model_programs(model), key, build)
             self._pchunk_jits[bucket] = fn
             counters.set_gauge("serving.prefill_programs",
                                len(self._pchunk_jits))
@@ -436,9 +438,10 @@ class PagedLLMEngine(LLMEngine):
                         top_p)
                     return nxt, pk, pv, new_keys
                 return jax.jit(decode, donate_argnums=(1, 2))
-            self._pdecode_jit = self.arena.program(
-                _model_programs(model), self._prog_key("decode_paged"),
-                build)
+            key = self._prog_key("decode_paged")
+            with span("serving.program_build", level=0, key=key):
+                self._pdecode_jit = self.arena.program(
+                    _model_programs(model), key, build)
         return self._pdecode_jit
 
     def _pcopy(self):
@@ -1020,7 +1023,7 @@ class PagedLLMEngine(LLMEngine):
                     self._queue.appendleft(req)
                 return
             self._observe("serving.queue_wait_ns",
-                          time.monotonic_ns() - req.arrival_ns,
+                          time.perf_counter_ns() - req.arrival_ns,
                           sum_counter=True)
             if req.trace is not None:
                 req.trace.span_from("enqueue", "queue")
@@ -1035,17 +1038,17 @@ class PagedLLMEngine(LLMEngine):
                           self.min_bucket, self.prefill_chunk)
         take_n = min(remaining, C)
         last = start + take_n == T
-        ids = np.zeros((1, C), np.int32)
-        ids[0, :take_n] = req.prompt[start:start + take_n]
-        # every chunk is fed the request's ORIGINAL seed key; only the
-        # final chunk's sample/key are consumed, so the key-split chain
-        # is exactly generate's one-split-after-prefill
-        key_data = np.asarray(
-            jax.random.key_data(jax.random.key(req.seed)))
-        self._observe("serving.prefill_occupancy", take_n / C)
-        tr = req.trace
-        t0_tr = time.perf_counter_ns() if tr is not None else 0
-        with span("serving.prefill"):
+        with span("serving.prefill.operands"):
+            ids = np.zeros((1, C), np.int32)
+            ids[0, :take_n] = req.prompt[start:start + take_n]
+            # every chunk is fed the request's ORIGINAL seed key; only the
+            # final chunk's sample/key are consumed, so the key-split chain
+            # is exactly generate's one-split-after-prefill
+            key_data = np.asarray(
+                jax.random.key_data(jax.random.key(req.seed)))
+            self._observe("serving.prefill_occupancy", take_n / C)
+            tr = req.trace
+            t0_tr = time.perf_counter_ns() if tr is not None else 0
             pf = self._pchunk_for(C)
             head = (self._w, self.arena.operand(ids), np.int32(start),
                     np.int32(take_n), self.arena.operand(self._bt[slot]))
@@ -1067,6 +1070,7 @@ class PagedLLMEngine(LLMEngine):
             pname = f"serving.{self._prog_key('prefill_paged')}[c{C}]"
             self._maybe_capture(pname, pf, *pargs)
             self._maybe_audit(pname, pf, *pargs, donate_argnums=dn)
+        with span("serving.prefill.dispatch"):
             _dt = _devicetime.note(pname)
             if self.kv_dtype:
                 (self._pk, self._pv, self._sk, self._sv, tok,
@@ -1084,9 +1088,11 @@ class PagedLLMEngine(LLMEngine):
         if last:
             del self._prefill_state[slot]
             counters.inc("serving.prefill_batches")
-            self._tok[slot] = int(tok)
-            self._pos[slot] = T
-            self._keys[slot] = np.asarray(new_key)
+            # only the last chunk's sample is consumed: the one read-back
+            with span("serving.prefill.wait"):
+                self._tok[slot] = int(tok)
+                self._pos[slot] = T
+                self._keys[slot] = np.asarray(new_key)
             self._temp[slot] = req.temperature
             self._topk[slot] = req.top_k
             self._topp[slot] = req.top_p
@@ -1139,16 +1145,16 @@ class PagedLLMEngine(LLMEngine):
             return
         self._observe("serving.decode_occupancy",
                       len(active) / self.max_slots)
-        # non-running rows (idle or mid-prefill) are tabled to the trash
-        # block at position 0: the ONE decode program runs every launch
-        # with fixed shapes, whatever subset of rows is live
-        bt_eff = np.where(self._running[:, None], self._bt,
-                          0).astype(np.int32)
-        pos_eff = np.where(self._running, self._pos, 0).astype(np.int32)
-        t0 = time.perf_counter()
-        tr_on = rtrace.enabled()
-        t0_tr = time.perf_counter_ns() if tr_on else 0
-        with span("serving.decode"):
+        with span("serving.decode.operands"):
+            # non-running rows (idle or mid-prefill) are tabled to the
+            # trash block at position 0: the ONE decode program runs every
+            # launch with fixed shapes, whatever subset of rows is live
+            bt_eff = np.where(self._running[:, None], self._bt,
+                              0).astype(np.int32)
+            pos_eff = np.where(self._running, self._pos, 0).astype(np.int32)
+            t0 = time.perf_counter()
+            tr_on = rtrace.enabled()
+            t0_tr = time.perf_counter_ns() if tr_on else 0
             dec = self._pdecode()
             op = self.arena.operand
             tail = (op(bt_eff), op(self._tok),
@@ -1171,6 +1177,7 @@ class PagedLLMEngine(LLMEngine):
             dname = f"serving.{self._prog_key('decode_paged')}"
             self._maybe_capture(dname, dec, *dargs)
             self._maybe_audit(dname, dec, *dargs, donate_argnums=dn)
+        with span("serving.decode.dispatch"):
             _dt = _devicetime.note(dname)
             if self.kv_dtype:
                 (nxt, self._pk, self._pv, self._sk, self._sv,
@@ -1178,6 +1185,7 @@ class PagedLLMEngine(LLMEngine):
             else:
                 nxt, self._pk, self._pv, new_keys = dec(*dargs)
             _devicetime.observe(_dt, nxt)
+        with span("serving.decode.wait"):
             nxt = np.asarray(nxt)
         if tr_on:
             t1_tr = time.perf_counter_ns()
@@ -1185,17 +1193,19 @@ class PagedLLMEngine(LLMEngine):
                 if r.trace is not None:
                     r.trace.add_span("decode.iter", t0_tr, t1_tr,
                                      batch=len(active))
-        self._keys = np.array(new_keys)  # mutable host copy
+        with span("serving.decode.wait"):    # the second read-back
+            self._keys = np.array(new_keys)  # mutable host copy
         # one token emitted per active slot this launch
         self._note_decode(len(active), time.perf_counter() - t0)
         counters.inc("serving.decode_steps")
         counters.inc("serving.decode_tokens", len(active))
         if self.kv_dtype:
             counters.inc("serving.kv.quant.decode_tokens", len(active))
-        for s, req in active:
-            self._tok[s] = nxt[s]
-            self._pos[s] += 1
-            self._emit(req, nxt[s], events)
+        with span("serving.decode.emit"):
+            for s, req in active:
+                self._tok[s] = nxt[s]
+                self._pos[s] += 1
+                self._emit(req, nxt[s], events)
 
     # -- KV migration (disaggregated prefill/decode fleet) -------------------
     def export_request(self, req):
@@ -1502,14 +1512,20 @@ class PagedLLMEngine(LLMEngine):
         launches), advance every mid-prefill request by ONE chunk, run
         ONE decode launch for all running slots, re-admit into anything
         freed this step."""
-        with span("serving.step"):
+        with span("serving.step") as sp:
             events = []
-            self._sweep(events)
-            self._maybe_spill_idle()
-            self._admit(events)
+            with span("serving.sweep"):
+                self._sweep(events)
+                self._maybe_spill_idle()
+            with span("serving.admit"):
+                self._admit(events)
             self._prefill_chunks(events)
             self._decode_step(events)
-            self._admit(events)
+            with span("serving.admit"):
+                self._admit(events)
+            if sp.live:   # counted only for someone who is profiling
+                sp.note(blocks_live=self._blocks_live(),
+                        blocks_total=self.pool.capacity)
         counters.set_gauge(
             "serving.slot_occupancy",
             sum(r is not None for r in self._slots) / self.max_slots)
@@ -1521,6 +1537,13 @@ class PagedLLMEngine(LLMEngine):
             counters.set_gauge("serving.kv.tier.host_blocks",
                                self._host_tier.resident)
         return events
+
+    def _blocks_live(self):
+        """Distinct pool blocks in the tables of the requests that hold a
+        slot.  ``pool.used_blocks`` also counts what the prefix tree
+        retains after a request has finished; this does not."""
+        return int(np.count_nonzero(
+            np.bincount(self._bt.ravel(), minlength=1)[TRASH_BLOCK + 1:]))
 
     def stats(self):
         """Slot-engine snapshot plus the block-pool / prefix-cache
@@ -1538,6 +1561,7 @@ class PagedLLMEngine(LLMEngine):
                 "blocks_total": self.pool.capacity,
                 "blocks_free": self.pool.free_blocks,
                 "blocks_used": self.pool.used_blocks,
+                "blocks_live": self._blocks_live(),
                 "block_utilization": (self.pool.used_blocks
                                       / max(1, self.pool.capacity)),
                 "prefix_hits": self.kv_prefix_hits,

@@ -10,6 +10,8 @@ paddle_tpu/nn/functional/common.py).
 
 from __future__ import annotations
 
+import time
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -44,7 +46,13 @@ class Generator:
         self._key = jax.random.wrap_key_data(state._data if isinstance(state, Tensor) else state)
 
 
+# The default generator's key is the package's first touch of the device:
+# where nothing touched it before the import, the backend starts here
+# (seconds on a TPU).  Stamped so that the package's own import can be told
+# from it (``paddle_tpu/__init__`` keeps ``setup.first_device_touch``).
+_T0_NS = time.perf_counter_ns()
 _DEFAULT_GEN = Generator(np.random.randint(0, 2**31 - 1))
+FIRST_TOUCH_NS = (_T0_NS, time.perf_counter_ns())
 
 
 def default_generator():
