@@ -1,16 +1,18 @@
 """Fused paged-attention decode kernel + quantized-KV helpers.
 
 The plain-XLA paged decode (``GPT.decode_paged``) gathers every row's
-logical sequence ``pool[bt] -> [B, S_max, nh, hd]`` per layer before the
-attention einsum — O(B * S_max) HBM traffic per step however short the
-sequences actually are.  The Pallas kernel here walks the int32 block
-tables **directly over the block-pool arena** (vLLM's PagedAttention
-shape, Kwon et al. SOSP '23): the grid is ``(B, max_blocks)``, the block
-tables + positions ride as scalar-prefetch operands so each grid step
-DMAs exactly ONE physical block ``pool[bt[row, j]]`` into VMEM, and an
-online-softmax accumulator (flash-attention style) folds the block in —
-the ``[B, S_max]`` gathered cache is never materialized, and blocks past
-``ceil((pos+1)/bs)`` are skipped.
+logical sequence ``pool[l, bt] -> [B, S_max, nh, hd]`` per layer before
+the attention einsum — O(B * S_max) HBM traffic per step however short
+the sequences actually are.  The Pallas kernel here walks the int32
+block tables **directly over the stacked block-pool arena** (vLLM's
+PagedAttention shape, Kwon et al. SOSP '23): the grid is ``(B,)``, the
+layer index, block tables and positions ride as scalar-prefetch
+operands, the pool stays in HBM, and each row loops over its LIVE
+blocks only, several per step, fetched by the kernel's own
+double-buffered async copies and folded into an online-softmax
+accumulator (flash-attention style) — the ``[B, S_max]`` gathered cache
+is never materialized, and a block past ``pos // bs`` costs neither a
+loop step nor a DMA.
 
 Quantized KV (int8 / fp8-e4m3) stores the arena 1 byte/value with one
 fp32 scale per (layer, block, position) — per-token symmetric absmax,
@@ -20,13 +22,15 @@ contractions, so the kernel dequantizes **in-register** by scaling the
 ``[1, bs]`` logit/probability rows — the int8 tiles themselves are never
 expanded in HBM.
 
-Backend selection is ``FLAGS_paged_kernel``:
+Backend selection is :func:`kernel_mode`, from what the code can
+observe and with no switch to set:
 
-* ``off`` (default) — the plain-XLA gather math in ``GPT.decode_paged``
-  (the reference twin; also the CPU path, so tier-1 never needs a TPU).
-* ``pallas`` — this kernel on TPU (or under interpret mode in tests).
-  Off-TPU without interpret mode :func:`kernel_mode` raises: a requested
-  backend that cannot run is an error, never a quiet switch to the twin.
+* ``pallas`` — this kernel, on a TPU whose compiler can tile the pool's
+  ``[nh, hd]`` slabs (``hd`` a multiple of 128 lanes, ``nh`` of 8
+  sublanes: the kernel's own DMAs move whole tiles), and under the
+  tests' interpret hook.
+* ``off`` — the plain-XLA gather math in ``GPT.decode_paged`` everywhere
+  else: the CPU path (tier-1 never needs a TPU) and the tests' reference.
 
 The kernel is trace-time transparent to the serving invariants: block
 tables stay int32 OPERANDS, one compiled decode program serves every
@@ -37,22 +41,18 @@ is traced — steady-state windows stay counter-silent.
 from __future__ import annotations
 
 import functools
+import importlib
+import threading
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..core.flags import define_flag, flag
 from ..device import on_tpu
 from ..profiler import counters
-from ._shapes import check_divides, check_equal, neg_inf
+from ._shapes import LANE, check_divides, check_equal, neg_inf
 
 _INTERPRET = [False]  # tests flip this on CPU
-
-define_flag("FLAGS_paged_kernel", "off",
-            "paged-attention decode backend: 'off' keeps the plain-XLA "
-            "gather twin (reference; CPU default), 'pallas' fuses the "
-            "block-table walk into one Pallas kernel on TPU")
 
 #: serving ``kv_dtype`` string -> arena storage dtype.
 KV_DTYPES = {"int8": jnp.int8, "fp8": jnp.float8_e4m3fn}
@@ -62,20 +62,29 @@ KV_DTYPES = {"int8": jnp.int8, "fp8": jnp.float8_e4m3fn}
 KV_QMAX = {"int8": 127.0, "fp8": 448.0}
 
 
-def kernel_mode():
-    """Validate ``FLAGS_paged_kernel`` against the platform and return
-    it: the mode the decode program compiles with.  ``pallas`` without a
-    TPU (and without the tests' interpret hook) raises."""
-    mode = flag("FLAGS_paged_kernel")
-    if mode not in ("off", "pallas"):
-        raise ValueError(f"FLAGS_paged_kernel={mode!r}: want 'off' or "
-                         "'pallas'")
-    if mode == "pallas" and not (on_tpu() or _INTERPRET[0]):
-        raise RuntimeError(
-            "FLAGS_paged_kernel='pallas' needs a TPU backend, found "
-            f"{jax.devices()[0].platform!r}; the Pallas decode kernel "
-            "does not fall back to the XLA twin")
-    return mode
+def kernel_mode(nh, hd):
+    """The decode attention a program over ``nh`` heads of ``hd``
+    (per chip) compiles with: ``"pallas"`` where the kernel can run —
+    under the tests' interpret hook, or on a TPU when the pool's
+    ``[nh, hd]`` slabs are whole (8, 128) tiles, which the kernel's
+    block DMAs need — else ``"off"``, the XLA gather twin."""
+    if _INTERPRET[0] or (on_tpu() and hd % LANE == 0 and nh % 8 == 0):
+        return "pallas"
+    return "off"
+
+
+def preload():
+    """Start importing Pallas on a background thread.  The import pulls
+    in every Mosaic dialect (0.8 s on a desktop core, 1.5 s on a v5e's
+    host) and would otherwise sit inside the decode program's first
+    trace, in front of the first token.  An engine that resolved to the
+    kernel calls this at construction; the prefill programs it builds
+    first leave the GIL often enough to hide about a third of it
+    (``PERF.md``, PR 27).  The kernel's own import then finds the module
+    loaded or waits on its lock."""
+    threading.Thread(target=importlib.import_module,
+                     args=("jax.experimental.pallas.tpu",),
+                     name="pallas-import", daemon=True).start()
 
 
 # ---------------------------------------------------------------------------
@@ -119,169 +128,199 @@ def dequantize_kv(q, scale):
 # ---------------------------------------------------------------------------
 # the fused decode kernel
 # ---------------------------------------------------------------------------
-def _dot32(a, b, tb=False):
-    """Tiny fp32-accumulating dot for the per-head [1, hd] x [hd, bs]
-    contractions (operands stay in their input dtype; the MXU/VPU
-    accumulates fp32)."""
-    cb = (1 if tb else 0,)
-    return jax.lax.dot_general(a.astype(jnp.float32),
-                               b.astype(jnp.float32),
-                               (((1,), cb), ((), ())),
-                               preferred_element_type=jnp.float32)
+#: physical blocks folded per inner step (8 blocks of 16 tokens = 128
+#: positions: 512 KB of bf16 K and of V at 16 heads of 128)
+_BLOCKS_PER_STEP = 8
 
 
-def _decode_kernel(bt_ref, pos_ref, q_ref, k_ref, v_ref, *rest, bs, nh,
-                   scale, max_blocks, quant):
-    """One grid step: fold physical block ``bt[b, j]`` into row ``b``'s
-    online-softmax state.  Scratch (m, l, acc) persists across the
-    ``j`` (arbitrary-semantics) grid dim; the output row is written at
-    the last block."""
+def _decode_kernel(layer_ref, bt_ref, pos_ref, q_ref, k_hbm, v_hbm, *rest,
+                   bs, nh, G, quant):
+    """One grid step = one row: walk the row's live blocks ``0 .. pos //
+    bs`` in chunks of ``G``, each chunk fetched from the stacked pool
+    (left in HBM) by the kernel's own double-buffered async copies and
+    folded into an online-softmax state carried in registers.  A dead
+    block costs neither a loop step nor a DMA.
+
+    All heads share one contraction: the chunk read as ``[T * nh, hd]``
+    (free: nh fills whole sublane tiles) against q gives every (head,
+    head') pair; the own-head diagonal is selected before the softmax
+    and is all that P.V sums, so both products are plain ``[nh, .]``
+    matmuls with K/V in the dtype they are stored in."""
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     if quant:
-        sk_ref, sv_ref, o_ref, m_ref, l_ref, acc_ref = rest
+        sk_ref, sv_ref, o_ref, kbuf, vbuf, sem = rest
     else:
-        o_ref, m_ref, l_ref, acc_ref = rest
-        sk_ref = sv_ref = None
+        o_ref, kbuf, vbuf, sem = rest
     b = pl.program_id(0)
-    j = pl.program_id(1)
+    layer = layer_ref[0]
     pos = pos_ref[b]
-    nb = pos // bs + 1          # blocks holding live positions
+    nb = pos // bs + 1              # blocks holding live positions
+    nchunks = (nb + G - 1) // G
+    hd = q_ref.shape[-1]
+    W = G * bs * nh                 # logit columns a chunk: (position, head)
+    cd = q_ref.dtype                # contraction operand dtype
+    fmin = neg_inf(jnp.float32)
 
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, neg_inf(jnp.float32))
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    # stale rows of a partly filled chunk meet p == 0 in P.V: they must
+    # be finite, which old K/V is and never-written VMEM need not be
+    @pl.when(b == 0)
+    def _zero():
+        vbuf[...] = jnp.zeros_like(vbuf)
 
-    @pl.when(j < nb)
-    def _fold():
-        # key positions this block covers, vs the row's live horizon
-        kpos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
-        live = kpos <= pos                                   # [1, bs]
-        skrow = sk_ref[0] if quant else None                 # [1, bs] f32
-        svrow = sv_ref[0] if quant else None
-        # per-head tiny matmuls, python-unrolled (nh is static + small);
-        # a per-key-token scale commutes with the contraction, so the
-        # quantized dequant is a [1, bs] row multiply — int8/fp8 tiles
-        # are never expanded
-        rows = []
-        for hh in range(nh):
-            qh = q_ref[0, hh:hh + 1]                          # [1, hd]
-            kh = k_ref[0, :, hh, :]                           # [bs, hd]
-            s_h = _dot32(qh, kh, tb=True) * scale             # [1, bs]
-            if quant:
-                s_h = s_h * skrow
-            rows.append(jnp.where(live, s_h, neg_inf(jnp.float32)))
-        s = jnp.concatenate(rows, axis=0)                     # [nh, bs]
-        m_prev, l_prev = m_ref[...], l_ref[...]               # [nh, 1]
+    def dma(i, slot, start):
+        """Start, or wait for, the copies of chunk ``i``'s live blocks."""
+        def block(g, _):
+            blk = bt_ref[b, i * G + g]
+            for s, (hbm, buf) in enumerate(((k_hbm, kbuf), (v_hbm, vbuf))):
+                cp = pltpu.make_async_copy(
+                    hbm.at[layer, blk],
+                    buf.at[slot, pl.ds(pl.multiple_of(g * bs, bs), bs)],
+                    sem.at[s, slot])
+                cp.start() if start else cp.wait()
+            return 0
+
+        jax.lax.fori_loop(0, jnp.minimum(G, nb - i * G), block, 0)
+
+    q = q_ref[0]                                              # [nh, hd]
+    col = jax.lax.broadcasted_iota(jnp.int32, (nh, W), 1)
+    own = jax.lax.rem(col, nh) == jax.lax.broadcasted_iota(
+        jnp.int32, (nh, W), 0)
+
+    def body(i, carry):
+        m_prev, l_prev, acc = carry
+        slot = jax.lax.rem(i, 2)
+
+        @pl.when(i + 1 < nchunks)
+        def _():
+            dma(i + 1, 1 - slot, True)
+
+        dma(i, slot, False)
+        s = jax.lax.dot_general(
+            q, kbuf[slot].astype(cd).reshape(W, hd),
+            (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        if quant:
+            # a per-key-token scale commutes with both contractions:
+            # dequantization is a row multiply of logits / probabilities
+            cols = pl.ds(pl.multiple_of(i * W, W), W)
+            s = s * sk_ref[0, :, cols]
+        live = own & (col < (pos + 1 - i * G * bs) * nh)
+        s = jnp.where(live, s, fmin)
         m_new = jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)                                # [nh, bs]
-        l_ref[...] = l_prev * alpha + jnp.sum(p, -1, keepdims=True)
-        m_ref[...] = m_new
+        p = jnp.exp(s - m_new)
+        l_new = l_prev * alpha + jnp.sum(p, -1, keepdims=True)
         if quant:
-            p = p * svrow
-        prows = [_dot32(p[hh:hh + 1], v_ref[0, :, hh, :])     # [1, hd]
-                 for hh in range(nh)]
-        acc_ref[...] = acc_ref[...] * alpha + jnp.concatenate(prows, 0)
+            p = p * sv_ref[0, :, cols]
+        pv = jnp.dot(p.astype(cd), vbuf[slot].astype(cd).reshape(W, hd),
+                     preferred_element_type=jnp.float32)
+        return m_new, l_new, acc * alpha + pv
 
-    @pl.when(j == max_blocks - 1)
-    def _emit():
-        o_ref[0] = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+    dma(0, 0, True)
+    _, l_fin, acc = jax.lax.fori_loop(
+        0, nchunks, body,
+        (jnp.full((nh, 1), fmin, jnp.float32),
+         jnp.zeros((nh, 1), jnp.float32),
+         jnp.zeros((nh, hd), jnp.float32)))
+    o_ref[0] = acc / l_fin
 
 
-def paged_decode_attention(q, pool_k, pool_v, bt, pos, scale_k=None,
+def paged_decode_attention(q, pool_k, pool_v, layer, bt, pos, scale_k=None,
                            scale_v=None, *, scale):
-    """Fused paged decode attention for B rows over the shared arena.
+    """Fused paged decode attention for B rows over the stacked arena.
 
-    q ``[B, nh, hd]`` (the rows' single query tokens, any float dtype),
-    pool_k/pool_v ``[n_blocks, bs, nh, hd]`` (one layer's arena, already
-    holding each row's newly scattered K/V at ``pos``), bt ``[B,
-    max_blocks]`` int32, pos ``[B]`` int32.  With quantized pools,
-    scale_k/scale_v ``[n_blocks, bs]`` fp32 are the per-token scales and
-    dequantization happens in-register.  Returns fp32 ``[B, nh, hd]``.
+    q ``[B, nh, hd]`` (the rows' single query tokens), pool_k/pool_v
+    ``[L, n_blocks, bs, nh, hd]`` (the whole arena, layer ``layer``
+    already holding each row's newly scattered K/V at ``pos``; it stays
+    in HBM and only the live blocks of that layer are read), layer int32
+    scalar, bt ``[B, max_blocks]`` int32, pos ``[B]`` int32.  With
+    quantized pools, scale_k/scale_v ``[L, n_blocks, bs]`` fp32 are the
+    per-token scales and dequantization happens in-register.  Contraction
+    operands are in the pool's dtype (q's for a quantized pool), the
+    accumulation and the softmax in fp32.  Returns fp32 ``[B, nh, hd]``.
     """
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     B, nh, hd = q.shape
-    n_blocks, bs = pool_k.shape[0], pool_k.shape[1]
+    n_blocks, bs = pool_k.shape[1], pool_k.shape[2]
     max_blocks = bt.shape[1]
     quant = scale_k is not None
     check_equal(
         "paged_attention",
-        pool_v_blocks=(pool_v.shape[0], n_blocks),
-        pool_k_heads=(pool_k.shape[2], nh),
-        pool_k_head_dim=(pool_k.shape[3], hd),
+        pool_v_layers=(pool_v.shape[0], pool_k.shape[0]),
+        pool_v_blocks=(pool_v.shape[1], n_blocks),
+        pool_k_heads=(pool_k.shape[3], nh),
+        pool_k_head_dim=(pool_k.shape[4], hd),
         table_rows=(bt.shape[0], B),
         pos_rows=(pos.shape[0], B),
-        **({"scale_k_blocks": (scale_k.shape[0], n_blocks),
-            "scale_k_positions": (scale_k.shape[1], bs)} if quant else {}))
+        **({"scale_k_blocks": (scale_k.shape[1], n_blocks),
+            "scale_k_positions": (scale_k.shape[2], bs)} if quant else {}))
     check_divides("paged_attention", block_size=(bs, 1))
 
-    kernel = functools.partial(_decode_kernel, bs=bs, nh=nh, scale=scale,
-                               max_blocks=max_blocks, quant=quant)
-    blk = lambda b, j, bt_s, pos_s: (bt_s[b, j], 0, 0, 0)  # noqa: E731
-    row = lambda b, j, bt_s, pos_s: (b, 0, 0)              # noqa: E731
-    in_specs = [
-        pl.BlockSpec((1, nh, hd), row),
-        pl.BlockSpec((1, bs, nh, hd), blk),
-        pl.BlockSpec((1, bs, nh, hd), blk),
-    ]
-    args = [q, pool_k, pool_v]
+    cd = q.dtype if quant else pool_k.dtype
+    G = min(_BLOCKS_PER_STEP, max_blocks)
+    kernel = functools.partial(_decode_kernel, bs=bs, nh=nh, G=G,
+                               quant=quant)
+    row = lambda b, *_: (b, 0, 0)                          # noqa: E731
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    in_specs = [pl.BlockSpec((1, nh, hd), row), hbm, hbm]
+    args = [(q * scale).astype(cd), pool_k, pool_v]
     if quant:
-        # Mosaic wants a block's last two dims divisible by (8, 128) or
-        # equal to the array's: a (1, bs) row of [n_blocks, bs] is
-        # neither, a (1, 1, bs) slab of [n_blocks, 1, bs] is the latter
-        srow = lambda b, j, bt_s, pos_s: (bt_s[b, j], 0, 0)  # noqa: E731
-        in_specs += [pl.BlockSpec((1, 1, bs), srow),
-                     pl.BlockSpec((1, 1, bs), srow)]
-        args += [scale_k[:, None, :], scale_v[:, None, :]]
+        # the rows' scales in logical order, one column per column of
+        # the kernel's logits: [B, 1, cols] rows sliced chunk by chunk
+        # (B * S fp32 a layer, next to B * S * nh * hd of tiles)
+        cols = -(-max_blocks // G) * G * bs * nh
+        for sc in (scale_k, scale_v):
+            r = jnp.repeat(sc[layer, bt].reshape(B, -1), nh, axis=1)
+            args.append(jnp.pad(r, ((0, 0), (0, cols - r.shape[1])))
+                        [:, None, :])
+        in_specs += [pl.BlockSpec((1, 1, cols), row)] * 2
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, max_blocks),
+        num_scalar_prefetch=3,
+        grid=(B,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, nh, hd), row),
-        scratch_shapes=[pltpu.VMEM((nh, 1), jnp.float32),
-                        pltpu.VMEM((nh, 1), jnp.float32),
-                        pltpu.VMEM((nh, hd), jnp.float32)])
+        scratch_shapes=[pltpu.VMEM((2, G * bs, nh, hd), pool_k.dtype),
+                        pltpu.VMEM((2, G * bs, nh, hd), pool_v.dtype),
+                        pltpu.SemaphoreType.DMA((2, 2))])
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, nh, hd), jnp.float32),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-            vmem_limit_bytes=64 * 1024 * 1024),
+            dimension_semantics=("arbitrary",)),
         interpret=_INTERPRET[0],
         name="paged_decode_attn",
-    )(bt, pos, *args)
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), bt, pos, *args)
 
 
-def sharded_paged_decode_attention(mesh, axis, q, pool_k, pool_v, bt, pos,
-                                   scale_k=None, scale_v=None, *, scale):
+def sharded_paged_decode_attention(mesh, axis, q, pool_k, pool_v, layer, bt,
+                                   pos, scale_k=None, scale_v=None, *,
+                                   scale):
     """Head-sharded twin of :func:`paged_decode_attention`.
 
-    The kernel's per-head matmuls are fully independent, so a pool whose
-    head axis is sharded over ``axis`` (``[n_blocks, bs, nh/mp, hd]`` per
-    chip) decodes with one ``shard_map`` over the heads: each chip runs
-    the unmodified kernel on its head slice against the replicated block
+    The kernel's heads are fully independent, so a pool whose head axis
+    is sharded over ``axis`` (``[L, n_blocks, bs, nh/mp, hd]`` per chip)
+    decodes with one ``shard_map`` over the heads: each chip runs the
+    unmodified kernel on its head slice against the replicated block
     tables/positions/scales, and the concatenated ``[B, nh, hd]`` output
     needs no collective at all — the TP all-reduce happens later, at the
     projection contraction GSPMD partitions.
     """
     hspec = P(None, axis, None)                 # q / output: heads on dim 1
-    pspec = P(None, None, axis, None)           # pools: heads on dim 2
-    in_specs = [hspec, pspec, pspec, P(), P()]
-    args = [q, pool_k, pool_v, bt, pos]
+    pspec = P(None, None, None, axis, None)     # pools: heads on dim 3
+    in_specs = [hspec, pspec, pspec, P(), P(), P()]
+    args = [q, pool_k, pool_v, layer, bt, pos]
     if scale_k is not None:
         in_specs += [P(), P()]                  # per-token scales replicate
         args += [scale_k, scale_v]
 
-    def _local(q_, pk_, pv_, bt_, pos_, *scales):
+    def _local(q_, pk_, pv_, layer_, bt_, pos_, *scales):
         sk_, sv_ = scales if scales else (None, None)
-        return paged_decode_attention(q_, pk_, pv_, bt_, pos_, sk_, sv_,
-                                      scale=scale)
+        return paged_decode_attention(q_, pk_, pv_, layer_, bt_, pos_, sk_,
+                                      sv_, scale=scale)
 
     fn = jax.shard_map(_local, mesh=mesh, in_specs=tuple(in_specs),
                        out_specs=hspec, check_vma=False)

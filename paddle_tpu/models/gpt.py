@@ -825,16 +825,22 @@ class GPTForCausalLM(Layer):
         attends over its gathered logical sequence with ``kpos <=
         pos[row]``.  Returns ``(logits [B, V] fp32, pool_k, pool_v)``.
 
-        ``kernel="pallas"`` routes the attention through the fused Pallas
-        block-table walk (``kernels.paged_attention``) instead of the
-        gather einsum — same operands, same mask, no ``[B, S]`` logical
-        view in HBM.  ``kernel=None``/``"off"`` keeps the plain-XLA
-        gather below as the reference twin.  Under tensor parallelism
+        The pools ride the layer scan as carry and are updated in place:
+        each layer scatters at ``(l, blk, off)`` into the stacked buffers
+        and no layer's slice of them is ever materialised.
+
+        ``kernel="pallas"`` (what ``kernels.paged_attention.kernel_mode``
+        resolves to on a TPU) routes the attention through the fused
+        Pallas block-table walk — same operands, same mask, only the
+        live blocks read, no ``[B, S]`` logical view in HBM.
+        ``kernel=None``/``"off"`` is the plain-XLA gather below: the
+        CPU path and the tests' reference.  Under tensor parallelism
         pass ``mesh``/``head_axis`` (the serving arena does): the pallas
         call then runs through ``shard_map`` over the KV head axis —
         each chip walks only its own ``nh/mp`` heads, and the cross-chip
         reduction happens at the following proj contraction exactly as
-        in the gather twin (GSPMD partitions that twin with no help).  Quantized-KV mode mirrors
+        in the gather twin (GSPMD partitions that twin with no help).
+        Quantized-KV mode mirrors
         ``prefill_paged``: per-token fp32 scale arenas ``scale_k``/
         ``scale_v [L, n_blocks, bs]`` ride the donated carry, the new
         token quantizes on insert, and the return grows to ``(logits,
@@ -867,17 +873,18 @@ class GPTForCausalLM(Layer):
         lora = adapters is not None
         aids = adapter_ids
 
-        def body(hh, xs):
+        def body(carry, xs):
+            # the arena rides the scan as CARRY: each layer scatters its
+            # new token into the stacked buffers at (l, blk, off) and
+            # reads them where they lie — no layer's slice of the pool
+            # is ever copied out or back
+            hh, ck, cv, *scales = carry
+            sk, sv = scales if quant else (None, None)
             if lora:
-                lw, al, *rest = xs
+                lw, l, al = xs
             else:
+                lw, l = xs
                 al = None
-                lw, *rest = xs
-            if quant:
-                ck, cv, sk, sv = rest
-            else:
-                ck, cv = rest
-                sk = sv = None
             x = _norm(hh, lw["ln1_w"], lw["ln1_b"], eps)
             qkv = _mm_lora(x, lw, "qkv_w", al, aids) + lw["qkv_b"]
             q, k, v = jnp.split(qkv, 3, axis=-1)
@@ -890,40 +897,41 @@ class GPTForCausalLM(Layer):
             if quant:
                 kq, ks = _pa.quantize_kv(k[:, 0], kv_dt)
                 vq, vs = _pa.quantize_kv(v[:, 0], kv_dt)
-                ck = ck.at[blk, off].set(kq)
-                cv = cv.at[blk, off].set(vq)
-                sk = sk.at[blk, off].set(ks)
-                sv = sv.at[blk, off].set(vs)
+                ck = ck.at[l, blk, off].set(kq)
+                cv = cv.at[l, blk, off].set(vq)
+                sk = sk.at[l, blk, off].set(ks)
+                sv = sv.at[l, blk, off].set(vs)
             else:
-                ck = ck.at[blk, off].set(k[:, 0].astype(ck.dtype))
-                cv = cv.at[blk, off].set(v[:, 0].astype(cv.dtype))
+                ck = ck.at[l, blk, off].set(k[:, 0].astype(ck.dtype))
+                cv = cv.at[l, blk, off].set(v[:, 0].astype(cv.dtype))
             if mode == "pallas":
                 # fused block-table walk: the arena is read in physical
                 # blocks, never gathered to [B, S]
                 if mesh is not None and head_axis is not None:
                     o = _pa.sharded_paged_decode_attention(
-                        mesh, head_axis, q[:, 0] * scale, ck, cv, bt,
-                        pos, sk, sv, scale=1.0)
+                        mesh, head_axis, q[:, 0], ck, cv, l, bt, pos, sk,
+                        sv, scale=scale)
                 else:
                     o = _pa.paged_decode_attention(
-                        q[:, 0] * scale, ck, cv, bt, pos, sk, sv,
-                        scale=1.0)
+                        q[:, 0], ck, cv, l, bt, pos, sk, sv, scale=scale)
                 o = o.reshape(B, 1, H)
             else:
+                # K/V go to the contractions in the dtype they are
+                # stored in, accumulated in fp32: a product of two bf16
+                # values is exact in fp32, so this is the arithmetic of
+                # a widened copy without the copy
+                gk = ck[l, bt].reshape(B, S, nh, hd)
+                gv = cv[l, bt].reshape(B, S, nh, hd)
                 if quant:
-                    gk = _pa.dequantize_kv(ck[bt], sk[bt]).reshape(
-                        B, S, nh, hd)
-                    gv = _pa.dequantize_kv(cv[bt], sv[bt]).reshape(
-                        B, S, nh, hd)
-                else:
-                    gk = ck[bt].reshape(B, S, nh, hd)
-                    gv = cv[bt].reshape(B, S, nh, hd)
+                    gk = _pa.dequantize_kv(gk, sk[l, bt].reshape(B, S))
+                    gv = _pa.dequantize_kv(gv, sv[l, bt].reshape(B, S))
                 logits = jnp.einsum("bqhd,bkhd->bhqk",
-                                    (q * scale).astype(jnp.float32),
-                                    gk.astype(jnp.float32))
+                                    (q * scale).astype(gk.dtype), gk,
+                                    preferred_element_type=jnp.float32)
                 logits = jnp.where(mask[:, None, None, :], logits, NEG_INF)
                 p = jax.nn.softmax(logits, axis=-1)
-                o = jnp.einsum("bhqk,bkhd->bqhd", p.astype(gv.dtype), gv)
+                o = jnp.einsum("bhqk,bkhd->bqhd", p.astype(gv.dtype), gv,
+                               preferred_element_type=jnp.float32)
                 o = o.reshape(B, 1, H)
             o = o.astype(hh.dtype)
             a = _mm_lora(o, lw, "proj_w", al, aids) + lw["proj_b"]
@@ -939,21 +947,15 @@ class GPTForCausalLM(Layer):
                 up = _mm_lora(x, lw, "fc1_w", al, aids) + lw["fc1_b"]
                 f = _mm_lora(jax.nn.gelu(up), lw, "fc2_w", al,
                              aids) + lw["fc2_b"]
-            return hh + f, ((ck, cv, sk, sv) if quant else (ck, cv))
+            return (hh + f, ck, cv) + ((sk, sv) if quant else ()), None
 
-        xs = ((w["lws"], adapters) if lora else (w["lws"],)) \
-            + ((pool_k, pool_v, scale_k, scale_v) if quant
-               else (pool_k, pool_v))
-        if quant:
-            h, (pool_k, pool_v, scale_k, scale_v) = jax.lax.scan(
-                body, h, xs)
-        else:
-            h, (pool_k, pool_v) = jax.lax.scan(body, h, xs)
+        xs = (w["lws"], jnp.arange(c.num_layers, dtype=jnp.int32)) \
+            + ((adapters,) if lora else ())
+        pools = (pool_k, pool_v) + ((scale_k, scale_v) if quant else ())
+        (h, *pools), _ = jax.lax.scan(body, (h, *pools), xs)
         logits = _lm_logits(c, w["wte"], w["lnf_w"], w["lnf_b"], w["head"],
                             h[:, 0])
-        if quant:
-            return logits, pool_k, pool_v, scale_k, scale_v
-        return logits, pool_k, pool_v
+        return (logits, *pools)
 
     def verify_paged(self, w, toks, pos0, n_valid, bt, pool_k, pool_v,
                      scale_k=None, scale_v=None, adapters=None,

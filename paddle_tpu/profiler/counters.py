@@ -132,8 +132,9 @@ Well-known names (see README "Observability" for the full table):
   serving.fleet.adapter_routed (dispatches won by tenant affinity — the
       winning replica already held the request's adapter)
   kernels.paged.pallas_programs / kernels.paged.xla_fallbacks
-      (trace-time: paged decode programs compiled with the fused Pallas
-      backend vs the plain-XLA gather twin; 0 in steady state)
+      (trace-time: paged decode programs compiled with the Pallas
+      block-table walk — every TPU engine whose K/V slabs are whole
+      tiles — vs the plain-XLA gather twin; 0 in steady state)
   kernels.flash.reference_calls (trace-time: flash/ring attention calls
       that took the jnp reference instead of the Pallas kernel — off-TPU,
       or a sequence that does not tile by 128; chip_smoke.py asserts 0)

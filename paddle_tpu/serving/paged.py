@@ -108,11 +108,6 @@ class PagedLLMEngine(LLMEngine):
         self.prefill_chunk = max(int(self.prefill_chunk), self.min_bucket)
         self.pool = BlockPool(self.n_blocks, bs, kv_dtype=self.kv_dtype)
         self.prefix = PrefixCache(self.pool) if self.prefix_caching else None
-        # which attention backend the decode program compiles with —
-        # resolved ONCE at construction (FLAGS_paged_kernel vs platform)
-        # and baked into the program-cache key, so two engines under
-        # different flag values can never silently share a program
-        self.kv_kernel = _pa.kernel_mode()
         adt = _pa.KV_DTYPES[self.kv_dtype] if self.kv_dtype else dt
         from .arena import KV_POOL_SPEC
         self.arena.declare(
@@ -143,6 +138,20 @@ class PagedLLMEngine(LLMEngine):
         else:
             self.arena.declare("scale_k", None)
             self.arena.declare("scale_v", None)
+        # which attention the decode program compiles with — resolved
+        # ONCE at construction from the platform and the heads a chip
+        # holds, and baked into the program-cache key.  Pools left
+        # replicated on a mesh (indivisible heads) keep the twin: GSPMD
+        # partitions it, and cannot partition a Mosaic call
+        if self.arena.kv_head_axis:
+            self.kv_kernel = _pa.kernel_mode(
+                nh // self.arena.mesh.shape["mp"], hd)
+        elif self.arena.multi_device:
+            self.kv_kernel = "off"
+        else:
+            self.kv_kernel = _pa.kernel_mode(nh, hd)
+        if self.kv_kernel == "pallas":
+            _pa.preload()
         # per-slot block tables (host mirror; rides decode as an operand)
         self._bt = np.zeros((B, self.max_blocks), np.int32)
         self._running = np.zeros(B, np.bool_)
@@ -313,8 +322,8 @@ class PagedLLMEngine(LLMEngine):
     # by argument shape, so chunk buckets and differing pool sizes each
     # get their own executable while identical engines reuse them.
     # Engines whose attention backend or KV precision differ get distinct
-    # cache keys (``_prog_key``) — a program traced under one
-    # FLAGS_paged_kernel / kv_dtype must never serve another.
+    # cache keys (``_prog_key``) — a program traced for one attention
+    # backend / kv_dtype must never serve another.
     # The arena tag (e.g. "[mp2]") rides the key AND the display name so
     # a sharded program can never serve an unsharded engine, and ledger /
     # capture rows stay distinguishable per mesh shape.

@@ -312,7 +312,12 @@ class SpeculativeLLMEngine(PagedLLMEngine):
         drawn from (``q`` — what the acceptance test divides by)."""
         if self._pdraft_jit is None:
             draft = self.draft_model
-            mode = self.kv_kernel
+            # the draft's own head shape decides; its pools take no
+            # shard_map route, so on a mesh it keeps the twin GSPMD
+            # partitions
+            dc = draft.config
+            mode = ("off" if self.arena.multi_device else _pa.kernel_mode(
+                dc.num_heads, dc.hidden_size // dc.num_heads))
 
             def build():
                 def sample_q(logits, keys_data, do_sample, temp, top_k,

@@ -421,7 +421,6 @@ def run():
     # program per backend (kernels.paged.* tick once, at trace time), and
     # zero retraces in a warm measure window.
     import jax.numpy as jnp
-    from paddle_tpu.core import flags as pflags
     from paddle_tpu.kernels import paged_attention as _pa
     from paddle_tpu.quantization import ptq_int8_decode_state
 
@@ -445,8 +444,7 @@ def run():
     base_greedy = pq_run(pq_base)
     base_sampled = pq_run(pq_base, sampled=True)
 
-    _pa._INTERPRET[0] = True
-    pflags.set_flags({"FLAGS_paged_kernel": "pallas"})
+    _pa._INTERPRET[0] = True       # the hook: the kernel runs anywhere
     try:
         kbefore = counters.snapshot()
         pk_eng = pq_engine()
@@ -479,7 +477,6 @@ def run():
             if ksteady.get(k, 0):
                 violations[f"paged-pallas:{k}"] = (ksteady.get(k, 0), 0)
     finally:
-        pflags.set_flags({"FLAGS_paged_kernel": "off"})
         _pa._INTERPRET[0] = False
 
     # int8 arena twin: greedy-identical on the tiny model, ONE decode

@@ -115,8 +115,8 @@ def test_phase_serve_tiny(smoke, interpret):
                             prompt_range=(4, 40), quantum=4, new_tokens=6)
     assert out["ok"]
     assert [r["kv_kernel"] for r in out["runs"].values()] == [
-        "off", "pallas", "pallas"]
-    assert out["runs"]["pallas_int8"]["kv_dtype"] == "int8"
+        "pallas", "pallas"]
+    assert out["runs"]["int8"]["kv_dtype"] == "int8"
 
 
 @pytest.mark.slow

@@ -5,7 +5,7 @@ materialized gather-softmax reference to float epsilon — the block-table
 walk and online softmax are invisible in the math; (2) int8/fp8 pools
 dequantized in-register match the explicitly dequantized reference
 exactly (same fp32 ops, reordered by a commuting per-token scale);
-(3) engines running kv_dtype / FLAGS_paged_kernel=pallas / weight-only
+(3) engines running kv_dtype / the Pallas kernel / weight-only
 PTQ stay token-identical to the plain-XLA bf16 baseline on the tiny
 model; (4) the shared ``kernels._shapes`` preflight validators fail
 loudly, naming the offending dimension.
@@ -15,7 +15,6 @@ import numpy as np
 import pytest
 
 import paddle_tpu as paddle
-from paddle_tpu.core.flags import flag, set_flags
 from paddle_tpu.kernels import paged_attention as pa
 from paddle_tpu.kernels._shapes import (LANE, NEG_INF, check_divides,
                                         check_equal, check_min_tile,
@@ -66,16 +65,24 @@ def interpret_mode():
 
 
 @pytest.fixture()
-def pallas_mode(interpret_mode):
-    set_flags({"FLAGS_paged_kernel": "pallas"})
-    yield
-    set_flags({"FLAGS_paged_kernel": "off"})
+def twin_then_kernel():
+    """For engine-against-engine tests: call it once the twin baselines
+    are built; engines built after that run the kernel."""
+    def switch():
+        pa._INTERPRET[0] = True
+    yield switch
+    pa._INTERPRET[0] = False
 
 
-def _ref_paged(q, pool_k, pool_v, bt, pos, scale, sk=None, sv=None):
+def _ref_paged(q, pool_k, pool_v, bt, pos, scale, sk=None, sv=None,
+               layer=0):
     """Materialized gather + softmax reference (the XLA-twin math in
-    numpy): pool[bt] -> [B, S, nh, hd], causal-mask to pos, softmax."""
+    numpy): pool[layer, bt] -> [B, S, nh, hd], causal-mask to pos,
+    softmax."""
     B, nh, hd = q.shape
+    pool_k, pool_v = pool_k[layer], pool_v[layer]
+    if sk is not None:
+        sk, sv = sk[layer], sv[layer]
     bs = pool_k.shape[1]
     S = bt.shape[1] * bs
     k = pool_k[bt].reshape(B, S, nh, hd).astype(np.float32)
@@ -92,10 +99,10 @@ def _ref_paged(q, pool_k, pool_v, bt, pos, scale, sk=None, sv=None):
 
 
 def _random_case(rng, B=3, nh=2, hd=8, n_blocks=16, bs=4, max_blocks=5,
-                 dtype=np.float32):
+                 dtype=np.float32, L=1):
     q = rng.standard_normal((B, nh, hd)).astype(dtype)
-    pool_k = rng.standard_normal((n_blocks, bs, nh, hd)).astype(dtype)
-    pool_v = rng.standard_normal((n_blocks, bs, nh, hd)).astype(dtype)
+    pool_k = rng.standard_normal((L, n_blocks, bs, nh, hd)).astype(dtype)
+    pool_v = rng.standard_normal((L, n_blocks, bs, nh, hd)).astype(dtype)
     # distinct physical blocks per row, deliberately out of order
     perm = rng.permutation(n_blocks)[:B * max_blocks]
     bt = perm.reshape(B, max_blocks).astype(np.int32)
@@ -110,7 +117,7 @@ class TestPagedDecodeKernel:
         q, pk, pv, bt, pos = _random_case(rng)
         scale = 1.0 / np.sqrt(q.shape[-1])
         out = np.asarray(pa.paged_decode_attention(
-            jnp.asarray(q), jnp.asarray(pk), jnp.asarray(pv),
+            jnp.asarray(q), jnp.asarray(pk), jnp.asarray(pv), 0,
             jnp.asarray(bt), jnp.asarray(pos), scale=scale))
         ref = _ref_paged(q, pk, pv, bt, pos, scale)
         assert np.allclose(out, ref, atol=1e-5), np.abs(out - ref).max()
@@ -122,9 +129,9 @@ class TestPagedDecodeKernel:
         q, pk, pv, bt, pos = _random_case(rng, B=2)
         pos[:] = 0
         out = np.asarray(pa.paged_decode_attention(
-            jnp.asarray(q), jnp.asarray(pk), jnp.asarray(pv),
+            jnp.asarray(q), jnp.asarray(pk), jnp.asarray(pv), 0,
             jnp.asarray(bt), jnp.asarray(pos), scale=0.5))
-        ref = pv[bt[:, 0], 0]                       # [B, nh, hd]
+        ref = pv[0, bt[:, 0], 0]                    # [B, nh, hd]
         assert np.allclose(out, ref, atol=1e-6)
 
     def test_bf16_pool(self, interpret_mode):
@@ -134,7 +141,7 @@ class TestPagedDecodeKernel:
         scale = 0.35
         out = np.asarray(pa.paged_decode_attention(
             jnp.asarray(q, jnp.bfloat16), jnp.asarray(pk, jnp.bfloat16),
-            jnp.asarray(pv, jnp.bfloat16), jnp.asarray(bt),
+            jnp.asarray(pv, jnp.bfloat16), 0, jnp.asarray(bt),
             jnp.asarray(pos), scale=scale))
         ref = _ref_paged(
             np.asarray(jnp.asarray(q, jnp.bfloat16), np.float32),
@@ -153,7 +160,7 @@ class TestPagedDecodeKernel:
         qv, sv = pa.quantize_kv(jnp.asarray(pv), kv_dtype)
         scale = 1.0 / np.sqrt(q.shape[-1])
         out = np.asarray(pa.paged_decode_attention(
-            jnp.asarray(q), qk, qv, jnp.asarray(bt), jnp.asarray(pos),
+            jnp.asarray(q), qk, qv, 0, jnp.asarray(bt), jnp.asarray(pos),
             sk, sv, scale=scale))
         # in-register dequant must equal the explicitly dequantized pool
         dk = np.asarray(pa.dequantize_kv(qk, sk))
@@ -174,7 +181,8 @@ class TestPagedDecodeKernel:
 
         @jax.jit
         def step(q, pk, pv, bt, pos):
-            return pa.paged_decode_attention(q, pk, pv, bt, pos, scale=0.5)
+            return pa.paged_decode_attention(q, pk, pv, 0, bt, pos,
+                                             scale=0.5)
 
         out = np.asarray(step(jnp.asarray(q), jnp.asarray(pk),
                               jnp.asarray(pv), jnp.asarray(bt),
@@ -182,13 +190,99 @@ class TestPagedDecodeKernel:
         ref = _ref_paged(q, pk, pv, bt, pos, 0.5)
         assert np.allclose(out, ref, atol=1e-5)
 
+    @pytest.mark.parametrize("kv", ["bf16", "int8", "fp8"])
+    @pytest.mark.parametrize("nh,hd", [(16, 128), (16, 96)])
+    def test_stacked_pool_layer_index_and_positions(self, interpret_mode,
+                                                    nh, hd, kv):
+        # the whole [L, ...] arena plus a layer index, 16-token blocks,
+        # rows at pos 0, bs - 1, bs, a multiple of the inner step (8
+        # blocks = 128 positions) and S_max - 1, all in one batch
+        import jax.numpy as jnp
+        rng = np.random.default_rng(8)
+        bs, max_blocks, L = 16, 24, 3
+        S = bs * max_blocks
+        q, pk, pv, bt, _ = _random_case(
+            rng, B=5, nh=nh, hd=hd, n_blocks=5 * max_blocks + 1, bs=bs,
+            max_blocks=max_blocks, L=L)
+        pos = np.array([0, bs - 1, bs, 128, S - 1], np.int32)
+        scale = 1.0 / np.sqrt(hd)
+        if kv == "bf16":
+            qj, pkj, pvj = (jnp.asarray(x, jnp.bfloat16) for x in (q, pk, pv))
+            sk = sv = None
+            q, pk, pv = (np.asarray(x, np.float32) for x in (qj, pkj, pvj))
+        else:
+            qj = jnp.asarray(q)
+            pkj, sk = pa.quantize_kv(jnp.asarray(pk), kv)
+            pvj, sv = pa.quantize_kv(jnp.asarray(pv), kv)
+            pk = np.asarray(pa.dequantize_kv(pkj, sk))
+            pv = np.asarray(pa.dequantize_kv(pvj, sv))
+        for layer in (0, L - 1):
+            out = np.asarray(pa.paged_decode_attention(
+                qj, pkj, pvj, jnp.int32(layer), jnp.asarray(bt),
+                jnp.asarray(pos), sk, sv, scale=scale))
+            ref = _ref_paged(q, pk, pv, bt, pos, scale, layer=layer)
+            # bf16: q * scale and p are rounded to the pool's dtype, as
+            # the twin rounds them; quantized pools compute in fp32 here
+            tol = 3e-2 if kv == "bf16" else 2e-5
+            assert np.allclose(out, ref, atol=tol), np.abs(out - ref).max()
+
+    @pytest.mark.parametrize("kv", ["bf16", "int8", "fp8"])
+    def test_dead_blocks_are_never_read(self, interpret_mode, kv):
+        # every block a row does not hold live tokens in — the other
+        # layers, the unlisted blocks, and the row's own table beyond
+        # pos // bs — is poisoned with NaN (Inf scales for quantized
+        # pools): one read of one of them and the output is not finite
+        import jax.numpy as jnp
+        rng = np.random.default_rng(9)
+        bs, max_blocks, L, layer = 4, 20, 2, 1
+        q, pk, pv, bt, _ = _random_case(
+            rng, B=4, nh=8, hd=16, n_blocks=4 * max_blocks + 1, bs=bs,
+            max_blocks=max_blocks, L=L)
+        pos = np.array([0, bs - 1, 8 * bs, 11 * bs + 1], np.int32)
+        livemask = np.zeros(pk.shape[:2], bool)
+        for b, p in enumerate(pos):
+            livemask[layer, bt[b, :p // bs + 1]] = True
+        scale = 1.0 / np.sqrt(q.shape[-1])
+        if kv == "bf16":
+            ref = _ref_paged(q, pk, pv, bt, pos, scale, layer=layer)
+            pk[~livemask] = np.nan
+            pv[~livemask] = np.nan
+            args = (jnp.asarray(pk), jnp.asarray(pv))
+            scales = ()
+            tol = 1e-5
+        else:
+            pkj, sk = pa.quantize_kv(jnp.asarray(pk), kv)
+            pvj, sv = pa.quantize_kv(jnp.asarray(pv), kv)
+            ref = _ref_paged(q, np.asarray(pa.dequantize_kv(pkj, sk)),
+                             np.asarray(pa.dequantize_kv(pvj, sv)), bt, pos,
+                             scale, layer=layer)
+            # the table's dead entries index the scale arena too: its
+            # gather is masked, so poison only what no table names
+            named = np.zeros(livemask.shape, bool)
+            named[layer, bt.reshape(-1)] = True
+            sk = jnp.where(jnp.asarray(named | livemask)[..., None], sk,
+                           jnp.inf)
+            sv = jnp.where(jnp.asarray(named | livemask)[..., None], sv,
+                           jnp.inf)
+            poison = jnp.asarray(~livemask)[..., None, None, None]
+            fill = 127 if kv == "int8" else jnp.nan
+            args = (jnp.where(poison, jnp.asarray(fill, pkj.dtype), pkj),
+                    jnp.where(poison, jnp.asarray(fill, pvj.dtype), pvj))
+            scales = (sk, sv)
+            tol = 1e-5
+        out = np.asarray(pa.paged_decode_attention(
+            jnp.asarray(q), *args, jnp.int32(layer), jnp.asarray(bt),
+            jnp.asarray(pos), *scales, scale=scale))
+        assert np.isfinite(out).all()
+        assert np.allclose(out, ref, atol=tol), np.abs(out - ref).max()
+
     def test_shape_mismatch_fails_preflight(self, interpret_mode):
         import jax.numpy as jnp
         rng = np.random.default_rng(5)
         q, pk, pv, bt, pos = _random_case(rng)
         with pytest.raises(ValueError, match="table_rows"):
             pa.paged_decode_attention(
-                jnp.asarray(q), jnp.asarray(pk), jnp.asarray(pv),
+                jnp.asarray(q), jnp.asarray(pk), jnp.asarray(pv), 0,
                 jnp.asarray(bt[:-1]), jnp.asarray(pos), scale=0.5)
 
 
@@ -229,35 +323,58 @@ class TestQuantizeKV:
 
 
 class TestKernelMode:
-    def test_default_off(self):
-        assert flag("FLAGS_paged_kernel") == "off"
-        assert pa.kernel_mode() == "off"
+    """The platform rule that replaced ``FLAGS_paged_kernel``: nothing
+    to set, the code observes where it runs."""
 
-    def test_pallas_raises_off_tpu(self):
-        set_flags({"FLAGS_paged_kernel": "pallas"})
-        try:
-            if pa.on_tpu():
-                assert pa.kernel_mode() == "pallas"
-            else:
-                # no TPU, no interpret: a requested backend that cannot
-                # run is an error, never a quiet switch to the XLA twin
-                with pytest.raises(RuntimeError, match="needs a TPU"):
-                    pa.kernel_mode()
-                with pytest.raises(RuntimeError, match="needs a TPU"):
-                    _paged(_model())
-                pa._INTERPRET[0] = True
-                assert pa.kernel_mode() == "pallas"  # tests force interpret
-        finally:
-            pa._INTERPRET[0] = False
-            set_flags({"FLAGS_paged_kernel": "off"})
+    def test_twin_off_tpu(self):
+        # was test_default_off: on the CPU the XLA twin, whatever the
+        # head shape, and an engine reports it
+        assert not pa.on_tpu()
+        assert pa.kernel_mode(16, 128) == "off"
+        assert _paged(_model()).stats()["kv_kernel"] == "off"
 
-    def test_invalid_mode_raises(self):
-        set_flags({"FLAGS_paged_kernel": "cuda"})
-        try:
-            with pytest.raises(ValueError, match="FLAGS_paged_kernel"):
-                pa.kernel_mode()
-        finally:
-            set_flags({"FLAGS_paged_kernel": "off"})
+    def test_kernel_on_tpu_for_whole_tiles(self, monkeypatch):
+        # was test_pallas_raises_off_tpu: a TPU runs the kernel where
+        # its block DMAs move whole (8, 128) tiles, the twin elsewhere;
+        # nothing raises and nothing is requested
+        monkeypatch.setattr(pa, "on_tpu", lambda: True)
+        assert pa.kernel_mode(16, 128) == "pallas"
+        assert pa.kernel_mode(8, 256) == "pallas"
+        assert pa.kernel_mode(16, 96) == "off"      # 96 lanes of 128
+        assert pa.kernel_mode(12, 128) == "off"     # 12 sublanes of 8
+        assert pa.kernel_mode(4, 128) == "off"      # 16 heads over mp4
+
+    def test_interpret_hook_runs_kernel_anywhere(self, interpret_mode):
+        # was test_invalid_mode_raises: no mode is left to get wrong;
+        # the tests' hook runs the kernel at any shape, and the program
+        # cache keys on the resolved mode
+        assert pa.kernel_mode(4, 8) == "pallas"
+        eng = _paged(_model())
+        assert eng.stats()["kv_kernel"] == "pallas"
+        assert eng._prog_key("decode_paged") == "decode_paged@pallas:raw"
+        pa._INTERPRET[0] = False
+        assert _paged(_model())._prog_key("decode_paged") == "decode_paged"
+
+
+def test_preload_imports_pallas_off_the_main_thread():
+    # an engine that resolved to the kernel starts this at construction;
+    # an engine on the twin (every CPU engine) never does
+    import sys
+    import threading
+
+    def importers():
+        return [t for t in threading.enumerate() if t.name == "pallas-import"]
+
+    for t in importers():
+        t.join(60)
+    _paged(_model())
+    assert not importers()
+    pa.preload()
+    started = importers()
+    assert all(t.daemon for t in started)
+    for t in started:
+        t.join(60)
+    assert "jax.experimental.pallas.tpu" in sys.modules
 
 
 class TestShapesPreflight:
@@ -343,14 +460,13 @@ class TestQuantizedEngines:
         for h, r in zip(hs, refs):
             assert h.tokens == r
 
-    def test_pallas_greedy_and_sampled_identity(self, pallas_mode):
+    def test_pallas_greedy_and_sampled_identity(self, twin_then_kernel):
         m = _model()
         prompts, seeds = self._prompts(21), [3, 4, 5]
         kw = dict(do_sample=True, temperature=0.9, top_k=8)
-        set_flags({"FLAGS_paged_kernel": "off"})
         greedy_ref = self._baseline(m, prompts, seeds)
         sampled_ref = self._baseline(m, prompts, seeds, **kw)
-        set_flags({"FLAGS_paged_kernel": "pallas"})
+        twin_then_kernel()
         eng = _paged(m)
         assert eng.stats()["kv_kernel"] == "pallas"
         hs = [eng.add_request(p, max_new_tokens=6, seed=s)
@@ -365,12 +481,11 @@ class TestQuantizedEngines:
         for h, r in zip(hs2, sampled_ref):
             assert h.tokens == r
 
-    def test_pallas_int8_identity(self, pallas_mode):
+    def test_pallas_int8_identity(self, twin_then_kernel):
         m = _model()
         prompts, seeds = self._prompts(22), [6, 7, 8]
-        set_flags({"FLAGS_paged_kernel": "off"})
         refs = self._baseline(m, prompts, seeds)
-        set_flags({"FLAGS_paged_kernel": "pallas"})
+        twin_then_kernel()
         eng = _paged(m, kv_dtype="int8")
         hs = [eng.add_request(p, max_new_tokens=6, seed=s)
               for p, s in zip(prompts, seeds)]

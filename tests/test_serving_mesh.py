@@ -176,19 +176,17 @@ def test_mp2_int8_engine_token_identity():
 @pytest.mark.slow  # interpret-mode pallas sweep
 def test_mp2_pallas_shard_map_token_identity():
     import paddle_tpu.kernels.paged_attention as _pa
-    from paddle_tpu.core import flags as pflags
     mesh = _mesh(2)
     m = _fresh_model()
     base = _run(_paged(m))
     _pa._INTERPRET[0] = True
-    pflags.set_flags({"FLAGS_paged_kernel": "pallas"})
     try:
         eng = _paged(m, mesh=mesh)
+        assert eng.stats()["kv_kernel"] == "pallas"
         assert _run(eng) == base
         assert eng.arena.kv_head_axis
     finally:
         _pa._INTERPRET[0] = False
-        pflags.set_flags({"FLAGS_paged_kernel": "off"})
 
 
 def test_mp2_fleet_replicas_construct_mesh_engines():

@@ -8,9 +8,11 @@ tests hand each kernel its real shapes on a ``v5e:2x2`` device and fail
 with whatever the chip's compiler would say.  Nothing runs: a pass here
 is not a chip run (``chip_smoke.py`` is).
 
-Shapes are the ones ``chip_smoke.py`` and ``bench.py`` use: GPT-760M
-(16 heads of 96) and GPT-125M (12 heads of 64) at batch x 1024, a paged
-pool of 16-token blocks holding 8 rows x 1024 tokens.
+Shapes are the ones ``chip_smoke.py`` and ``bench.py`` use for flash:
+GPT-760M (16 heads of 96) and GPT-125M (12 heads of 64) at batch x 1024.
+The paged decode kernel is compiled at the chat cell's own shape (GPT-3
+XL: 16 rows x 128 blocks of 16 tokens, 1,025 blocks, 24 layers stacked,
+16 heads of 128), alone and inside the whole ``decode_paged`` step.
 """
 
 import os
@@ -101,22 +103,74 @@ def test_flash_fwd_bwd_compiles(chip, b, s, h, d):
              kernels=["flash_fwd", "flash_dkv", "flash_dq"])
 
 
-@pytest.mark.parametrize("nh,hd", [pytest.param(16, 96, id="gpt760m"),
-                                   pytest.param(12, 64, id="gpt125m")])
+# (rows, blocks a row, blocks, layers, heads, head_dim): the chat cell's
+# own arena (GPT-3 XL, 16 slots x 2048 tokens in 16-token blocks) and what
+# one chip of an mp2 mesh holds of it
+_PAGED_SHAPES = [pytest.param(16, 128, 1025, 24, 16, 128, id="gpt1.3b-chat"),
+                 pytest.param(16, 128, 1025, 24, 8, 128, id="gpt1.3b-mp2")]
+
+
+@pytest.mark.parametrize("B,max_blocks,n_blocks,L,nh,hd", _PAGED_SHAPES)
 @pytest.mark.parametrize("kv_dtype", [None, "int8", "fp8"])
-def test_paged_decode_compiles(chip, nh, hd, kv_dtype):
-    B, bs, max_blocks = 8, 16, 64
-    n_blocks = B * max_blocks + 1
-    pool = _sds(chip, (n_blocks, bs, nh, hd),
+def test_paged_decode_compiles(chip, B, max_blocks, n_blocks, L, nh, hd,
+                               kv_dtype):
+    bs = 16
+    pool = _sds(chip, (L, n_blocks, bs, nh, hd),
                 pa.KV_DTYPES[kv_dtype] if kv_dtype else jnp.bfloat16)
     args = [_sds(chip, (B, nh, hd), jnp.bfloat16), pool, pool,
+            _sds(chip, (), jnp.int32),
             _sds(chip, (B, max_blocks), jnp.int32),
             _sds(chip, (B,), jnp.int32)]
     if kv_dtype:
-        scales = _sds(chip, (n_blocks, bs), jnp.float32)
+        scales = _sds(chip, (L, n_blocks, bs), jnp.float32)
         args += [scales, scales]
     _compile(lambda *a: pa.paged_decode_attention(*a, scale=1.0), *args,
              kernels=["paged_decode_attn"])
+
+
+@pytest.mark.parametrize("kv_dtype", [None, "int8"])
+def test_decode_step_updates_the_arena_in_place(chip, kv_dtype):
+    """The whole ``decode_paged`` step at the chat cell's shape: the kernel
+    is in it, the stacked pools alias through, and nothing the size of a
+    layer's pool (67 MB) is copied, widened or kept as a temporary."""
+    from paddle_tpu.models import GPTConfig, GPTForCausalLM
+    L, B, bs, max_blocks, n_blocks, nh, hd = 24, 16, 16, 128, 1025, 16, 128
+    # a toy model lends its weights' tree; every leaf is given the shape
+    # GPT-3 XL has there (hidden 16 -> 2048, 3x and 4x of it, the vocab)
+    toy = GPTForCausalLM(GPTConfig(
+        vocab_size=32, hidden_size=nh, num_layers=L, num_heads=nh,
+        max_seq_len=2048, use_flash_attention=False))
+    real = GPTForCausalLM.__new__(GPTForCausalLM)
+    real.config = GPTConfig(
+        vocab_size=50304, hidden_size=nh * hd, num_layers=L, num_heads=nh,
+        max_seq_len=2048, use_flash_attention=False)
+    grow = {nh: nh * hd, 3 * nh: 3 * nh * hd, 4 * nh: 4 * nh * hd,
+            32: 50304}
+    w = jax.tree_util.tree_map(
+        lambda x: _sds(chip, tuple(grow.get(d, d) for d in x.shape),
+                       jnp.bfloat16), toy.decode_state())
+    pool = _sds(chip, (L, n_blocks, bs, nh, hd),
+                pa.KV_DTYPES[kv_dtype] if kv_dtype else jnp.bfloat16)
+    pools = [pool, pool]
+    if kv_dtype:
+        pools += [_sds(chip, (L, n_blocks, bs), jnp.float32)] * 2
+
+    def decode(w, bt, tok, pos, *pools):
+        return real.decode_paged(w, tok, pos, bt, *pools, kernel="pallas")
+
+    compiled = jax.jit(decode, donate_argnums=(4, 5, 6, 7)[:len(pools)]) \
+        .lower(w, _sds(chip, (B, max_blocks), jnp.int32),
+               _sds(chip, (B,), jnp.int32), _sds(chip, (B,), jnp.int32),
+               *pools).compile()
+    text = compiled.as_text()
+    assert re.search(r"%paged_decode_attn(\.\d+)? = [^\n]*custom-call\(",
+                     text)
+    assert not re.search(r"\[(24,)?1025,16,16,128\]\S* (copy|convert)\(",
+                         text)
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 16 * 2 ** 20, mem
+    assert mem.alias_size_in_bytes >= 2 * L * n_blocks * bs * nh * hd * (
+        1 if kv_dtype else 2), mem
 
 
 def test_rms_norm_compiles(chip):

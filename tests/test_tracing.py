@@ -33,7 +33,10 @@ from paddle_tpu.profiler import trace as rtrace
 
 @pytest.fixture(autouse=True)
 def _trace_reset():
-    """Every test leaves tracing OFF and the kept-ring empty."""
+    """Every test finds and leaves tracing OFF and the kept-ring empty
+    (another file's test in the same worker may have kept traces)."""
+    flags.set_flags({"FLAGS_request_trace_sample": 0.0})
+    rtrace.clear()
     yield
     flags.set_flags({"FLAGS_request_trace_sample": 0.0})
     rtrace.clear()
