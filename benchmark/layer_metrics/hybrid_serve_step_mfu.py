@@ -1,0 +1,20 @@
+"""``serve_step_mfu`` for the ``olmo_hybrid`` family: required operations
+(``olmo_hybrid_flops.serve_flops``: 2 per matmul parameter and token,
+attention over the live keys in the full layers only, the delta rule's
+7 d_k d_v per token and head in the linear layers) of every token
+prefilled or decoded by the steps of the traced seconds, over their length
+times the chip's bf16 peak."""
+
+from benchmark import olmo_hybrid_flops as flops
+from benchmark.layer_metrics.serve_step_mfu import traced_steps
+
+
+def read(name, obs, cell, cfg, peak):
+    steps = traced_steps(obs) if "traced" in obs else []
+    if not steps:
+        return None
+    need = sum(flops.serve_flops(cfg, *s["prefill"])
+               + flops.serve_tokens_flops(cfg, s["decode_live"])
+               for s in steps)
+    t0, t1 = obs["traced"]
+    return 100.0 * need / ((t1 - t0) * peak["bf16_flops_per_s"])

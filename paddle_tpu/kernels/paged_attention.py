@@ -73,6 +73,35 @@ def kernel_mode(nh, hd):
     return "off"
 
 
+def pool_heads(nh, hd):
+    """Heads along a single chip's pool for ``nh`` heads of ``hd``: ``nh``
+    rounded up to whole sublane tiles where the lanes are whole
+    (``hd % 128 == 0``), so that the kernel runs whatever the head count
+    (30 heads of 128 are stored as 32); ``nh`` elsewhere.  The padded
+    heads hold zeros and never reach an output (:func:`pad_heads`)."""
+    return -(-nh // 8) * 8 if hd % LANE == 0 else nh
+
+
+def pad_heads(x, nhp):
+    """``x [..., nh, hd]`` with zero heads appended up to ``nhp``; ``x``
+    itself when there is nothing to append."""
+    nh = x.shape[-2]
+    if nhp == nh:
+        return x
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 2) + [(0, nhp - nh), (0, 0)])
+
+
+def head_padding(pool, nh):
+    """``(pad, unpad)`` for a model of ``nh`` heads over ``pool [..., nhp,
+    hd]``, which may store more heads than the model has (whole tiles:
+    :func:`pool_heads`): ``pad`` appends the zero heads to what is written
+    or asked, ``unpad`` drops them from what is read.  Both are the
+    identity where the counts agree."""
+    nhp = pool.shape[-2]
+    return (lambda t: pad_heads(t, nhp),
+            lambda t: t if nhp == nh else t[..., :nh, :])
+
+
 def preload():
     """Start importing Pallas on a background thread.  The import pulls
     in every Mosaic dialect (0.8 s on a desktop core, 1.5 s on a v5e's

@@ -562,6 +562,14 @@ class GPTForCausalLM(Layer):
         return h
 
     # -- serving entry points (paddle_tpu.serving.LLMEngine) -----------------
+    def cache_spec(self):
+        """What a serving engine holds for one request: K/V of every
+        layer, and no per-slot state beside it (see
+        ``models/olmo_hybrid.py`` for a model that has some)."""
+        c = self.config
+        return {"kv_layers": c.num_layers, "kv_heads": c.num_heads,
+                "head_dim": c.hidden_size // c.num_heads, "slot_state": {}}
+
     def decode_state(self):
         """Raw device weights for the serving prefill/decode programs (one
         dict the engine passes through jit unchanged — the arrays stay
@@ -706,6 +714,8 @@ class GPTForCausalLM(Layer):
         hd = H // nh
         B, C = ids.shape
         n_blocks, bs = pool_k.shape[1], pool_k.shape[2]
+        nhp = pool_k.shape[3]       # the pool's heads: nh in whole tiles
+        ph, uh = _pa.head_padding(pool_k, nh)
         max_blocks = bt.shape[0]
         S = max_blocks * bs
         scale = 1.0 / math.sqrt(hd)
@@ -759,18 +769,18 @@ class GPTForCausalLM(Layer):
             else:
                 kz = jnp.where(vm, k[0].astype(ck.dtype), 0)
                 vz = jnp.where(vm, v[0].astype(cv.dtype), 0)
-            ck = ck.at[blk, off].set(kz)
-            cv = cv.at[blk, off].set(vz)
+            ck = ck.at[blk, off].set(ph(kz))
+            cv = cv.at[blk, off].set(ph(vz))
             # gather AFTER the scatter: the logical view holds the shared
             # prefix, earlier chunks, and this chunk's own K/V
             if quant:
-                gk = _pa.dequantize_kv(ck[bt], sk[bt]).reshape(
-                    S, nh, hd)[None]
-                gv = _pa.dequantize_kv(cv[bt], sv[bt]).reshape(
-                    S, nh, hd)[None]
+                gk = uh(_pa.dequantize_kv(ck[bt], sk[bt]).reshape(
+                    S, nhp, hd))[None]
+                gv = uh(_pa.dequantize_kv(cv[bt], sv[bt]).reshape(
+                    S, nhp, hd))[None]
             else:
-                gk = ck[bt].reshape(S, nh, hd)[None]
-                gv = cv[bt].reshape(S, nh, hd)[None]
+                gk = uh(ck[bt].reshape(S, nhp, hd))[None]
+                gv = uh(cv[bt].reshape(S, nhp, hd))[None]
             logits = jnp.einsum("bqhd,bkhd->bhqk",
                                 (q * scale).astype(jnp.float32),
                                 gk.astype(jnp.float32))
@@ -852,6 +862,8 @@ class GPTForCausalLM(Layer):
         hd = H // nh
         B = tok.shape[0]
         n_blocks, bs = pool_k.shape[1], pool_k.shape[2]
+        nhp = pool_k.shape[3]       # the pool's heads: nh in whole tiles
+        ph, uh = _pa.head_padding(pool_k, nh)
         max_blocks = bt.shape[1]
         S = max_blocks * bs
         scale = 1.0 / math.sqrt(hd)
@@ -897,13 +909,13 @@ class GPTForCausalLM(Layer):
             if quant:
                 kq, ks = _pa.quantize_kv(k[:, 0], kv_dt)
                 vq, vs = _pa.quantize_kv(v[:, 0], kv_dt)
-                ck = ck.at[l, blk, off].set(kq)
-                cv = cv.at[l, blk, off].set(vq)
+                ck = ck.at[l, blk, off].set(ph(kq))
+                cv = cv.at[l, blk, off].set(ph(vq))
                 sk = sk.at[l, blk, off].set(ks)
                 sv = sv.at[l, blk, off].set(vs)
             else:
-                ck = ck.at[l, blk, off].set(k[:, 0].astype(ck.dtype))
-                cv = cv.at[l, blk, off].set(v[:, 0].astype(cv.dtype))
+                ck = ck.at[l, blk, off].set(ph(k[:, 0]).astype(ck.dtype))
+                cv = cv.at[l, blk, off].set(ph(v[:, 0]).astype(cv.dtype))
             if mode == "pallas":
                 # fused block-table walk: the arena is read in physical
                 # blocks, never gathered to [B, S]
@@ -912,16 +924,17 @@ class GPTForCausalLM(Layer):
                         mesh, head_axis, q[:, 0], ck, cv, l, bt, pos, sk,
                         sv, scale=scale)
                 else:
-                    o = _pa.paged_decode_attention(
-                        q[:, 0], ck, cv, l, bt, pos, sk, sv, scale=scale)
+                    o = uh(_pa.paged_decode_attention(
+                        ph(q[:, 0]), ck, cv, l, bt, pos, sk, sv,
+                        scale=scale))
                 o = o.reshape(B, 1, H)
             else:
                 # K/V go to the contractions in the dtype they are
                 # stored in, accumulated in fp32: a product of two bf16
                 # values is exact in fp32, so this is the arithmetic of
                 # a widened copy without the copy
-                gk = ck[l, bt].reshape(B, S, nh, hd)
-                gv = cv[l, bt].reshape(B, S, nh, hd)
+                gk = uh(ck[l, bt].reshape(B, S, nhp, hd))
+                gv = uh(cv[l, bt].reshape(B, S, nhp, hd))
                 if quant:
                     gk = _pa.dequantize_kv(gk, sk[l, bt].reshape(B, S))
                     gv = _pa.dequantize_kv(gv, sv[l, bt].reshape(B, S))
@@ -989,6 +1002,8 @@ class GPTForCausalLM(Layer):
         hd = H // nh
         B, K1 = toks.shape
         n_blocks, bs = pool_k.shape[1], pool_k.shape[2]
+        nhp = pool_k.shape[3]       # the pool's heads: nh in whole tiles
+        ph, uh = _pa.head_padding(pool_k, nh)
         max_blocks = bt.shape[1]
         S = max_blocks * bs
         scale = 1.0 / math.sqrt(hd)
@@ -1035,23 +1050,23 @@ class GPTForCausalLM(Layer):
             if quant:
                 kq, ks = _pa.quantize_kv(k, kv_dt)
                 vq, vs = _pa.quantize_kv(v, kv_dt)
-                ck = ck.at[blk, off].set(kq)
-                cv = cv.at[blk, off].set(vq)
+                ck = ck.at[blk, off].set(ph(kq))
+                cv = cv.at[blk, off].set(ph(vq))
                 sk = sk.at[blk, off].set(ks)
                 sv = sv.at[blk, off].set(vs)
             else:
-                ck = ck.at[blk, off].set(k.astype(ck.dtype))
-                cv = cv.at[blk, off].set(v.astype(cv.dtype))
+                ck = ck.at[blk, off].set(ph(k).astype(ck.dtype))
+                cv = cv.at[blk, off].set(ph(v).astype(cv.dtype))
             # gather AFTER the scatter: query j sees the committed prefix
             # plus every draft token at or before its own position
             if quant:
-                gk = _pa.dequantize_kv(ck[bt], sk[bt]).reshape(
-                    B, S, nh, hd)
-                gv = _pa.dequantize_kv(cv[bt], sv[bt]).reshape(
-                    B, S, nh, hd)
+                gk = uh(_pa.dequantize_kv(ck[bt], sk[bt]).reshape(
+                    B, S, nhp, hd))
+                gv = uh(_pa.dequantize_kv(cv[bt], sv[bt]).reshape(
+                    B, S, nhp, hd))
             else:
-                gk = ck[bt].reshape(B, S, nh, hd)
-                gv = cv[bt].reshape(B, S, nh, hd)
+                gk = uh(ck[bt].reshape(B, S, nhp, hd))
+                gv = uh(cv[bt].reshape(B, S, nhp, hd))
             logits = jnp.einsum("bqhd,bkhd->bhqk",
                                 (q * scale).astype(jnp.float32),
                                 gk.astype(jnp.float32))

@@ -21,7 +21,7 @@ from .arena import (DEFAULT_SHARD_RULES, KV_POOL_SPEC,  # noqa: F401
                     StateArena)
 from .autoscale import FleetAutoscaler  # noqa: F401
 from .engine import (EngineBackpressure, EngineClosed, LLMEngine,  # noqa: F401
-                     Request, bucket_length)
+                     RecurrentStateUnsupported, Request, bucket_length)
 from .fleet import FleetRequest, Replica, ServingFleet  # noqa: F401
 from .kvcache import (BlockPool, BlockPoolExhausted,  # noqa: F401
                       PrefixCache, blocks_for_tokens)
@@ -31,7 +31,8 @@ from .sampling import filter_logits, residual_sample, sample_tokens  # noqa: F40
 from .speculative import SpeculativeLLMEngine  # noqa: F401
 
 __all__ = ["LLMEngine", "PagedLLMEngine", "SpeculativeLLMEngine", "Request",
-           "EngineBackpressure", "EngineClosed", "bucket_length",
+           "EngineBackpressure", "EngineClosed", "RecurrentStateUnsupported",
+           "bucket_length",
            "filter_logits", "sample_tokens", "residual_sample",
            "ServingFleet", "FleetRequest", "Replica", "FleetAutoscaler",
            "Router", "RetryAfter", "BlockPool", "BlockPoolExhausted",

@@ -100,6 +100,17 @@ class EngineClosed(RuntimeError):
     """add_request refused: the engine is draining or drained."""
 
 
+class RecurrentStateUnsupported(RuntimeError):
+    """Refused for a model with recurrent layers: the feature moves or
+    adopts K/V blocks, and a request that arrived somewhere without the
+    fixed-size state of its recurrent layers would be served silently
+    wrong.  Raised at construction for ``kv_layout="slots"``,
+    ``kv_dtype=``, ``host_kv_blocks=``, ``adapter_slots=``, ``mesh=`` and
+    ``draft_model=``, and by ``export_request`` / ``adopt_migration``
+    (the prefix cache resolves to off instead: reuse is an optimisation,
+    not a request)."""
+
+
 class Request:
     """One generation request and its live state (also the user handle:
     ``add_request`` returns it; iterate it to stream tokens)."""
@@ -251,6 +262,26 @@ class LLMEngine:
         c = model.config
         self.model = model
         self.config = c
+        # what the model caches: paged K/V for ``kv_layers`` layers, and
+        # one row per slot of each ``slot_state`` array (recurrent layers)
+        cache = model.cache_spec()
+        self.kv_layers = int(cache["kv_layers"])
+        self.slot_state = dict(cache["slot_state"])
+        if self.slot_state:
+            asked = {"kv_layout='slots'": kv_layout != "paged",
+                     "kv_dtype=": kv_dtype is not None,
+                     "host_kv_blocks=": self.host_kv_blocks > 0,
+                     "adapter_slots=": self.adapter_slots > 0,
+                     "mesh=": mesh is not None}
+            if any(asked.values()):
+                raise RecurrentStateUnsupported(
+                    f"{type(model).__name__} keeps recurrent state per "
+                    "request, which "
+                    + ", ".join(k for k, v in asked.items() if v)
+                    + " cannot carry yet")
+            # a prefix hit adopts K/V blocks and would skip the tokens
+            # that built the recurrent state
+            self.prefix_caching = False
         self.max_slots = int(max_slots)
         self.max_seq_len = int(max_seq_len or c.max_seq_len)
         if not c.use_rope and self.max_seq_len > c.max_seq_len:
@@ -274,8 +305,7 @@ class LLMEngine:
                     "weights", model.decode_state())
 
             B, S = self.max_slots, self.max_seq_len
-            nh = c.num_heads
-            hd = c.hidden_size // nh
+            nh, hd = int(cache["kv_heads"]), int(cache["head_dim"])
             dt = jnp.dtype(c.dtype)
             self._init_kv(c, B, S, nh, hd, dt)
 
@@ -384,10 +414,10 @@ class LLMEngine:
         set (``[L, B, S, nh/mp, hd]``)."""
         from .arena import KV_POOL_SPEC
         self.arena.declare("slot_k",
-                           jnp.zeros((c.num_layers, B, S, nh, hd), dt),
+                           jnp.zeros((self.kv_layers, B, S, nh, hd), dt),
                            spec=KV_POOL_SPEC)
         self.arena.declare("slot_v",
-                           jnp.zeros((c.num_layers, B, S, nh, hd), dt),
+                           jnp.zeros((self.kv_layers, B, S, nh, hd), dt),
                            spec=KV_POOL_SPEC)
 
     # the slot arena lives in the StateArena; donated-program outputs are
@@ -476,7 +506,7 @@ class LLMEngine:
     def _insert_for(self, bucket):
         fn = self._insert_jits.get(bucket)
         if fn is None:
-            L = self.config.num_layers
+            L = self.kv_layers
             nh = self.config.num_heads
             hd = self.config.hidden_size // nh
             S = self.max_seq_len

@@ -60,8 +60,9 @@ from ..profiler import flight
 from ..profiler import metrics
 from ..profiler import trace as rtrace
 from ..profiler.host_tracer import span
-from .engine import (EngineBackpressure, EngineClosed, LLMEngine, Request,
-                     _model_programs, bucket_length)
+from .engine import (EngineBackpressure, EngineClosed, LLMEngine,
+                     RecurrentStateUnsupported, Request, _model_programs,
+                     bucket_length)
 from .kvcache import (TRASH_BLOCK, BlockPool, BlockPoolExhausted,
                       HostKVTier, HostTierLost, PrefixCache,
                       blocks_for_tokens)
@@ -110,28 +111,43 @@ class PagedLLMEngine(LLMEngine):
         self.prefix = PrefixCache(self.pool) if self.prefix_caching else None
         adt = _pa.KV_DTYPES[self.kv_dtype] if self.kv_dtype else dt
         from .arena import KV_POOL_SPEC
+        L = self.kv_layers
+        # one chip's pool stores whole (8, 128) tiles of heads where that
+        # lets the block-table walk run (30 heads of 128 as 32); a pool
+        # whose head axis shards over a mesh keeps the model's own count
+        nhp = nh if self.arena.mesh is not None else _pa.pool_heads(nh, hd)
         self.arena.declare(
-            "pool_k",
-            jnp.zeros((c.num_layers, self.n_blocks, bs, nh, hd), adt),
+            "pool_k", jnp.zeros((L, self.n_blocks, bs, nhp, hd), adt),
             spec=KV_POOL_SPEC)
         self.arena.declare(
-            "pool_v",
-            jnp.zeros((c.num_layers, self.n_blocks, bs, nh, hd), adt),
+            "pool_v", jnp.zeros((L, self.n_blocks, bs, nhp, hd), adt),
             spec=KV_POOL_SPEC)
+        self._block_bytes = 2 * L * bs * nhp * hd * jnp.dtype(adt).itemsize
+        # recurrent layers: one row per slot of each array the model
+        # names, next to the pools and donated through the same programs
+        self._state_names = tuple(sorted(self.slot_state))
+        for name in self._state_names:
+            lead, per_slot, sdt = self.slot_state[name]
+            self.arena.declare(
+                "state." + name,
+                jnp.zeros(tuple(lead) + (B,) + tuple(per_slot), sdt))
+        # fixed at construction, whatever the requests' lengths
+        self._state_bytes = self.arena.device_bytes(
+            *("state." + n for n in self._state_names))
         if self.kv_dtype:
             # per-token fp32 scales at the same (layer, block, position)
             # address as the quantized tiles (donated alongside them);
             # no head axis, so they stay replicated on a mesh
             self.arena.declare(
                 "scale_k",
-                jnp.zeros((c.num_layers, self.n_blocks, bs), jnp.float32))
+                jnp.zeros((L, self.n_blocks, bs), jnp.float32))
             self.arena.declare(
                 "scale_v",
-                jnp.zeros((c.num_layers, self.n_blocks, bs), jnp.float32))
-            tile = c.num_layers * self.n_blocks * bs * nh * hd
+                jnp.zeros((L, self.n_blocks, bs), jnp.float32))
+            tile = L * self.n_blocks * bs * nhp * hd
             raw = 2 * tile * jnp.dtype(dt).itemsize
             quant = (2 * tile * jnp.dtype(adt).itemsize
-                     + 2 * c.num_layers * self.n_blocks * bs * 4)
+                     + 2 * L * self.n_blocks * bs * 4)
             counters.set_gauge("serving.kv.quant.arena_bytes", quant)
             counters.set_gauge("serving.kv.quant.bytes_saved",
                                max(raw - quant, 0))
@@ -149,7 +165,7 @@ class PagedLLMEngine(LLMEngine):
         elif self.arena.multi_device:
             self.kv_kernel = "off"
         else:
-            self.kv_kernel = _pa.kernel_mode(nh, hd)
+            self.kv_kernel = _pa.kernel_mode(nhp, hd)
         if self.kv_kernel == "pallas":
             _pa.preload()
         # per-slot block tables (host mirror; rides decode as an operand)
@@ -172,9 +188,9 @@ class PagedLLMEngine(LLMEngine):
         if self.prefix is not None:
             self.prefix.tier = self._host_tier
         # one host buffer spec per block: K/V tiles (+ scale rows)
-        spec = [((c.num_layers, bs, nh, hd), np.dtype(adt))] * 2
+        spec = [((L, bs, nhp, hd), np.dtype(adt))] * 2
         if self.kv_dtype:
-            spec += [((c.num_layers, bs), np.dtype(np.float32))] * 2
+            spec += [((L, bs), np.dtype(np.float32))] * 2
         self._host_spec = tuple(spec)
         self._req_host = {}    # rid -> {"idx": set[int], "lost": bool}
         self._held_idle = {}   # rid -> idle scheduler steps while held
@@ -240,8 +256,19 @@ class PagedLLMEngine(LLMEngine):
     def _sv(self, v):
         self.arena.bind("scale_v", v)
 
+    @property
+    def _st(self):
+        """The per-slot state arrays of a model with recurrent layers,
+        ``{name: array}`` as the model's programs take them."""
+        return {n: self.arena.get("state." + n) for n in self._state_names}
+
+    @_st.setter
+    def _st(self, v):
+        for n in self._state_names:
+            self.arena.bind("state." + n, None if v is None else v[n])
+
     def release_kv(self):
-        self._pk = self._pv = self._sk = self._sv = None
+        self._pk = self._pv = self._sk = self._sv = self._st = None
         if self.adapters is not None:
             self.adapters.release_slabs()
 
@@ -350,6 +377,18 @@ class PagedLLMEngine(LLMEngine):
                 # them); donation indices are untouched
                 lora = self.adapters is not None
 
+                if self.slot_state:
+                    def pchunk(w, ids, start, length, bt, pk, pv, st, slot,
+                               key_data, do_sample, temp, top_k, top_p):
+                        counters.inc("serving.retraces")  # trace-time only
+                        pk, pv, st, logits = model.prefill_paged(
+                            w, ids, start, length, bt, pk, pv, st, slot)
+                        tok, new_key = LLMEngine._first_token(
+                            logits, jax.random.wrap_key_data(key_data),
+                            do_sample, temp, top_k, top_p)
+                        return pk, pv, st, tok, new_key
+                    return jax.jit(pchunk, donate_argnums=(5, 6, 7))
+
                 if self.kv_dtype:
                     def pchunk(w, ids, start, length, bt, pk, pv, sk, sv,
                                key_data, do_sample, temp, top_k, top_p,
@@ -418,6 +457,19 @@ class PagedLLMEngine(LLMEngine):
                     return nxt, jax.random.key_data(new_keys)
 
                 lora = self.adapters is not None
+
+                if self.slot_state:
+                    def decode(w, pk, pv, st, bt, tok, pos, running,
+                               keys_data, do_sample, temp, top_k, top_p):
+                        counters.inc("serving.retraces")
+                        logits, pk, pv, st = model.decode_paged(
+                            w, tok, pos, bt, pk, pv, st, running,
+                            kernel=mode)
+                        nxt, new_keys = sample_next(
+                            logits, keys_data, do_sample, temp, top_k,
+                            top_p)
+                        return nxt, pk, pv, st, new_keys
+                    return jax.jit(decode, donate_argnums=(1, 2, 3))
 
                 if self.kv_dtype:
                     def decode(w, pk, pv, sk, sv, bt, tok, pos, keys_data,
@@ -1069,7 +1121,11 @@ class PagedLLMEngine(LLMEngine):
                 # match the chunk's batch) as trailing operands
                 tail = tail + (self.adapters.slabs(), self.arena.operand(
                     np.asarray([self._aid[slot]], np.int32)))
-            if self.kv_dtype:
+            if self.slot_state:
+                pargs = (*head, self._pk, self._pv, self._st,
+                         np.int32(slot), *tail)
+                dn = (5, 6, 7)
+            elif self.kv_dtype:
                 pargs = (*head, self._pk, self._pv, self._sk, self._sv,
                          *tail)
                 dn = (5, 6, 7, 8)
@@ -1081,7 +1137,9 @@ class PagedLLMEngine(LLMEngine):
             self._maybe_audit(pname, pf, *pargs, donate_argnums=dn)
         with span("serving.prefill.dispatch"):
             _dt = _devicetime.note(pname)
-            if self.kv_dtype:
+            if self.slot_state:
+                self._pk, self._pv, self._st, tok, new_key = pf(*pargs)
+            elif self.kv_dtype:
                 (self._pk, self._pv, self._sk, self._sv, tok,
                  new_key) = pf(*pargs)
             else:
@@ -1176,7 +1234,13 @@ class PagedLLMEngine(LLMEngine):
                 aid_eff = np.where(self._running, self._aid,
                                    0).astype(np.int32)
                 tail = tail + (self.adapters.slabs(), op(aid_eff))
-            if self.kv_dtype:
+            if self.slot_state:
+                # the rows' recurrent state rides next to the pools; a row
+                # that is not running keeps its own bit for bit
+                dargs = (self._w, self._pk, self._pv, self._st, *tail[:3],
+                         op(self._running), *tail[3:])
+                dn = (1, 2, 3)
+            elif self.kv_dtype:
                 dargs = (self._w, self._pk, self._pv, self._sk, self._sv,
                          *tail)
                 dn = (1, 2, 3, 4)
@@ -1188,7 +1252,9 @@ class PagedLLMEngine(LLMEngine):
             self._maybe_audit(dname, dec, *dargs, donate_argnums=dn)
         with span("serving.decode.dispatch"):
             _dt = _devicetime.note(dname)
-            if self.kv_dtype:
+            if self.slot_state:
+                nxt, self._pk, self._pv, self._st, new_keys = dec(*dargs)
+            elif self.kv_dtype:
                 (nxt, self._pk, self._pv, self._sk, self._sv,
                  new_keys) = dec(*dargs)
             else:
@@ -1225,6 +1291,7 @@ class PagedLLMEngine(LLMEngine):
         nothing.  KV is valid for positions ``[0, pos)``; the last
         committed token (``tok``) was sampled but never written back —
         exactly the prefix-tree donation contract."""
+        self._refuse_recurrent("export_request")
         with self._cond:
             slot = req.slot
             if slot is None or req.state != "held":
@@ -1274,6 +1341,7 @@ class PagedLLMEngine(LLMEngine):
         ``"running"`` with the migrated tokens replayed into its stream
         state, so its next emitted token continues the source's ITL
         chain."""
+        self._refuse_recurrent("adopt_migration")
         if (self.pool.block_size != mig["block_size"]
                 or self.kv_dtype != mig["kv_dtype"]):
             raise ValueError(
@@ -1447,6 +1515,12 @@ class PagedLLMEngine(LLMEngine):
         flight.record("serving.kv.adopt", rid=req.rid, **info)
         return req, info
 
+    def _refuse_recurrent(self, what):
+        if self.slot_state:
+            raise RecurrentStateUnsupported(
+                f"{what} moves K/V blocks and would leave the request's "
+                f"recurrent state ({', '.join(self._state_names)}) behind")
+
     def _adopt_extra(self, slot, req, mig):
         """Subclass hook: rebuild engine-local state the migration
         payload does not carry (the speculative engine re-prefills its
@@ -1533,8 +1607,10 @@ class PagedLLMEngine(LLMEngine):
             with span("serving.admit"):
                 self._admit(events)
             if sp.live:   # counted only for someone who is profiling
-                sp.note(blocks_live=self._blocks_live(),
-                        blocks_total=self.pool.capacity)
+                live = self._blocks_live()
+                sp.note(blocks_live=live, blocks_total=self.pool.capacity,
+                        kv_live_bytes=live * self._block_bytes,
+                        state_bytes=self._state_bytes)
         counters.set_gauge(
             "serving.slot_occupancy",
             sum(r is not None for r in self._slots) / self.max_slots)
@@ -1560,6 +1636,7 @@ class PagedLLMEngine(LLMEngine):
         acquisition; the RLock makes the nested base call atomic)."""
         with self._cond:
             st = super().stats()
+            live = self._blocks_live()
             st.update({
                 "kv_layout": "paged",
                 "kv_dtype": self.kv_dtype,
@@ -1570,7 +1647,10 @@ class PagedLLMEngine(LLMEngine):
                 "blocks_total": self.pool.capacity,
                 "blocks_free": self.pool.free_blocks,
                 "blocks_used": self.pool.used_blocks,
-                "blocks_live": self._blocks_live(),
+                "blocks_live": live,
+                "kv_live_bytes": live * self._block_bytes,
+                "state_bytes": self._state_bytes,
+                "prefix_cache": self.prefix is not None,
                 "block_utilization": (self.pool.used_blocks
                                       / max(1, self.pool.capacity)),
                 "prefix_hits": self.kv_prefix_hits,

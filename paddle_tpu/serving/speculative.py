@@ -68,7 +68,8 @@ from ..profiler import devicetime as _devicetime
 from ..profiler import flight
 from ..profiler import trace as rtrace
 from ..profiler.host_tracer import span
-from .engine import _model_programs, bucket_length
+from .engine import (RecurrentStateUnsupported, _model_programs,
+                     bucket_length)
 from .kvcache import blocks_for_tokens
 from .paged import PagedLLMEngine
 from .sampling import filter_logits, residual_sample
@@ -177,6 +178,11 @@ class SpeculativeLLMEngine(PagedLLMEngine):
                 f"draft vocab ({draft.config.vocab_size}) != target vocab "
                 f"({model.config.vocab_size}); speculative acceptance "
                 "compares the two distributions token for token")
+        if any(m.cache_spec()["slot_state"] for m in (model, draft)):
+            raise RecurrentStateUnsupported(
+                "draft_model= with a target or a draft that has recurrent "
+                "layers: verification rolls K/V back by position, and a "
+                "recurrent state has none")
         self.draft_model = draft
         self.spec_k = k
         super().__init__(model, *args, **kw)

@@ -1708,6 +1708,43 @@ def run():
         if ad_reuse.get(k, 0):
             violations[f"adapters:reuse:{k}"] = (ad_reuse.get(k, 0), 0)
 
+    # ---- recurrent-state gate: a second kind of cache, no retrace --------
+    # A model with recurrent layers keeps one row of state per slot next
+    # to the block pool (models/olmo_hybrid.py).  The state arrays are
+    # donated through the same chunk and decode programs, so the measure
+    # window must trace nothing, and a request served alone in a slot
+    # that another request has just left must read what the model's own
+    # forward pass puts first (nothing of the last owner's state is left).
+    # Last of the gates: its first steps compile, and their gaps must not
+    # reach the health gate's windows over the process-wide histograms.
+    from paddle_tpu.models.olmo_hybrid import (OlmoHybridConfig,
+                                               OlmoHybridForCausalLM)
+    hmodel = OlmoHybridForCausalLM(OlmoHybridConfig(
+        vocab_size=64, hidden_size=32, intermediate_size=64, num_layers=4,
+        num_heads=2, linear_num_heads=2, linear_key_head_dim=8,
+        linear_value_head_dim=16, max_seq_len=64))
+    hmodel.eval()
+    heng = LLMEngine(hmodel, max_slots=1, max_seq_len=32, min_bucket=4,
+                     kv_layout="paged", block_size=4, prefill_chunk=8)
+    pserve(heng, SERVE_LENS_WARM)
+    hbefore = counters.snapshot()
+    hhs = pserve(heng, SERVE_LENS_MEASURE)
+    hsteady = counters.delta(hbefore)
+    for k in ("serving.retraces", "jit.traces"):
+        if hsteady.get(k, 0):
+            violations[f"recurrent:{k}"] = (hsteady.get(k, 0), 0)
+    hst = heng.stats()
+    if not (hst["state_bytes"] > 0 and hst["prefix_cache"] is False):
+        violations["recurrent:stats"] = (
+            (hst["state_bytes"], hst["prefix_cache"]), "(>0, False)")
+    for h in hhs:
+        ids = np.concatenate([h.prompt, np.asarray(h.tokens[:-1], np.int32)])
+        lg = np.asarray(hmodel.forward_logits(
+            hmodel.decode_state(), ids[None]))[0][len(h.prompt) - 1:]
+        if lg.argmax(-1).tolist() != h.tokens:
+            violations[f"recurrent:identity@{h.rid}"] = (
+                h.tokens, lg.argmax(-1).tolist())
+
     result = {"metric": "steady_state_counter_violations",
               "value": len(violations),
               "unit": f"violations/{MEASURE} steps "

@@ -344,8 +344,12 @@ class TestEngineStep:
     def test_step_counts(self, paged_steps):
         counts = [e[5] for e in paged_steps["events"]
                   if e[0] == "serving.step"]
-        # what a metric reads (kv_blocks_live_peak_share), and no more
-        assert all(set(c) == {"blocks_live", "blocks_total"} for c in counts)
+        # what a metric reads (kv_blocks_live_peak_share and, beside a
+        # model's recurrent state, recurrent_state_share), and no more
+        assert all(set(c) == {"blocks_live", "blocks_total",
+                              "kv_live_bytes", "state_bytes"}
+                   for c in counts)
+        assert all(c["state_bytes"] == 0 for c in counts)    # a GPT has none
         assert all(0 <= c["blocks_live"] <= c["blocks_total"] for c in counts)
         assert max(c["blocks_live"] for c in counts) > 0
         assert [c["blocks_live"] for c in counts] \

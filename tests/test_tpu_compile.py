@@ -105,9 +105,12 @@ def test_flash_fwd_bwd_compiles(chip, b, s, h, d):
 
 # (rows, blocks a row, blocks, layers, heads, head_dim): the chat cell's
 # own arena (GPT-3 XL, 16 slots x 2048 tokens in 16-token blocks) and what
-# one chip of an mp2 mesh holds of it
+# one chip of an mp2 mesh holds of it; and the docqa cell's (Olmo-Hybrid's 4
+# full-attention layers, 30 heads of 128 stored as 32, 16 slots x 16,896)
 _PAGED_SHAPES = [pytest.param(16, 128, 1025, 24, 16, 128, id="gpt1.3b-chat"),
-                 pytest.param(16, 128, 1025, 24, 8, 128, id="gpt1.3b-mp2")]
+                 pytest.param(16, 128, 1025, 24, 8, 128, id="gpt1.3b-mp2"),
+                 pytest.param(16, 1056, 4097, 4, 32, 128,
+                              id="olmo-hybrid-docqa")]
 
 
 @pytest.mark.parametrize("B,max_blocks,n_blocks,L,nh,hd", _PAGED_SHAPES)
@@ -178,3 +181,53 @@ def test_rms_norm_compiles(chip):
     # platform gate, which sees the CPU here
     _compile(rn._rms_norm_pallas, _sds(chip, (8192, 1536), jnp.bfloat16),
              _sds(chip, (1536,), jnp.bfloat16), kernels=["rms_norm"])
+
+
+def test_hybrid_decode_step_updates_both_caches_in_place(chip):
+    """``OlmoHybridForCausalLM.decode_paged`` at the docqa cell's shape:
+    the block-table walk is in it over the padded pool, pools and recurrent
+    state alias through, no stacked weight is copied or re-laid out for the
+    scan over periods (a slice of a period's weights once cost 2 GB of
+    temporaries a launch), and nothing the size of a pool or of the state
+    is kept as a temporary."""
+    import json
+    import os
+    from paddle_tpu.models.olmo_hybrid import (OlmoHybridConfig,
+                                               OlmoHybridForCausalLM,
+                                               param_shapes)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "olmo-hybrid-7b.json")) as f:
+        hf = json.load(f)
+    real = OlmoHybridForCausalLM.__new__(OlmoHybridForCausalLM)
+    real.__dict__["config"] = c = OlmoHybridConfig.from_hf(
+        hf, dtype="bfloat16")
+    H, dk, dv = 30, 96, 192
+    w = {n: _sds(chip, shape, dt)
+         for n, (shape, _, dt) in param_shapes(c).items()}
+    assert w["gate_w"].shape == (16, 3840, 11008)
+    assert w["lin_qkv_w"].shape == (12, 3840, 11520)
+    B, bs, max_blocks, n_blocks, nhp, hd = 16, 16, 1056, 4097, 32, 128
+    assert pa.pool_heads(c.num_heads, hd) == nhp
+    pool = _sds(chip, (4, n_blocks, bs, nhp, hd), jnp.bfloat16)
+    spec = real.cache_spec()["slot_state"]
+    st = {n: _sds(chip, tuple(lead) + (B,) + tuple(per), dt)
+          for n, (lead, per, dt) in spec.items()}
+
+    def decode(w, pk, pv, st, bt, tok, pos, running):
+        return real.decode_paged(w, tok, pos, bt, pk, pv, st, running,
+                                 kernel="pallas")
+
+    compiled = jax.jit(decode, donate_argnums=(1, 2, 3)).lower(
+        w, pool, pool, st, _sds(chip, (B, max_blocks), jnp.int32),
+        _sds(chip, (B,), jnp.int32), _sds(chip, (B,), jnp.int32),
+        _sds(chip, (B,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert re.search(r"%paged_decode_attn(\.\d+)? = [^\n]*custom-call\(",
+                     text)
+    assert not re.search(r"bf16\[(16|12|4),3840,\d+\]\S* copy\(", text)
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 16 * 2 ** 20, mem
+    state_bytes = 12 * B * (H * dk * dv * 4 + 3 * 11520 * 2)
+    assert mem.alias_size_in_bytes >= (
+        2 * 4 * n_blocks * bs * nhp * hd * 2 + state_bytes), mem
