@@ -33,6 +33,8 @@ Well-known names (see README "Observability" for the full table):
   optimizer.steps
   serving.requests / serving.prefill_batches / serving.decode_steps
   serving.decode_tokens / serving.evictions / serving.evictions.<reason>
+  serving.decode.sampled_steps (decode launches with a running
+      do_sample row: the ones whose sampling tail ran the filters)
   serving.retraces (serving program compiles; 0 in steady state)
   serving.queue_wait_ns
   serving.deadline_expired (queued past-deadline, evicted pre-prefill)

@@ -51,7 +51,7 @@ from ..profiler import metrics
 from ..profiler import trace as rtrace
 from ..profiler.host_tracer import span
 from .arena import StateArena
-from .sampling import filter_logits
+from .sampling import next_tokens
 
 # the arena/chunk donations are a no-op on CPU backends; the warning would
 # fire on every serving step there
@@ -469,15 +469,14 @@ class LLMEngine:
 
     # -- compiled programs ---------------------------------------------------
     @staticmethod
-    def _first_token(logits, key, do_sample, temp, top_k, top_p):
-        """Sample the prefill's first token: identical key discipline and
-        math to generate's post-prefill draw."""
-        key, k0 = jax.random.split(key)
-        flg = filter_logits(logits, temp, top_k, top_p)
-        sampled = jax.random.categorical(k0, flg, axis=-1)
-        greedy = jnp.argmax(logits, axis=-1)
-        tok = jnp.where(do_sample, sampled, greedy).astype(jnp.int32)
-        return tok[0], jax.random.key_data(key)
+    def _first_token(logits, key_data, do_sample, temp, top_k, top_p):
+        """The prefill's first token from ``logits[1, V]``: the shared
+        sampling tail over a batch of one (identical key discipline and
+        math to generate's post-prefill draw)."""
+        nxt, new_keys = next_tokens(
+            logits, key_data[None],
+            *(jnp.reshape(x, (1,)) for x in (do_sample, temp, top_k, top_p)))
+        return nxt[0], new_keys[0]
 
     def _prefill_for(self, bucket):
         fn = self._prefill_jits.get(bucket)
@@ -490,8 +489,7 @@ class LLMEngine:
                     counters.inc("serving.retraces")  # trace-time only
                     ck, cv, logits = model.prefill_slot(w, ids, length)
                     tok, new_key = LLMEngine._first_token(
-                        logits, jax.random.wrap_key_data(key_data),
-                        do_sample, temp, top_k, top_p)
+                        logits, key_data, do_sample, temp, top_k, top_p)
                     return ck, cv, tok, new_key
                 return jax.jit(prefill)
             key = self.arena.decorate("prefill_slot")
@@ -540,20 +538,9 @@ class LLMEngine:
                            top_k, top_p):
                     counters.inc("serving.retraces")
                     logits, ck, cv = model.decode_slots(w, tok, pos, ck, cv)
-                    keys = jax.random.wrap_key_data(keys_data)  # [B] typed
-                    pair = jax.vmap(jax.random.split)(keys)     # [B, 2]
-                    new_keys, kstep = pair[:, 0], pair[:, 1]
-                    # per-row draw over [1, V] with the row's own key —
-                    # exactly generate's categorical for a batch-1 request
-                    sampled = jax.vmap(
-                        lambda k, lg, t, tk, tp: jax.random.categorical(
-                            k, filter_logits(lg[None], t, tk, tp),
-                            axis=-1)[0]
-                    )(kstep, logits, temp, top_k, top_p)
-                    greedy = jnp.argmax(logits, axis=-1)
-                    nxt = jnp.where(do_sample, sampled,
-                                    greedy).astype(jnp.int32)
-                    return nxt, ck, cv, jax.random.key_data(new_keys)
+                    nxt, new_keys = next_tokens(
+                        logits, keys_data, do_sample, temp, top_k, top_p)
+                    return nxt, ck, cv, new_keys
                 return jax.jit(decode, donate_argnums=(1, 2))
             key = self.arena.decorate("decode_slots")
             with span("serving.program_build", level=0, key=key):
@@ -877,6 +864,10 @@ class LLMEngine:
         # one token emitted per active slot this launch
         self._note_decode(len(active), time.perf_counter() - t0)
         counters.inc("serving.decode_steps")
+        # every slot that holds a request runs here, and _finish clears a
+        # freed slot's flag: the array as it stands is the running rows'
+        counters.inc("serving.decode.sampled_steps",
+                     int(self._dosample.any()))
         counters.inc("serving.decode_tokens", len(active))
         with span("serving.decode.emit"):
             for s, req in active:

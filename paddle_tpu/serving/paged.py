@@ -66,6 +66,7 @@ from .engine import (EngineBackpressure, EngineClosed, LLMEngine,
 from .kvcache import (TRASH_BLOCK, BlockPool, BlockPoolExhausted,
                       HostKVTier, HostTierLost, PrefixCache,
                       blocks_for_tokens)
+from .sampling import next_tokens
 
 __all__ = ["PagedLLMEngine"]
 
@@ -384,8 +385,7 @@ class PagedLLMEngine(LLMEngine):
                         pk, pv, st, logits = model.prefill_paged(
                             w, ids, start, length, bt, pk, pv, st, slot)
                         tok, new_key = LLMEngine._first_token(
-                            logits, jax.random.wrap_key_data(key_data),
-                            do_sample, temp, top_k, top_p)
+                            logits, key_data, do_sample, temp, top_k, top_p)
                         return pk, pv, st, tok, new_key
                     return jax.jit(pchunk, donate_argnums=(5, 6, 7))
 
@@ -399,8 +399,7 @@ class PagedLLMEngine(LLMEngine):
                             w, ids, start, length, bt, pk, pv, sk, sv,
                             adapters=aw, adapter_ids=aid)
                         tok, new_key = LLMEngine._first_token(
-                            logits, jax.random.wrap_key_data(key_data),
-                            do_sample, temp, top_k, top_p)
+                            logits, key_data, do_sample, temp, top_k, top_p)
                         return pk, pv, sk, sv, tok, new_key
                     return jax.jit(pchunk, donate_argnums=(5, 6, 7, 8))
 
@@ -412,8 +411,7 @@ class PagedLLMEngine(LLMEngine):
                         w, ids, start, length, bt, pk, pv,
                         adapters=aw, adapter_ids=aid)
                     tok, new_key = LLMEngine._first_token(
-                        logits, jax.random.wrap_key_data(key_data),
-                        do_sample, temp, top_k, top_p)
+                        logits, key_data, do_sample, temp, top_k, top_p)
                     return pk, pv, tok, new_key
                 return jax.jit(pchunk, donate_argnums=(5, 6))
             key = self._prog_key("prefill_paged")
@@ -440,22 +438,6 @@ class PagedLLMEngine(LLMEngine):
             head_axis = "mp" if mesh is not None else None
 
             def build():
-                def sample_next(logits, keys_data, do_sample, temp, top_k,
-                                top_p):
-                    keys = jax.random.wrap_key_data(keys_data)
-                    pair = jax.vmap(jax.random.split)(keys)
-                    new_keys, kstep = pair[:, 0], pair[:, 1]
-                    from .sampling import filter_logits
-                    sampled = jax.vmap(
-                        lambda k, lg, t, tk, tp: jax.random.categorical(
-                            k, filter_logits(lg[None], t, tk, tp),
-                            axis=-1)[0]
-                    )(kstep, logits, temp, top_k, top_p)
-                    greedy = jnp.argmax(logits, axis=-1)
-                    nxt = jnp.where(do_sample, sampled,
-                                    greedy).astype(jnp.int32)
-                    return nxt, jax.random.key_data(new_keys)
-
                 lora = self.adapters is not None
 
                 if self.slot_state:
@@ -465,9 +447,8 @@ class PagedLLMEngine(LLMEngine):
                         logits, pk, pv, st = model.decode_paged(
                             w, tok, pos, bt, pk, pv, st, running,
                             kernel=mode)
-                        nxt, new_keys = sample_next(
-                            logits, keys_data, do_sample, temp, top_k,
-                            top_p)
+                        nxt, new_keys = next_tokens(
+                            logits, keys_data, do_sample, temp, top_k, top_p)
                         return nxt, pk, pv, st, new_keys
                     return jax.jit(decode, donate_argnums=(1, 2, 3))
 
@@ -480,9 +461,8 @@ class PagedLLMEngine(LLMEngine):
                             w, tok, pos, bt, pk, pv, sk, sv, kernel=mode,
                             mesh=mesh, head_axis=head_axis,
                             adapters=aw, adapter_ids=aid)
-                        nxt, new_keys = sample_next(
-                            logits, keys_data, do_sample, temp, top_k,
-                            top_p)
+                        nxt, new_keys = next_tokens(
+                            logits, keys_data, do_sample, temp, top_k, top_p)
                         return nxt, pk, pv, sk, sv, new_keys
                     return jax.jit(decode, donate_argnums=(1, 2, 3, 4))
 
@@ -494,9 +474,8 @@ class PagedLLMEngine(LLMEngine):
                         w, tok, pos, bt, pk, pv, kernel=mode,
                         mesh=mesh, head_axis=head_axis,
                         adapters=aw, adapter_ids=aid)
-                    nxt, new_keys = sample_next(
-                        logits, keys_data, do_sample, temp, top_k,
-                        top_p)
+                    nxt, new_keys = next_tokens(
+                        logits, keys_data, do_sample, temp, top_k, top_p)
                     return nxt, pk, pv, new_keys
                 return jax.jit(decode, donate_argnums=(1, 2))
             key = self._prog_key("decode_paged")
@@ -1219,6 +1198,11 @@ class PagedLLMEngine(LLMEngine):
             bt_eff = np.where(self._running[:, None], self._bt,
                               0).astype(np.int32)
             pos_eff = np.where(self._running, self._pos, 0).astype(np.int32)
+            # a row that holds a slot without running (parked for
+            # migration, adopted and not yet resumed) keeps its request's
+            # flag; masked, so it cannot send a greedy batch's launch down
+            # the sampling tail's long branch
+            ds_eff = self._dosample & self._running
             t0 = time.perf_counter()
             tr_on = rtrace.enabled()
             t0_tr = time.perf_counter_ns() if tr_on else 0
@@ -1226,7 +1210,7 @@ class PagedLLMEngine(LLMEngine):
             op = self.arena.operand
             tail = (op(bt_eff), op(self._tok),
                     op(pos_eff), op(self._keys),
-                    op(self._dosample), op(self._temp),
+                    op(ds_eff), op(self._temp),
                     op(self._topk), op(self._topp))
             if self.adapters is not None:
                 # non-running rows decode against the base row (id 0) —
@@ -1273,6 +1257,7 @@ class PagedLLMEngine(LLMEngine):
         # one token emitted per active slot this launch
         self._note_decode(len(active), time.perf_counter() - t0)
         counters.inc("serving.decode_steps")
+        counters.inc("serving.decode.sampled_steps", int(ds_eff.any()))
         counters.inc("serving.decode_tokens", len(active))
         if self.kv_dtype:
             counters.inc("serving.kv.quant.decode_tokens", len(active))

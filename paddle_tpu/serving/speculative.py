@@ -72,7 +72,7 @@ from .engine import (RecurrentStateUnsupported, _model_programs,
                      bucket_length)
 from .kvcache import blocks_for_tokens
 from .paged import PagedLLMEngine
-from .sampling import filter_logits, residual_sample
+from .sampling import filter_logits, next_tokens, residual_sample
 
 __all__ = ["SpeculativeLLMEngine"]
 
@@ -326,22 +326,6 @@ class SpeculativeLLMEngine(PagedLLMEngine):
                 dc.num_heads, dc.hidden_size // dc.num_heads))
 
             def build():
-                def sample_q(logits, keys_data, do_sample, temp, top_k,
-                             top_p):
-                    keys = jax.random.wrap_key_data(keys_data)
-                    pair = jax.vmap(jax.random.split)(keys)
-                    new_keys, kstep = pair[:, 0], pair[:, 1]
-                    flg = jax.vmap(lambda lg, t, tk, tp: filter_logits(
-                        lg[None], t, tk, tp)[0])(logits, temp, top_k,
-                                                 top_p)
-                    sampled = jax.vmap(lambda kk, lg: jax.random.categorical(
-                        kk, lg, axis=-1))(kstep, flg)
-                    greedy = jnp.argmax(logits, axis=-1)
-                    nxt = jnp.where(do_sample, sampled,
-                                    greedy).astype(jnp.int32)
-                    qdist = jax.nn.softmax(flg, axis=-1)
-                    return nxt, qdist, jax.random.key_data(new_keys)
-
                 if self.kv_dtype:
                     def dstep(dw, dk, dv, dsk, dsv, bt, tok, pos,
                               keys_data, do_sample, temp, top_k, top_p):
@@ -349,9 +333,9 @@ class SpeculativeLLMEngine(PagedLLMEngine):
                         logits, dk, dv, dsk, dsv = draft.decode_paged(
                             dw, tok, pos, bt, dk, dv, dsk, dsv,
                             kernel=mode)
-                        nxt, qdist, new_keys = sample_q(
+                        nxt, qdist, new_keys = next_tokens(
                             logits, keys_data, do_sample, temp, top_k,
-                            top_p)
+                            top_p, with_dist=True)
                         return nxt, qdist, dk, dv, dsk, dsv, new_keys
                     return jax.jit(dstep, donate_argnums=(1, 2, 3, 4))
 
@@ -360,9 +344,9 @@ class SpeculativeLLMEngine(PagedLLMEngine):
                     counters.inc("serving.retraces")
                     logits, dk, dv = draft.decode_paged(
                         dw, tok, pos, bt, dk, dv, kernel=mode)
-                    nxt, qdist, new_keys = sample_q(
+                    nxt, qdist, new_keys = next_tokens(
                         logits, keys_data, do_sample, temp, top_k,
-                        top_p)
+                        top_p, with_dist=True)
                     return nxt, qdist, dk, dv, new_keys
                 return jax.jit(dstep, donate_argnums=(1, 2))
             self._pdraft_jit = self.arena.program(
@@ -683,7 +667,8 @@ class SpeculativeLLMEngine(PagedLLMEngine):
             op = self.arena.operand
             cur = op(self._tok)
             dkeys = op(self._dkeys)
-            dosample = op(self._dosample)
+            ds_eff = self._dosample & self._running
+            dosample = op(ds_eff)
             temp = op(self._temp)
             topk = op(self._topk)
             topp = op(self._topp)
@@ -755,6 +740,7 @@ class SpeculativeLLMEngine(PagedLLMEngine):
         self._dkeys = np.array(np.asarray(dkeys))
         counters.inc("serving.spec.verify_steps")
         counters.inc("serving.decode_steps")
+        counters.inc("serving.decode.sampled_steps", int(ds_eff.any()))
         emitted = int(sum(int(n_emit[s]) for s, _ in active))
         self._note_decode(emitted, time.perf_counter() - t0)
         counters.inc("serving.decode_tokens", emitted)

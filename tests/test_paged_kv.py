@@ -325,6 +325,46 @@ class TestPagedIdentity:
         assert len(pool._free) + live == pool.capacity
 
 
+class TestSamplingTailPredicate:
+    def test_parked_sampling_row_keeps_greedy_batch_on_short_branch(self):
+        """A sampling request that holds a slot WITHOUT running (parked
+        after prefill for migration) keeps its flag on the host, but the
+        decode launch uploads ``False`` for its row: the greedy rows
+        decoding beside it never take the sampling tail's long branch
+        (``serving.decode.sampled_steps`` stays 0), and their tokens are
+        their own."""
+        m = _model()
+        rng = np.random.default_rng(40)
+        ps = rng.integers(0, 64, size=6).tolist()
+        pg = [rng.integers(0, 64, size=n).tolist() for n in (5, 7)]
+        eng = _paged(m)
+        before = counters.snapshot()
+        held = eng.add_request(ps, max_new_tokens=6, do_sample=True,
+                               top_p=0.9, seed=3, hold_after_prefill=True)
+        while held.state != "held":
+            eng.step()
+        dec, uploads = eng._pdecode(), []
+
+        def shim(*args):       # (w, pk, pv, bt, tok, pos, keys, do_sample, ..)
+            uploads.append(np.asarray(args[7]).copy())
+            return dec(*args)
+        eng._pdecode_jit = shim
+        hs = [eng.add_request(p, max_new_tokens=5) for p in pg]
+        _run(eng, hs)
+        d = counters.delta(before)
+        assert d["serving.decode_steps"] == len(uploads) == 4
+        assert d.get("serving.decode.sampled_steps", 0) == 0
+        assert "serving.decode.sampled_steps" in counters.snapshot()
+        assert held.state == "held" and eng._dosample[held.slot]
+        assert uploads[0].dtype == np.bool_
+        assert not any(u.any() for u in uploads)
+        for h, p in zip(hs, pg):
+            assert list(h.tokens) == _ref_generate(m, p, 5)
+        held.cancel()
+        eng.step()
+        assert held.is_finished and not eng._dosample.any()
+
+
 class TestChunkedPrefillInterleaving:
     def test_decode_not_starved_by_long_prefill(self):
         m = _model()

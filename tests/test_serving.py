@@ -513,3 +513,287 @@ class TestFleetSatellites:
         assert h2.tokens == []
         assert d.get("serving.deadline_expired", 0) == 1
         assert d.get("serving.prefill_batches", 0) == 1   # h1 only
+
+
+# -- the one sampling tail (serving.sampling.next_tokens) --------------------
+_V = 384
+# (do_sample, temperature, top_k, top_p) per row of a batch of four
+_KNOB_MIXES = {
+    "all_greedy": [(False, 1.0, 0, 1.0)] * 4,
+    "all_sampling_neutral": [(True, 1.0, 0, 1.0)] * 4,
+    "top_k_only": [(True, 0.8, 5, 1.0), (True, 1.0, 40, 1.0),
+                   (True, 1.3, 1, 1.0), (True, 1.0, 7, 1.0)],
+    "top_p_only": [(True, 1.0, 0, 0.9), (True, 0.7, 0, 0.5),
+                   (True, 1.0, 0, 0.99), (True, 1.2, 0, 0.8)],
+    "greedy_and_filtered": [(False, 1.0, 0, 1.0), (True, 0.8, 8, 0.9),
+                            (False, 0.7, 6, 0.5), (True, 1.0, 0, 0.95)],
+}
+
+
+def _tail_operands(mix, seed=0, scale=3.0):
+    import jax
+    import jax.numpy as jnp
+    rows = _KNOB_MIXES[mix]
+    rng = np.random.default_rng(seed)
+    logits = jnp.asarray(rng.normal(0, scale, (len(rows), _V)), jnp.float32)
+    keys = jax.vmap(jax.random.key_data)(
+        jax.vmap(jax.random.key)(jnp.arange(len(rows)) + 11 * seed))
+    ds, t, tk, tp = zip(*rows)
+    return (logits, keys, jnp.asarray(ds, jnp.bool_),
+            jnp.asarray(t, jnp.float32), jnp.asarray(tk, jnp.int32),
+            jnp.asarray(tp, jnp.float32))
+
+
+def _old_decode_tail(logits, keys_data, do_sample, temp, top_k, top_p):
+    """The tail as ``sample_next`` / ``engine._decode`` wrote it out before
+    ``next_tokens``: every row filtered and drawn, then selected."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.serving.sampling import filter_logits
+    keys = jax.random.wrap_key_data(keys_data)
+    pair = jax.vmap(jax.random.split)(keys)
+    new_keys, kstep = pair[:, 0], pair[:, 1]
+    sampled = jax.vmap(
+        lambda k, lg, t, tk, tp: jax.random.categorical(
+            k, filter_logits(lg[None], t, tk, tp), axis=-1)[0]
+    )(kstep, logits, temp, top_k, top_p)
+    greedy = jnp.argmax(logits, axis=-1)
+    nxt = jnp.where(do_sample, sampled, greedy).astype(jnp.int32)
+    return nxt, jax.random.key_data(new_keys)
+
+
+def _old_first_token(logits, key_data, do_sample, temp, top_k, top_p):
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.serving.sampling import filter_logits
+    key, k0 = jax.random.split(jax.random.wrap_key_data(key_data))
+    flg = filter_logits(logits, temp, top_k, top_p)
+    sampled = jax.random.categorical(k0, flg, axis=-1)
+    greedy = jnp.argmax(logits, axis=-1)
+    tok = jnp.where(do_sample, sampled, greedy).astype(jnp.int32)
+    return tok[0], jax.random.key_data(key)
+
+
+def _old_draft_tail(logits, keys_data, do_sample, temp, top_k, top_p):
+    """``speculative.sample_q`` as it was: the draw and the filtered
+    distribution it was drawn from."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.serving.sampling import filter_logits
+    keys = jax.random.wrap_key_data(keys_data)
+    pair = jax.vmap(jax.random.split)(keys)
+    new_keys, kstep = pair[:, 0], pair[:, 1]
+    flg = jax.vmap(lambda lg, t, tk, tp: filter_logits(
+        lg[None], t, tk, tp)[0])(logits, temp, top_k, top_p)
+    sampled = jax.vmap(lambda kk, lg: jax.random.categorical(
+        kk, lg, axis=-1))(kstep, flg)
+    greedy = jnp.argmax(logits, axis=-1)
+    nxt = jnp.where(do_sample, sampled, greedy).astype(jnp.int32)
+    return nxt, jax.nn.softmax(flg, axis=-1), jax.random.key_data(new_keys)
+
+
+def _sorts_outside_conditionals(hlo_text):
+    """``(sorts, loose)``: how many ``sort`` instructions an HLO module
+    has, and the computations holding one that ENTRY reaches WITHOUT
+    passing through a ``conditional``'s branch."""
+    import re
+    comps, name = {}, None
+    for line in hlo_text.splitlines():
+        head = re.match(r"^(ENTRY\s+)?%?([\w.\-]+)\s.*\{\s*$", line)
+        if head and not line.startswith(" "):
+            name = head.group(2)
+            comps[name] = {"entry": bool(head.group(1)), "sorts": 0,
+                           "calls": set()}
+        elif line.startswith("}"):
+            name = None
+        elif name is not None and "=" in line:
+            body = line.split("=", 1)[1]
+            if re.search(r"\bsort\(", body):
+                comps[name]["sorts"] += 1
+            if not re.search(r"\bconditional\(", body):
+                for called in re.findall(
+                        r"(?:to_apply|calls|body|condition)=(\{[^}]*\}|\S+)",
+                        body):
+                    comps[name]["calls"] |= set(
+                        re.findall(r"[\w.\-]+", called))
+    entry = [n for n, c in comps.items() if c["entry"]]
+    assert len(entry) == 1, entry
+    seen, todo = set(), list(entry)
+    while todo:
+        n = todo.pop()
+        if n in seen or n not in comps:
+            continue
+        seen.add(n)
+        todo.extend(comps[n]["calls"])
+    return (sum(c["sorts"] for c in comps.values()),
+            [n for n in seen if comps[n]["sorts"]])
+
+
+def _record_program(eng, attr, key=None):
+    """Shim one of the engine's jitted programs so that its next launch
+    leaves the abstract shapes of its operands in the returned dict."""
+    import jax
+    jits = getattr(eng, attr)
+    fn = jits if key is None else jits[key]
+    seen = {"fn": fn}
+
+    def shim(*args):
+        seen["shapes"] = jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), args)
+        return fn(*args)
+    if key is None:
+        setattr(eng, attr, shim)
+    else:
+        jits[key] = shim
+    return seen
+
+
+class TestSamplingTail:
+    @pytest.mark.parametrize("mix", sorted(_KNOB_MIXES))
+    def test_next_tokens_bitwise_old_decode_tail(self, mix):
+        """Tokens AND new keys, bit for bit the tail every decode program
+        wrote out before: over seeds and logit scales, jitted as the
+        programs jit it."""
+        import jax
+        from paddle_tpu.serving.sampling import next_tokens
+        new, old = jax.jit(next_tokens), jax.jit(_old_decode_tail)
+        for seed, scale in ((0, 1.0), (1, 3.0), (2, 5.0)):
+            ops = _tail_operands(mix, seed, scale)
+            (n_tok, n_keys), (o_tok, o_keys) = new(*ops), old(*ops)
+            assert n_tok.dtype == o_tok.dtype == np.int32
+            assert np.array_equal(n_tok, o_tok), (mix, seed)
+            assert np.array_equal(n_keys, o_keys), (mix, seed)
+
+    @pytest.mark.parametrize("mix", sorted(_KNOB_MIXES))
+    def test_first_token_bitwise_old_first_token(self, mix):
+        """A prefill program's draw is the same tail over a batch of one:
+        scalar knobs, one key, ``logits[1, V]``."""
+        import jax
+        from paddle_tpu.serving import LLMEngine
+        new, old = jax.jit(LLMEngine._first_token), jax.jit(_old_first_token)
+        logits, keys, ds, t, tk, tp = _tail_operands(mix, seed=4)
+        for b in range(logits.shape[0]):
+            ops = (logits[b:b + 1], keys[b], ds[b], t[b], tk[b], tp[b])
+            (n_tok, n_key), (o_tok, o_key) = new(*ops), old(*ops)
+            assert n_tok.shape == () and int(n_tok) == int(o_tok), (mix, b)
+            assert np.array_equal(n_key, o_key), (mix, b)
+
+    @pytest.mark.parametrize("mix", ["all_greedy", "greedy_and_filtered",
+                                     "top_p_only"])
+    def test_draft_tail_bitwise_old_sample_q(self, mix):
+        """The speculative drafter's proposal and, for every sampling
+        row, the distribution ``q`` the acceptance test divides by (a
+        greedy row's is never read)."""
+        import functools
+        import jax
+        from paddle_tpu.serving.sampling import next_tokens
+        new = jax.jit(functools.partial(next_tokens, with_dist=True))
+        ops = _tail_operands(mix, seed=5)
+        (n_tok, n_q, n_keys) = new(*ops)
+        (o_tok, o_q, o_keys) = jax.jit(_old_draft_tail)(*ops)
+        assert np.array_equal(n_tok, o_tok)
+        assert np.array_equal(n_keys, o_keys)
+        ds = np.asarray(ops[2])
+        assert n_q.shape == o_q.shape
+        assert np.array_equal(np.asarray(n_q)[ds], np.asarray(o_q)[ds])
+
+    def test_traced_neutral_nucleus_is_not_the_identity(self):
+        """What the module docstring says of traced neutral knobs: the
+        top-k threshold masks nothing, the ``top_p = 1.0`` nucleus masks
+        tail tokens once the float32 cumsum rounds up to 1.0."""
+        import jax
+        import jax.numpy as jnp
+        from paddle_tpu.serving.sampling import filter_logits
+        lg = jnp.asarray(np.random.default_rng(0).normal(0, 5, (4, 50304)),
+                         jnp.float32)
+        only_k = jax.jit(lambda x, t, k: filter_logits(x, t, k, 1.0))(
+            lg, jnp.float32(1.0), jnp.int32(0))
+        assert np.array_equal(only_k, lg)
+        both = jax.jit(filter_logits)(
+            lg, jnp.float32(1.0), jnp.int32(0), jnp.float32(1.0))
+        masked = np.asarray(both) != np.asarray(lg)
+        assert masked.any()
+        # next to no mass: the tokens of the identity tests still agree
+        assert float(jnp.sum(jnp.where(masked, jax.nn.softmax(lg), 0))) < 1e-4
+
+    def test_sorts_only_inside_a_conditional_branch(self):
+        """The paged decode program's and a prefill chunk program's
+        lowered HLO keep every ``sort`` inside a ``conditional``'s branch:
+        the ``cond`` sits outside the per-row ``vmap`` and did not turn
+        into a select that runs both sides."""
+        m = _model()
+        eng = _engine(m, kv_layout="paged", block_size=4, prefill_chunk=8)
+        h = eng.add_request([3, 1, 4, 1, 5], max_new_tokens=2)
+        _run(eng, [h])                               # builds the programs
+        bucket = next(iter(eng._pchunk_jits))
+        progs = [_record_program(eng, "_pdecode_jit"),
+                 _record_program(eng, "_pchunk_jits", bucket)]
+        h = eng.add_request([2, 7, 1, 8, 2], max_new_tokens=3)
+        _run(eng, [h])
+        for seen in progs:
+            text = seen["fn"].lower(*seen["shapes"]).as_text(dialect="hlo")
+            sorts, loose = _sorts_outside_conditionals(text)
+            assert sorts and "conditional(" in text
+            assert loose == [], loose
+        # the walker does tell a select from a branch
+        import jax
+        bad = jax.jit(_old_decode_tail).lower(
+            *_tail_operands("all_greedy")).as_text(dialect="hlo")
+        assert _sorts_outside_conditionals(bad)[1]
+
+    @pytest.mark.parametrize("layout", ["slot", "paged"])
+    def test_neutral_row_ignores_a_filtering_neighbour(self, layout):
+        """A request's output does not depend on who shares its batch: a
+        sampling row with neutral knobs draws the same tokens alone and
+        beside a row that filters (the one predicate is any(do_sample),
+        never "does any row filter")."""
+        m = _model()
+        rng = np.random.default_rng(12)
+        pa = rng.integers(0, 64, size=6).tolist()
+        pb = rng.integers(0, 64, size=5).tolist()
+        kw = ({} if layout == "slot" else
+              dict(kv_layout="paged", block_size=4, prefill_chunk=8))
+        ref = _ref_generate(m, pa, 8, do_sample=True, seed=21)
+        eng = _engine(m, **kw)
+        alone = eng.add_request(pa, max_new_tokens=8, do_sample=True,
+                                seed=21)
+        _run(eng, [alone])
+        eng = _engine(m, **kw)
+        beside = eng.add_request(pa, max_new_tokens=8, do_sample=True,
+                                 seed=21)
+        other = eng.add_request(pb, max_new_tokens=8, do_sample=True,
+                                top_p=0.9, temperature=0.7, seed=22)
+        _run(eng, [beside, other])
+        assert list(alone.tokens) == list(beside.tokens)
+        assert np.array_equal(alone.tokens, ref)
+
+    @pytest.mark.parametrize("layout", ["slot", "paged"])
+    def test_sampled_steps_counts_steps_with_a_sampling_row(self, layout):
+        """``serving.decode.sampled_steps`` beside ``serving.decode_steps``
+        in the mixed batch of ``test_mixed_greedy_and_sampled_slots``: the
+        sampling request decodes 3 times (its first token is the
+        prefill's), the greedy one 7; once it has left, the launches take
+        the short branch again."""
+        m = _model()
+        rng = np.random.default_rng(3)
+        pg = rng.integers(0, 64, size=5).tolist()
+        ps = rng.integers(0, 64, size=7).tolist()
+        kw = ({} if layout == "slot" else
+              dict(kv_layout="paged", block_size=4, prefill_chunk=8))
+        eng = _engine(m, **kw)
+        before = counters.snapshot()
+        hg = eng.add_request(pg, max_new_tokens=8)
+        hsmp = eng.add_request(ps, max_new_tokens=4, do_sample=True,
+                               temperature=0.7, top_k=6, seed=9)
+        _run(eng, [hg, hsmp])
+        d = counters.delta(before)
+        assert d["serving.decode_steps"] == 7
+        assert d["serving.decode.sampled_steps"] == 3
+        assert np.array_equal(hg.tokens, _ref_generate(m, pg, 8))
+        assert np.array_equal(hsmp.tokens, _ref_generate(
+            m, ps, 4, do_sample=True, temperature=0.7, top_k=6, seed=9))
+        # an all-greedy engine registers the counter and leaves it at 0
+        counters.reset("serving.decode.sampled_steps")
+        h = eng.add_request(pg, max_new_tokens=3)
+        _run(eng, [h])
+        assert counters.snapshot()["serving.decode.sampled_steps"] == 0
