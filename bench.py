@@ -21,19 +21,18 @@ Legs (perf round 5):
   dispatch-amortisation win on the leg most exposed to per-step python
   overhead.
 - gpt125m_serve (serving leg): 64 staggered mixed-length requests through
-  ``serving.LLMEngine`` (continuous batching over the KV slot arena),
+  ``serving.LLMEngine`` (continuous batching over the paged K/V pool),
   with the first few verified token-identical against sequential
   ``GPT.generate`` — reports decode tokens/s for both, ``serve_speedup``,
   and TTFT / inter-token / queue-wait latency percentiles
   (p50/p95/p99 in ms) from the engine's mergeable histograms.
-- gpt125m_paged (paged-KV leg): the serving workload through
-  ``LLMEngine(kv_layout="paged")`` — a mixed-length request set against
-  the legacy slot arena at the SAME KV HBM budget (the block pool is
-  sized to the slot arena's token capacity), gating ≥2× peak admitted
-  concurrent requests; plus a 64-request shared-system-prompt workload
-  reporting TTFT p50/p95 and gating prefix-cache hits with strictly
-  fewer prefill-chunk launches than a no-cache twin; decode tok/s
-  parity vs the slot engine is reported informationally.
+- gpt125m_paged (paged-KV leg): a mixed-length request set through an
+  engine whose block pool holds ``max_slots`` sequences of ``S_max``,
+  gating ≥2× that many peak admitted concurrent requests (a request
+  reserves the blocks it can touch, not a row of ``S_max``); plus a
+  64-request shared-system-prompt workload reporting TTFT p50/p95 and
+  gating prefix-cache hits with strictly fewer prefill-chunk launches
+  than a no-cache twin.
 - gpt125m_tiered (KV-tiering leg): two-pass session traffic (every
   prompt queried twice) through paged engines whose block pools are cut
   to 1/2 and 1/4 of the working set with a pinned host-RAM KV tier
@@ -47,7 +46,7 @@ Legs (perf round 5):
 - gpt125m_spec (speculative-decoding leg): an aligned draft/target pair
   (shared embeddings, zeroed transformer blocks — acceptance ~1.0, so the
   leg measures the draft/verify machinery's ceiling) served greedily by
-  ``LLMEngine(draft_model=..., kv_layout="paged")`` vs the non-spec paged
+  ``LLMEngine(draft_model=...)`` vs the non-speculative
   baseline on the same prompts — reports acceptance rate, draft/verify
   dispatch counts, and net decode tok/s, gating token identity, zero
   steady retraces, ``accepted + rejected == drafted`` and ≥1.3× speedup.
@@ -370,8 +369,8 @@ def _run_serve_leg(cfg, n_requests=64, max_new=64, max_slots=8,
 
     eng = LLMEngine(model, max_slots=max_slots, max_seq_len=S,
                     min_bucket=min_bucket)
-    # warm: one throwaway request per distinct prefill bucket (compiles
-    # prefill + insert) plus the decode program
+    # warm: one throwaway request per distinct prompt bucket (compiles
+    # its prefill chunks) plus the decode program
     warm = [rng.randint(0, cfg.vocab_size,
                         size=min(b, S - 3)).tolist()
             for b in sorted({bucket_length(n, min_bucket, S)
@@ -448,17 +447,15 @@ def _run_serve_leg(cfg, n_requests=64, max_new=64, max_slots=8,
 def _run_paged_leg(cfg, n_requests=64, max_new=64, max_slots=8,
                    min_bucket=8, block_size=16, prefill_chunk=256,
                    n_verify=8, seed=0):
-    """Paged KV cache vs the legacy slot arena at the SAME KV HBM budget.
+    """What a block pool admits at a fixed KV HBM budget.
 
-    Leg 1 (capacity): a mixed-length request set served by the slot
-    engine (``max_slots`` rows of ``S_max``) and by a paged engine whose
-    block pool holds exactly the slot arena's token capacity
-    (``max_slots * ceil(S/bs)`` blocks).  Because paged requests reserve
-    only the blocks they can actually touch, the pool admits several
-    requests per slot-arena-row-equivalent — gated at ≥2× peak
-    concurrent admitted requests.  The first ``n_verify`` requests are
-    verified token-identical to sequential ``GPT.generate`` on both
-    engines, and decode tok/s parity is reported.
+    Leg 1 (capacity): a mixed-length request set served by an engine
+    whose block pool holds ``max_slots`` sequences of ``S_max``
+    (``max_slots * ceil(S/bs)`` blocks).  Because requests reserve only
+    the blocks they can actually touch, the pool admits several requests
+    per ``S_max`` of KV — gated at ≥2× ``max_slots`` peak concurrent
+    admitted requests.  The first ``n_verify`` requests are verified
+    token-identical to sequential ``GPT.generate``.
 
     Leg 2 (shared prefix): ``n_requests`` prompts sharing one
     system-prompt prefix, served sequentially enough to feed the prefix
@@ -468,7 +465,6 @@ def _run_paged_leg(cfg, n_requests=64, max_new=64, max_slots=8,
     from paddle_tpu.models import GPTForCausalLM
     from paddle_tpu.profiler import counters
     from paddle_tpu.serving import LLMEngine
-    from paddle_tpu.serving.engine import bucket_length
     from paddle_tpu.serving.kvcache import blocks_for_tokens
 
     paddle.seed(seed)
@@ -494,30 +490,12 @@ def _run_paged_leg(cfg, n_requests=64, max_new=64, max_slots=8,
             peak = max(peak, eng.stats()["active"])
         return hs, peak
 
-    # legacy slot arena: KV HBM = L x max_slots x S_max
-    slot_eng = LLMEngine(model, max_slots=max_slots, max_seq_len=S,
-                         min_bucket=min_bucket)
-    warm = [rng.randint(0, cfg.vocab_size, size=min(b, S - 3)).tolist()
-            for b in sorted({bucket_length(n, min_bucket, S)
-                             for n in lens})]
-    for _ in slot_eng.generate(warm, max_new_tokens=2):
-        pass
-    t0 = time.perf_counter()
-    shs, slot_peak = serve(slot_eng, prompts)
-    slot_s = time.perf_counter() - t0
-    slot_tps = n_requests * max_new / max(slot_s, 1e-9)
-    for h, r in zip(shs[:n_verify], refs):
-        if not np.array_equal(h.output_ids(), r):
-            raise AssertionError(
-                "paged leg: slot-engine output diverged from generate")
-    del slot_eng
-
-    # paged twin at the SAME KV HBM: pool == the slot arena's tokens;
-    # scheduling slots are host-side bookkeeping, so the admitted
-    # concurrency is bounded by memory, not by rows
+    # KV HBM = L x max_slots x S_max tokens; scheduling slots are
+    # host-side bookkeeping, so the admitted concurrency is bounded by
+    # memory, not by rows
     n_blocks = max_slots * blocks_for_tokens(S, block_size) + 1
     peng = LLMEngine(model, max_slots=4 * max_slots, max_seq_len=S,
-                     min_bucket=min_bucket, kv_layout="paged",
+                     min_bucket=min_bucket,
                      block_size=block_size, n_blocks=n_blocks,
                      prefill_chunk=prefill_chunk)
     # warm one request per power-of-two chunk bucket (+ the decode)
@@ -537,12 +515,12 @@ def _run_paged_leg(cfg, n_requests=64, max_new=64, max_slots=8,
     for h, r in zip(phs[:n_verify], refs):
         if not np.array_equal(h.output_ids(), r):
             raise AssertionError(
-                "paged leg: paged-engine output diverged from generate")
-    capacity_ratio = paged_peak / max(1, slot_peak)
+                "paged leg: engine output diverged from generate")
+    capacity_ratio = paged_peak / max_slots
     if capacity_ratio < 2.0:
         raise AssertionError(
-            f"paged leg: peak concurrency {paged_peak} vs slot "
-            f"{slot_peak} = {capacity_ratio:.2f}x at the same KV HBM "
+            f"paged leg: peak concurrency {paged_peak} in the KV HBM of "
+            f"{max_slots} sequences of S_max = {capacity_ratio:.2f}x "
             "(want >= 2x)")
 
     # shared-system-prompt workload: TTFT tail + prefix-cache economics.
@@ -567,7 +545,7 @@ def _run_paged_leg(cfg, n_requests=64, max_new=64, max_slots=8,
             eng.step()
 
     nc_eng = LLMEngine(model, max_slots=4 * max_slots, max_seq_len=S,
-                       min_bucket=min_bucket, kv_layout="paged",
+                       min_bucket=min_bucket,
                        block_size=block_size, n_blocks=n_blocks,
                        prefill_chunk=prefill_chunk, prefix_cache=False)
     ncbefore = counters.snapshot()
@@ -576,7 +554,7 @@ def _run_paged_leg(cfg, n_requests=64, max_new=64, max_slots=8,
                                              0)
     del nc_eng
     pc_eng = LLMEngine(model, max_slots=4 * max_slots, max_seq_len=S,
-                       min_bucket=min_bucket, kv_layout="paged",
+                       min_bucket=min_bucket,
                        block_size=block_size, n_blocks=n_blocks,
                        prefill_chunk=prefill_chunk)
     pcbefore = counters.snapshot()
@@ -602,12 +580,9 @@ def _run_paged_leg(cfg, n_requests=64, max_new=64, max_slots=8,
            "n_blocks": n_blocks,
            "prefill_chunk": peng.prefill_chunk,
            "kv_hbm_slots_equiv": max_slots,
-           "peak_concurrent_slot": slot_peak,
            "peak_concurrent_paged": paged_peak,
            "capacity_ratio": round(capacity_ratio, 3),
-           "decode_tokens_per_sec_slot": round(slot_tps, 2),
            "decode_tokens_per_sec_paged": round(paged_tps, 2),
-           "decode_parity": round(paged_tps / max(slot_tps, 1e-9), 4),
            "steady_retraces": pdelta.get("serving.retraces", 0),
            "outputs_match_generate": True,
            "shared_prefix": {
@@ -687,7 +662,7 @@ def _run_paged_q_leg(cfg, n_requests=64, max_new=64, max_slots=4,
 
     def engine(n_blocks, **kw):
         eng = LLMEngine(model, max_slots=n_requests, max_seq_len=S,
-                        min_bucket=min_bucket, kv_layout="paged",
+                        min_bucket=min_bucket,
                         block_size=bs, n_blocks=n_blocks,
                         prefill_chunk=prefill_chunk, prefix_cache=False,
                         **kw)
@@ -834,7 +809,7 @@ def _run_spec_leg(n_requests=16, max_new=32, max_slots=4, min_bucket=8,
 
     def engine(**kw):
         eng = LLMEngine(target, max_slots=max_slots, max_seq_len=seq_len,
-                        min_bucket=min_bucket, kv_layout="paged",
+                        min_bucket=min_bucket,
                         block_size=block_size, n_blocks=n_blocks,
                         prefill_chunk=prefill_chunk, prefix_cache=False,
                         **kw)
@@ -1087,7 +1062,7 @@ def _run_multitenant_leg(cfg, replicas=2, tenants=6, adapter_slots=4,
     fleet = ServingFleet(model, replicas=replicas, max_slots=max_slots,
                          max_seq_len=S, min_bucket=min_bucket,
                          threaded=False, warm_buckets=lens,
-                         kv_layout="paged", block_size=block_size,
+                         block_size=block_size,
                          prefill_chunk=prefill_chunk,
                          adapter_slots=adapter_slots, adapter_rank=rank)
     for i, t in enumerate(names + [cold]):
@@ -1247,7 +1222,7 @@ def _run_disagg_leg(cfg, n_long=6, n_short=18, max_new=16, max_slots=None,
         return ServingFleet(
             model, replicas=2, prefill_replicas=prefill_replicas,
             max_slots=max_slots, max_seq_len=S, min_bucket=min_bucket,
-            threaded=True, kv_layout="paged", block_size=block_size,
+            threaded=True, block_size=block_size,
             n_blocks=max(128, 4 * S // block_size * max_slots),
             prefill_chunk=prefill_chunk, warm_buckets=lens,
             max_retries=2)
@@ -1427,7 +1402,7 @@ def _run_tiered_leg(cfg, n_sessions=24, max_new=64, max_slots=8,
 
     def build(n_blocks, host_blocks):
         eng = LLMEngine(model, max_slots=max_slots, max_seq_len=S,
-                        min_bucket=min_bucket, kv_layout="paged",
+                        min_bucket=min_bucket,
                         block_size=bs, n_blocks=n_blocks,
                         prefill_chunk=prefill_chunk,
                         host_kv_blocks=host_blocks)
@@ -1495,7 +1470,7 @@ def _run_tiered_leg(cfg, n_sessions=24, max_new=64, max_slots=8,
     fbefore = counters.snapshot()
     fleet = ServingFleet(model, replicas=2, threaded=False,
                          max_slots=max_slots, max_seq_len=S,
-                         min_bucket=min_bucket, kv_layout="paged",
+                         min_bucket=min_bucket,
                          block_size=bs, n_blocks=nb_2x,
                          prefill_chunk=prefill_chunk,
                          host_kv_blocks=demand,
@@ -1609,7 +1584,7 @@ def _run_servemp_leg(cfg, mp, n_requests=8, max_new=24, max_slots=8,
 
     def build(mesh=None):
         return LLMEngine(model, max_slots=max_slots, max_seq_len=S,
-                         min_bucket=min_bucket, kv_layout="paged",
+                         min_bucket=min_bucket,
                          block_size=block_size,
                          prefill_chunk=prefill_chunk, mesh=mesh)
 
@@ -1954,8 +1929,8 @@ def main():
         legs["gpt125m_serve"] = _run_serve_leg(scfg, n_requests=64,
                                                max_new=64, max_slots=8)
     if which in ("all", "paged"):
-        # paged-KV leg: >=2x admitted concurrency at the slot arena's KV
-        # HBM on mixed lengths, plus shared-system-prompt TTFT tails and
+        # paged-KV leg: >=2x max_slots admitted in the KV HBM of max_slots
+        # sequences of S_max on mixed lengths, plus shared-system-prompt TTFT tails and
         # the prefix-cache hit / reduced-prefill gates
         pcfg = GPTConfig.gpt3_125m(vocab_size=50304, max_seq_len=1024,
                                    dtype="bfloat16",
@@ -2170,7 +2145,7 @@ def main():
             "metric": "gpt125m_paged_decode_tokens_per_sec",
             "value": leg["decode_tokens_per_sec_paged"],
             "unit": "tokens/s",
-            "vs_baseline": leg["capacity_ratio"],  # peak admits vs slots
+            "vs_baseline": leg["capacity_ratio"],  # peak admits vs rows
             "legs": legs,
         }))
         return

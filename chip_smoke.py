@@ -23,7 +23,7 @@ non-zero and the last line is never printed:
   (flash on, ``recompute="selective_lean"``, AdamW, 8 x 1024): warm-up,
   three steps on one repeated batch (loss finite and lower at the end,
   no retrace), then ``fused_steps=4`` windows.
-* ``serve`` — ``LLMEngine(kv_layout="paged")`` over a bf16 GPT-3 XL (1.3B:
+* ``serve`` — ``LLMEngine`` over a bf16 GPT-3 XL (1.3B:
   16 heads of 128, the width whose K/V slabs are whole tiles, so the
   decode program runs the Pallas block-table walk) with a pool of 8 rows
   x 1024 tokens: 8 greedy requests (prompts of 32-512 tokens, 32 new
@@ -389,8 +389,8 @@ def _serve_run(model, prompts, warm_prompts, new_tokens, probe=None,
     adds facts that need the live engine, which is released before
     returning so the next one finds the memory free."""
     programs = counters.snapshot()
-    eng = LLMEngine(model, max_slots=len(prompts), kv_layout="paged",
-                    block_size=16, **engine_kw)
+    eng = LLMEngine(model, max_slots=len(prompts), block_size=16,
+                    **engine_kw)
     t0 = time.perf_counter()
     _drain(eng, warm_prompts, new_tokens)
     warm_s = time.perf_counter() - t0

@@ -145,7 +145,7 @@ def create_predictor(config: Config):
 class GenerationPredictor:
     """Deployment front end for causal-LM generation that routes every
     request through ``serving.LLMEngine`` (continuous batching over a
-    device-resident KV slot arena) instead of one ``GPT.generate`` program
+    device-resident paged K/V pool) instead of one ``GPT.generate`` program
     per request shape.
 
     reference analogue: the inference-deployment generation path

@@ -40,7 +40,7 @@ def neg_inf(dtype=jnp.float32) -> float:
 
 
 #: fp32 mask fill shared by the kernels and the jnp reference twins
-#: (``gpt.decode_paged``/``decode_slots`` mask their fp32 logits with
+#: (``gpt.decode_paged``/``_cached_layers`` mask their fp32 logits with
 #: this).  Use ``neg_inf(dtype)`` when filling a non-fp32 array.
 NEG_INF = neg_inf(jnp.float32)
 

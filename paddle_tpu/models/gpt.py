@@ -584,106 +584,11 @@ class GPTForCausalLM(Layer):
             "head": (None if c.tie_word_embeddings else self.lm_head._data),
         }
 
-    def prefill_slot(self, w, ids, length):
-        """Pure prefill over ONE right-padded prompt ``ids[1, Sb]`` of true
-        length ``length`` (traced scalar): returns K/V chunks
-        ``[L, 1, Sb, nh, hd]`` zeroed beyond ``length`` plus the fp32
-        next-token logits ``[1, V]`` read at position ``length - 1``.
-
-        ``Sb`` is a power-of-two bucket, so the engine compiles
-        O(log S_max) prefill programs however many prompt lengths arrive.
-        Built on the same ``_cached_layers`` scan as ``generate`` — the
-        engine's first token is token-identical to ``generate``'s."""
-        c = self.config
-        nh, H = c.num_heads, c.hidden_size
-        hd = H // nh
-        B, Sb = ids.shape
-        dt = jnp.dtype(c.dtype)
-        ck0 = jnp.zeros((c.num_layers, B, Sb, nh, hd), dt)
-        cv0 = jnp.zeros((c.num_layers, B, Sb, nh, hd), dt)
-        h = self._embed(c, w["wte"], w["wpe"], ids, 0)
-        h, ck, cv = self._cached_layers(c, w["lws"], h, ck0, cv0, 0)
-        # zero the padded tail so arena rows only ever hold live K/V
-        valid = (jnp.arange(Sb) < length)[None, None, :, None, None]
-        ck = jnp.where(valid, ck, jnp.zeros((), ck.dtype))
-        cv = jnp.where(valid, cv, jnp.zeros((), cv.dtype))
-        h_last = jax.lax.dynamic_slice_in_dim(h, length - 1, 1, axis=1)
-        logits = _lm_logits(c, w["wte"], w["lnf_w"], w["lnf_b"], w["head"],
-                            h_last[:, 0])
-        return ck, cv, logits
-
-    def decode_slots(self, w, tok, pos, cache_k, cache_v):
-        """One decode step for B independent slot rows at PER-ROW positions
-        (the serving twin of ``_cached_layers``, whose position is one
-        scalar for the whole batch).
-
-        tok ``[B]`` int32, pos ``[B]`` int32, cache_k/v ``[L, B, S, nh,
-        hd]`` (the engine's KV arena).  Writes each row's K/V at
-        ``pos[row]`` (one-hot select — dynamic_update_slice needs a scalar
-        start), attends to ``kpos <= pos[row]``, and returns
-        ``(logits [B, V] fp32, new cache_k, new cache_v)``.  Rows are
-        independent, so a slot's trajectory is token-identical to a
-        ``generate`` call decoding the same request alone."""
-        c = self.config
-        nh = c.num_heads
-        eps = c.layer_norm_epsilon
-        H = c.hidden_size
-        hd = H // nh
-        B = tok.shape[0]
-        S = cache_k.shape[2]
-        scale = 1.0 / math.sqrt(hd)
-        h = jnp.take(w["wte"], tok, axis=0)[:, None, :]
-        if w["wpe"] is not None:
-            h = h + jnp.take(w["wpe"], pos, axis=0)[:, None, :]
-        kpos = jnp.arange(S)
-        mask = kpos[None, :] <= pos[:, None]                     # [B, S]
-        write = kpos[None, :, None, None] == pos[:, None, None, None]
-
-        def body(hh, xs):
-            lw, ck, cv = xs
-            x = _norm(hh, lw["ln1_w"], lw["ln1_b"], eps)
-            qkv = _mm(x, lw, "qkv_w") + lw["qkv_b"]
-            q, k, v = jnp.split(qkv, 3, axis=-1)
-            q = q.reshape(B, 1, nh, hd)
-            k = k.reshape(B, 1, nh, hd)
-            v = v.reshape(B, 1, nh, hd)
-            if c.use_rope:
-                q = _rope_rows(q, pos)
-                k = _rope_rows(k, pos)
-            ck = jnp.where(write, k.astype(ck.dtype), ck)
-            cv = jnp.where(write, v.astype(cv.dtype), cv)
-            logits = jnp.einsum("bqhd,bkhd->bhqk",
-                                (q * scale).astype(jnp.float32),
-                                ck.astype(jnp.float32))
-            logits = jnp.where(mask[:, None, None, :], logits, NEG_INF)
-            p = jax.nn.softmax(logits, axis=-1)
-            o = jnp.einsum("bhqk,bkhd->bqhd", p.astype(cv.dtype), cv)
-            o = o.reshape(B, 1, H)
-            a = _mm(o, lw, "proj_w") + lw["proj_b"]
-            hh = hh + a
-            x = _norm(hh, lw["ln2_w"], lw["ln2_b"], eps)
-            if c.num_experts > 0:
-                from ..incubate.moe import moe_ffn
-                f, _aux = moe_ffn(
-                    x, lw["gate_w"], lw["fc1_w"], lw["fc1_b"],
-                    lw["fc2_w"], lw["fc2_b"], top_k=c.moe_top_k,
-                    capacity_factor=c.moe_capacity_factor)
-            else:
-                up = _mm(x, lw, "fc1_w") + lw["fc1_b"]
-                f = _mm(jax.nn.gelu(up), lw, "fc2_w") + lw["fc2_b"]
-            return hh + f, (ck, cv)
-
-        h, (cache_k, cache_v) = jax.lax.scan(
-            body, h, (w["lws"], cache_k, cache_v))
-        logits = _lm_logits(c, w["wte"], w["lnf_w"], w["lnf_b"], w["head"],
-                            h[:, 0])
-        return logits, cache_k, cache_v
-
     def prefill_paged(self, w, ids, start, length, bt, pool_k, pool_v,
                       scale_k=None, scale_v=None, adapters=None,
                       adapter_ids=None):
-        """One chunked-prefill step over a block-pool KV arena (the paged
-        twin of ``prefill_slot``; see ``serving.paged``).
+        """One chunked-prefill step over a block-pool KV arena (the
+        serving twin of ``_cached_layers``; see ``serving.paged``).
 
         ``ids[1, C]`` is one right-padded prompt chunk of true length
         ``length`` (traced scalar) whose tokens sit at logical positions
@@ -822,9 +727,12 @@ class GPTForCausalLM(Layer):
                      scale_k=None, scale_v=None, kernel=None,
                      mesh=None, head_axis=None, adapters=None,
                      adapter_ids=None):
-        """One decode step for B slot rows over the block-pool arena (the
-        paged twin of ``decode_slots`` — identical math, the arena row is
-        replaced by a block-table gather).
+        """One decode step for B independent slot rows at PER-ROW
+        positions over the block-pool arena (the serving twin of
+        ``_cached_layers``, whose position is one scalar for the whole
+        batch and whose cache row is replaced by a block-table gather).
+        Rows are independent, so a slot's trajectory is token-identical
+        to a ``generate`` call decoding the same request alone.
 
         tok ``[B]`` int32, pos ``[B]`` int32, bt ``[B, max_blocks]``
         int32 block tables (operands: the ONE compiled decode program

@@ -10,10 +10,9 @@ lifecycle hop records a child span into the per-request span tree:
 
   admission        router pick + dispatch onto a replica
   queue            bounded-queue wait, enqueue → slot admission
-  kv.reserve       paged block-table reservation (prefix match included)
+  kv.reserve       block-table reservation (prefix match included)
   cow.adopt        copy-on-write clone of a shared partial block
-  prefill          slot-engine prefill launch (one span per request)
-  prefill.chunk    paged chunked-prefill launch (one span per chunk)
+  prefill.chunk    chunked-prefill launch (one span per chunk)
   decode.iter      one batched decode launch (one span per live request
                    per iteration — the per-token hot loop)
   decode.stall     injected ``slow_decode`` stall (chaos site)
@@ -76,7 +75,7 @@ TAIL_REASONS = frozenset({"deadline", "error", "retried"})
 # (queue + prefill work + decode work ≈ TTFT + decode wall time)
 STAGES = {
     "queue": ("queue",),
-    "prefill": ("prefill", "prefill.chunk", "kv.reserve", "cow.adopt"),
+    "prefill": ("prefill.chunk", "kv.reserve", "cow.adopt"),
     "decode": ("decode.iter", "decode.stall"),
 }
 _STAGE_OF = {n: s for s, names in STAGES.items() for n in names}
@@ -165,7 +164,8 @@ class TraceContext:
         return self.add_span(name, now, now, **extra)
 
     def span(self, name, parent=0, **extra):
-        """``with ctx.span("prefill", bucket=64): ...`` timed recording."""
+        """``with ctx.span("prefill.chunk", chunk=64): ...`` timed
+        recording."""
         return _CtxSpan(self, name, parent, extra)
 
     def stamp(self, name):

@@ -1,7 +1,7 @@
 """StateArena: one spec layer under every serving engine.
 
-Six serving subsystems (slot engine, paged engine, speculative engine,
-block migration, prefix spill/restore, fleet replicas) each hand-manage
+Five serving subsystems (the engine, the speculative engine, block
+migration, prefix spill/restore, fleet replicas) each hand-manage
 donated device state.  The arena centralises the three things they all
 re-prove independently:
 

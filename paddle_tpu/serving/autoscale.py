@@ -145,8 +145,6 @@ class FleetAutoscaler:
         admissions see the split."""
         if len(alive) < 2:
             return None
-        if self.fleet._engine_kw.get("kv_layout") != "paged":
-            return None      # KV migration is block-granular: paged only
         load = sorted(alive, key=lambda r:
                       (r.engine.stats()["outstanding_tokens"], r.idx))
         self.fleet.set_role(load[0], "prefill")

@@ -81,9 +81,9 @@ from ..profiler import trace as rtrace
 from ..profiler.host_tracer import span
 from ..resilience import faultinject
 from .autoscale import FleetAutoscaler
-from .engine import (EngineBackpressure, EngineClosed, LLMEngine,
-                     bucket_length)
+from .engine import EngineBackpressure, EngineClosed, bucket_length
 from .kvcache import BlockPoolExhausted, HostTierLost
+from .paged import LLMEngine
 from .router import RetryAfter, Router
 
 __all__ = ["FleetRequest", "Replica", "ServingFleet"]
@@ -245,15 +245,15 @@ class ServingFleet:
     health-checked scheduler tick per call, replicas stepped in index
     order in the caller's thread.
 
-    ``warm_buckets`` pre-compiles the prefill/insert programs for those
+    ``warm_buckets`` pre-compiles the prefill chunk programs for those
     prompt lengths (plus the decode program) on every replica at spawn;
     buckets seen at submit time are added to the set, so a respawned
     replica is warmed for the live traffic mix before it joins dispatch.
 
     ``prefill_replicas=P`` starts the fleet disaggregated: the first P
     replicas take the ``"prefill"`` role, the rest ``"decode"``
-    (requires ``kv_layout="paged"`` — migration is block-granular — and
-    ``P < replicas`` so at least one decode replica exists).
+    (requires ``P < replicas`` so at least one decode replica exists;
+    KV migrates between them by block table).
     ``autoscale=True`` attaches a :class:`FleetAutoscaler`
     (``autoscale_kw`` forwards to its constructor) that rebalances the
     split from the health plane's burn alerts; ``health_kw`` forwards to
@@ -265,7 +265,7 @@ class ServingFleet:
                  queue_size=64, min_bucket=8, eos_token_id=None,
                  threaded=True, heartbeat_timeout_s=10.0, slo_margin=1.0,
                  max_retries=1, warm_buckets=(), router=None,
-                 kv_layout="slots", block_size=16, n_blocks=None,
+                 block_size=16, n_blocks=None,
                  prefill_chunk=None, prefix_cache=True, kv_dtype=None,
                  weight_dtype=None, draft_model=None, spec_k=4,
                  prefill_replicas=0, autoscale=False, autoscale_kw=None,
@@ -274,21 +274,14 @@ class ServingFleet:
                  adapter_slots=0, adapter_rank=8):
         self.model = model
         prefill_replicas = int(prefill_replicas)
-        if prefill_replicas:
-            if kv_layout != "paged":
-                raise ValueError(
-                    "disaggregated prefill/decode requires "
-                    "kv_layout='paged': KV migrates between replicas by "
-                    "block table")
-            if prefill_replicas >= int(replicas):
-                raise ValueError(
-                    f"prefill_replicas={prefill_replicas} must leave at "
-                    f"least one decode replica (replicas={replicas})")
+        if prefill_replicas and prefill_replicas >= int(replicas):
+            raise ValueError(
+                f"prefill_replicas={prefill_replicas} must leave at "
+                f"least one decode replica (replicas={replicas})")
         self._engine_kw = dict(max_slots=max_slots, max_seq_len=max_seq_len,
                                queue_size=queue_size, min_bucket=min_bucket,
                                eos_token_id=eos_token_id,
-                               kv_layout=kv_layout, block_size=block_size,
-                               n_blocks=n_blocks,
+                               block_size=block_size, n_blocks=n_blocks,
                                prefill_chunk=prefill_chunk,
                                prefix_cache=prefix_cache,
                                kv_dtype=kv_dtype,
@@ -443,7 +436,7 @@ class ServingFleet:
 
     def _warm(self, rep):
         """Compile the replica's programs BEFORE it joins dispatch: one
-        throwaway request per known prompt bucket (prefill + insert) and
+        throwaway request per known prompt bucket (its prefill chunks) and
         at least one decode launch.  A respawned replica must not pay
         compile latency against live traffic's SLOs."""
         if not self._warm_lens:
@@ -541,8 +534,7 @@ class ServingFleet:
                            if r.tag is not None
                            and r.tag.trace is not None],
         })
-        # the KV storage of a dead replica is garbage — slot arena or
-        # paged block pool alike; release its HBM now
+        # the KV storage of a dead replica is garbage; release its HBM now
         eng.release_kv()
         requeue = []
         for er in stranded:
@@ -1144,8 +1136,7 @@ class ServingFleet:
                                  if not f.is_finished),
                "closed": self._closed,
                "health": self.health.summary()}
-        paged = [st for st in reps
-                 if st.get("kv_layout") == "paged" and st["alive"]]
+        paged = [st for st in reps if st["alive"]]
         if paged:
             # fleet-wide block-pool / prefix-cache roll-up: sums of the
             # per-replica monotonic counters, pooled utilization, and the

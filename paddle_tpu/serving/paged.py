@@ -1,11 +1,10 @@
-"""Paged KV-cache serving engine: block arena + prefix cache + chunked
-prefill (``LLMEngine(kv_layout="paged")``).
+"""The serving engine: continuous batching over a paged K/V pool, a
+prefix cache and chunked prefill (``serving.LLMEngine``).
 
-The slot engine charges every request the worst case: one arena row of
-``S_max`` positions.  The paged engine replaces the row with a **block
-table**: KV lives in a shared donated pool ``[L, n_blocks, block_size,
-nh, hd]`` and each slot carries a fixed-shape int32 table mapping its
-logical block index to a physical pool block.  Three consequences:
+A request is charged what it can use, not the worst case.  KV lives in a
+shared donated pool ``[L, n_blocks, block_size, nh, hd]`` and each of the
+engine's ``max_slots`` rows carries a fixed-shape int32 **block table**
+mapping its logical block index to a physical pool block.  What follows:
 
 * **Capacity** — a request reserves only ``ceil((T + max_new - 1)/bs)``
   blocks, so concurrent-user capacity at fixed KV HBM scales with the
@@ -34,19 +33,29 @@ logical block index to a physical pool block.  Three consequences:
   so the steady state never mallocs.  The payoff is graceful throughput
   degradation instead of shedding at 2–4× oversubscribed KV.
 
-TPU discipline is unchanged from the slot engine: block tables ride the
-compiled programs as int32 OPERANDS (never shape inputs), so steady
-state stays O(log prefill_chunk) chunk programs + ONE decode program +
-one COW copy program (+ one fixed-shape migration gather/scatter when a
+All device work happens in shape-stable donated XLA programs.  Block
+tables ride them as int32 OPERANDS (never shape inputs), so steady state
+is O(log prefill_chunk) chunk programs + ONE decode program + one COW
+copy program (+ one fixed-shape migration gather/scatter when a
 disaggregated fleet hands block tables between replicas) with zero
-retraces; the pool is donated through every launch.  Sampling replicates ``GPT.generate``'s key-split chain
-exactly (only the final chunk's sample is consumed), so paged output is
-token-identical to the slot engine and to sequential ``generate``.
+retraces; the pool is donated through every launch.  Per-row sampling
+knobs (temperature / top-k / top-p / greedy) and a per-row PRNG key chain
+seeded per request ride the programs as arrays; the sampling math is
+``serving.sampling`` — the same transform ``GPT.generate`` traces — and
+the key-split schedule replicates ``generate``'s exactly (only the final
+chunk's sample is consumed), so engine output is token-identical to
+running each request alone through ``generate``.
+
+The request's own life (queue, deadlines, cancellation, the finish
+compare-and-set, ``drain``) is ``serving.engine._RequestLifecycle``,
+which this class extends; ``serving.speculative`` extends this one.
 """
 
 from __future__ import annotations
 
 import time
+import warnings
+import weakref
 import zlib
 
 import jax
@@ -60,29 +69,64 @@ from ..profiler import flight
 from ..profiler import metrics
 from ..profiler import trace as rtrace
 from ..profiler.host_tracer import span
-from .engine import (EngineBackpressure, EngineClosed, LLMEngine,
-                     RecurrentStateUnsupported, Request, _model_programs,
+from .arena import StateArena
+from .engine import (EngineBackpressure, EngineClosed,
+                     RecurrentStateUnsupported, Request, _RequestLifecycle,
                      bucket_length)
 from .kvcache import (TRASH_BLOCK, BlockPool, BlockPoolExhausted,
                       HostKVTier, HostTierLost, PrefixCache,
                       blocks_for_tokens)
 from .sampling import next_tokens
 
-__all__ = ["PagedLLMEngine"]
+__all__ = ["LLMEngine"]
+
+# the pool donations are a no-op on CPU backends; the warning would fire
+# on every serving step there
+warnings.filterwarnings("ignore",
+                        message="Some donated buffers were not usable")
+
+# Per-model cache of the jitted serving programs.  The closures capture
+# the MODEL only (never an engine), so every engine over the same model
+# instance — fleet replicas, respawned replacements — reuses one set of
+# XLA executables instead of recompiling identical programs per engine.
+# Donation is per-call, and jax.jit keys compiled variants by argument
+# shape internally, so sharing is invisible except in compile time (and
+# in ``serving.retraces``, which only ever counts FEWER traces).
+_MODEL_PROGRAMS = weakref.WeakKeyDictionary()
 
 
-class PagedLLMEngine(LLMEngine):
-    """``LLMEngine`` over a paged block-pool KV arena.
+def _model_programs(model):
+    try:
+        cache = _MODEL_PROGRAMS.get(model)
+        if cache is None:
+            cache = _MODEL_PROGRAMS[model] = {}
+    except TypeError:  # unhashable / non-weakrefable model object
+        cache = model.__dict__.setdefault("_serving_programs", {})
+    return cache
 
-    Extra knobs (all inert under ``kv_layout="slots"``):
+
+class LLMEngine(_RequestLifecycle):
+    """Continuous-batching engine over one causal LM (``GPTForCausalLM``,
+    ``OlmoHybridForCausalLM``: anything with ``cache_spec()``,
+    ``decode_state()``, ``prefill_paged`` and ``decode_paged``).
+
+    ``add_request()`` enqueues (bounded queue, optional blocking
+    backpressure); ``step()`` admits into free slots by reserving K/V
+    blocks, advances every mid-prefill request by one chunk, runs one
+    decode launch for every running slot, and evicts finished rows;
+    ``generate()`` is the blocking convenience loop; iterating a returned
+    ``Request`` streams its tokens.  ``drain()`` stops admission and
+    finishes all outstanding work.
+
+    Cache knobs:
 
     * ``block_size`` — tokens per KV block (default 16).
     * ``n_blocks`` — physical pool blocks *including* the reserved trash
-      block 0; default sizes the pool to the slot arena's HBM footprint
-      (``max_slots * ceil(S_max/bs) + 1``).
+      block 0; default ``max_slots * ceil(S_max/bs) + 1`` (every slot can
+      hold a sequence of ``S_max``).
     * ``prefill_chunk`` — max tokens prefilled per scheduler step
       (default ``min(S_max, 128)``); chunk programs are bucketed
-      powers-of-two up to this, like the slot engine's prefill buckets.
+      powers-of-two from ``min_bucket`` up to this.
     * ``prefix_cache`` — enable the COW prefix tree (default True).
     * ``host_kv_blocks`` — host-RAM tier capacity in blocks (default 0:
       tier disabled).  Requires the prefix cache.
@@ -91,9 +135,93 @@ class PagedLLMEngine(LLMEngine):
       requests never spill).
     """
 
-    # -- construction hooks --------------------------------------------------
-    def __init__(self, *args, **kw):
-        super().__init__(*args, **kw)
+    def __new__(cls, *args, **kw):
+        # a draft_model= routes construction to the speculative subclass,
+        # so `LLMEngine(model, draft_model=...)` is the one public spelling
+        # (serving.speculative imports this module; resolve lazily)
+        if cls is LLMEngine and kw.get("draft_model") is not None:
+            from .speculative import SpeculativeLLMEngine
+            return super().__new__(SpeculativeLLMEngine)
+        return super().__new__(cls)
+
+    def __init__(self, model, max_slots=8, max_seq_len=None, queue_size=64,
+                 min_bucket=8, eos_token_id=None, kv_layout="paged",
+                 block_size=16, n_blocks=None, prefill_chunk=None,
+                 prefix_cache=True, kv_dtype=None, weight_dtype=None,
+                 host_kv_blocks=0, spill_idle_steps=0, mesh=None,
+                 shard_rules=None, adapter_slots=0, adapter_rank=8,
+                 tenant_buckets=8):
+        # not an option: there is one layout.  The keyword is still taken
+        # because benchmark/workloads/*.json pass it in their `engine`
+        # blocks, benchmark/kinds/serve_open_loop*.py forward them as
+        # **kw, and PR 30 could edit nothing under benchmark/.  ROADMAP
+        # D16: the next `benchmark` issue drops the key there, then this
+        # parameter and the check go.
+        if kv_layout != "paged":
+            raise ValueError(
+                f"kv_layout={kv_layout!r}: the slot layout was removed in "
+                "PR 30; the engine is paged (drop the keyword)")
+        if kv_dtype not in (None, "int8", "fp8"):
+            raise ValueError(f"kv_dtype must be None, 'int8' or 'fp8', "
+                             f"got {kv_dtype!r}")
+        if weight_dtype not in (None, "int8"):
+            raise ValueError(f"weight_dtype must be None or 'int8', "
+                             f"got {weight_dtype!r}")
+        c = model.config
+        # what the model caches: paged K/V for ``kv_layers`` layers, and
+        # one row per slot of each ``slot_state`` array (recurrent layers)
+        cache = model.cache_spec()
+        self.slot_state = dict(cache["slot_state"])
+        if self.slot_state:
+            asked = {"kv_dtype=": kv_dtype is not None,
+                     "host_kv_blocks=": int(host_kv_blocks or 0) > 0,
+                     "adapter_slots=": int(adapter_slots or 0) > 0,
+                     "mesh=": mesh is not None}
+            if any(asked.values()):
+                raise RecurrentStateUnsupported(
+                    f"{type(model).__name__} keeps recurrent state per "
+                    "request, which "
+                    + ", ".join(k for k, v in asked.items() if v)
+                    + " cannot carry yet")
+            # a prefix hit adopts K/V blocks and would skip the tokens
+            # that built the recurrent state
+            prefix_cache = False
+        S = int(max_seq_len or c.max_seq_len)
+        if not c.use_rope and S > c.max_seq_len:
+            raise ValueError(
+                f"max_seq_len {S} exceeds the model's "
+                f"learned-position table ({c.max_seq_len})")
+        super().__init__(max_slots, S, queue_size, eos_token_id,
+                         adapter_slots, tenant_buckets)
+        self.model = model
+        self.config = c
+        self.kv_layers = int(cache["kv_layers"])
+        self.adapter_rank = int(adapter_rank)   # LoRA (adapter_slots > 0)
+        self.block_size = int(block_size)
+        self.n_blocks = n_blocks
+        self.prefill_chunk = prefill_chunk
+        self.prefix_caching = bool(prefix_cache)
+        self.kv_dtype = kv_dtype
+        self.weight_dtype = weight_dtype
+        # host-RAM KV tier knobs (0 disables)
+        self.host_kv_blocks = int(host_kv_blocks or 0)
+        self.spill_idle_steps = int(spill_idle_steps or 0)
+        self.min_bucket = int(min_bucket)
+        self._captured = set()    # program names already sent to telemetry
+        # the arena owns every declared device-resident leaf (weights, KV
+        # pools, scale pools) with resolved NamedSharding specs; with
+        # mesh=None it is a bit-identical pass-through
+        with span("serving.engine_init", level=0):
+            self.arena = StateArena(mesh=mesh, shard_rules=shard_rules)
+            if weight_dtype == "int8":
+                from ..quantization import ptq_int8_decode_state
+                self._w = self.arena.declare_tree(
+                    "weights", ptq_int8_decode_state(model))
+            else:
+                self._w = self.arena.declare_tree(
+                    "weights", model.decode_state())
+            self._init_kv(c, self.max_slots, S, int(cache["kv_heads"]),
+                          int(cache["head_dim"]), jnp.dtype(c.dtype))
         self.hists["serving.kv.block_occupancy"] = metrics.Histogram(
             "serving.kv.block_occupancy", "frac")
 
@@ -269,6 +397,8 @@ class PagedLLMEngine(LLMEngine):
             self.arena.bind("state." + n, None if v is None else v[n])
 
     def release_kv(self):
+        """Drop the device KV storage (a dead replica's arena is garbage
+        — the fleet frees its HBM before respawning)."""
         self._pk = self._pv = self._sk = self._sv = self._st = None
         if self.adapters is not None:
             self.adapters.release_slabs()
@@ -295,6 +425,10 @@ class PagedLLMEngine(LLMEngine):
             self.adapters.register(tenant, factors)
 
     def adapter_peek(self, tenant):
+        """Tokens of prefill-equivalent work saved because ``tenant``'s
+        LoRA factors are already resident in this replica's adapter
+        arena (0 without adapters).  The Router folds this into the same
+        cost model as ``prefix_peek`` for tenant-affine dispatch."""
         if self.adapters is None or tenant is None:
             return 0
         with self._cond:
@@ -315,6 +449,9 @@ class PagedLLMEngine(LLMEngine):
         return [t + salt for t in tokens]
 
     def prefix_peek(self, prompt, tenant=None):
+        """Tokens of ``prompt`` the prefix cache could serve without
+        prefilling (0 with the cache off).  ``tenant`` scopes the probe
+        to that adapter's KV plane (see :meth:`_prefix_key`)."""
         if self.prefix is None:
             return 0
         ids = np.asarray(
@@ -344,8 +481,47 @@ class PagedLLMEngine(LLMEngine):
                 int(ids.shape[0]) - 1)
 
     # -- compiled programs ---------------------------------------------------
+    def _maybe_capture(self, name, fn, *args):
+        """Record HBM/compile/FLOPs stats for a compiled program, once per
+        program name (gated by FLAGS_device_telemetry; the AOT lower costs
+        a second trace, so the serving.retraces warm-path invariant only
+        holds with telemetry off)."""
+        if metrics.device_telemetry_enabled() and name not in self._captured:
+            self._captured.add(name)
+            metrics.capture_program_stats(name, fn, *args)
+
+    def _maybe_audit(self, name, fn, *args, donate_argnums=()):
+        """AOT-audit a compiled program once per name under
+        FLAGS_program_audit (donation aliasing, host callbacks, static
+        shapes, collective census — see analysis/program_audit).  Like
+        ``_maybe_capture``, the audit's extra AOT trace bumps
+        ``serving.retraces`` once per program, at the compile/warmup site
+        only — steady-state windows see a no-op set lookup."""
+        from ..analysis import program_audit as _audit
+        expected = self.arena.expected_collectives
+        if expected is not None:
+            # multi-device arena: in-graph collectives (GSPMD's TP
+            # reductions) are expected; anything else still fails
+            _audit.maybe_audit(name, fn, *args,
+                               donate_argnums=donate_argnums,
+                               expected_collectives=expected)
+        else:
+            _audit.maybe_audit(name, fn, *args,
+                               donate_argnums=donate_argnums,
+                               expect_no_collectives=True)
+
+    @staticmethod
+    def _first_token(logits, key_data, do_sample, temp, top_k, top_p):
+        """The prefill's first token from ``logits[1, V]``: the shared
+        sampling tail over a batch of one (identical key discipline and
+        math to generate's post-prefill draw)."""
+        nxt, new_keys = next_tokens(
+            logits, key_data[None],
+            *(jnp.reshape(x, (1,)) for x in (do_sample, temp, top_k, top_p)))
+        return nxt[0], new_keys[0]
+
     # The jitted callables live in the per-model cache shared by every
-    # engine over the same model (see engine._model_programs): the
+    # engine over the same model (see _model_programs above): the
     # closures capture the MODEL only, and jax.jit keys compiled variants
     # by argument shape, so chunk buckets and differing pool sizes each
     # get their own executable while identical engines reuse them.
@@ -1176,9 +1352,9 @@ class PagedLLMEngine(LLMEngine):
                 _fi.maybe_fault("serving_prefill", req.rid)
                 self._run_chunk(slot, st, events)
             except Exception as e:
-                # same containment contract as the slot engine's _admit:
-                # a poisoned prefill finishes THIS request with
-                # finish_reason="error" and frees its slot + blocks
+                # a poisoned request (bad prompt, injected fault, prefill
+                # blow-up) must not kill the engine loop: contain it to
+                # finish_reason="error" and free its slot + blocks
                 req.error = e
                 counters.inc("serving.request_errors")
                 self._finish(req, "error", events)
@@ -1514,7 +1690,7 @@ class PagedLLMEngine(LLMEngine):
     def finish_migrated(self, req):
         """Source-side release after the destination adopted (or the
         fleet abandoned) a migration: finish the held request with
-        reason ``"migrated"`` — ``_release_slot_kv`` donates the
+        reason ``"migrated"`` — ``_release_blocks`` donates the
         sequence's blocks to THIS engine's prefix tree (a replayed or
         prefix-sharing prompt re-resolves them here) and drops every
         table reference.  The fleet re-points its stream handle BEFORE
@@ -1525,7 +1701,7 @@ class PagedLLMEngine(LLMEngine):
         return done
 
     # -- eviction / teardown -------------------------------------------------
-    def _release_slot_kv(self, slot, req, reason):
+    def _release_blocks(self, slot, req, reason):
         """Free a finished request's table: donate the sequence's blocks
         to the prefix tree (when prefill completed cleanly), then drop
         the request's references.  Caller holds ``_cond``."""
@@ -1570,7 +1746,7 @@ class PagedLLMEngine(LLMEngine):
             slot = req.slot
             done = super()._finish(req, reason, events)
             if done and slot is not None:
-                self._release_slot_kv(slot, req, reason)
+                self._release_blocks(slot, req, reason)
         return done
 
     # -- scheduling ----------------------------------------------------------
@@ -1616,14 +1792,13 @@ class PagedLLMEngine(LLMEngine):
             np.bincount(self._bt.ravel(), minlength=1)[TRASH_BLOCK + 1:]))
 
     def stats(self):
-        """Slot-engine snapshot plus the block-pool / prefix-cache
+        """The lifecycle's snapshot plus the block-pool / prefix-cache
         fields the Router's fleet aggregation merges (one lock
         acquisition; the RLock makes the nested base call atomic)."""
         with self._cond:
             st = super().stats()
             live = self._blocks_live()
             st.update({
-                "kv_layout": "paged",
                 "kv_dtype": self.kv_dtype,
                 "kv_kernel": self.kv_kernel,
                 "weight_dtype": self.weight_dtype,

@@ -173,8 +173,8 @@ class Router:
 
         With ``prompt`` (the request's token ids) the score becomes a
         prefix-economy cost model: each candidate's backlog is discounted
-        by the prompt tokens its paged radix tree could serve
-        (``LLMEngine.prefix_probe``; ``(0, 0)`` under the slot layout) —
+        by the prompt tokens its radix tree could serve
+        (``LLMEngine.prefix_probe``) —
         device-resident tokens at full weight, host-tier-resident tokens
         discounted by ``restore_cost`` (they save the prefill FLOPs but
         pay a page-in) — so shared-prompt traffic gravitates to the

@@ -6,8 +6,8 @@ knobs, one PRNG key per step over [B, V] logits) and the serving engines'
 programs (per-slot knob ARRAYS, one key per slot).
 
 The serving programs' whole sampling tail lives here too, once:
-``next_tokens`` is what ``PagedLLMEngine``'s and ``LLMEngine``'s decode
-programs, every prefill program's first-token draw (a batch of one) and
+``next_tokens`` is what ``LLMEngine``'s decode program, every prefill
+chunk program's first-token draw (a batch of one) and
 the speculative drafter's proposal draw all trace.  It branches ON THE
 DEVICE on what the batch's own ``do_sample`` row shows: a batch with no
 sampling row runs ``argmax`` alone and never the two vocabulary-wide

@@ -38,7 +38,7 @@ namespaces (the same id indexes either the target arena ``[L, n_blocks,
 bs, nh, hd]`` or the draft arena ``[L_d, n_blocks, bs, nh_d, hd_d]``
 depending on whose table holds it; draft blocks are never donated to the
 target-namespace prefix tree).  The target's worst-case table is pinned
-at admission exactly as in ``PagedLLMEngine`` (``n_valid`` caps verify
+at admission exactly as in ``LLMEngine`` (``n_valid`` caps verify
 writes to the reservation), while the draft table grows ahead of each
 round and is ROLLED BACK after rejection by truncating the block table
 and releasing refcounts — stale rejected-draft KV is simply overwritten
@@ -68,10 +68,9 @@ from ..profiler import devicetime as _devicetime
 from ..profiler import flight
 from ..profiler import trace as rtrace
 from ..profiler.host_tracer import span
-from .engine import (RecurrentStateUnsupported, _model_programs,
-                     bucket_length)
+from .engine import RecurrentStateUnsupported, bucket_length
 from .kvcache import blocks_for_tokens
-from .paged import PagedLLMEngine
+from .paged import LLMEngine, _model_programs
 from .sampling import filter_logits, next_tokens, residual_sample
 
 __all__ = ["SpeculativeLLMEngine"]
@@ -150,8 +149,8 @@ def _acceptance(logits, toks, q, nv, keys_data, do_sample, temp, top_k,
     return emit, acc + 1, jax.random.key_data(new_keys)
 
 
-class SpeculativeLLMEngine(PagedLLMEngine):
-    """``PagedLLMEngine`` with draft/verify speculative decoding.
+class SpeculativeLLMEngine(LLMEngine):
+    """``LLMEngine`` with draft/verify speculative decoding.
 
     Extra knobs:
 
@@ -165,11 +164,6 @@ class SpeculativeLLMEngine(PagedLLMEngine):
         draft = kw.pop("draft_model", None)
         if draft is None:
             raise ValueError("SpeculativeLLMEngine requires draft_model=")
-        if kw.get("kv_layout", "paged") != "paged":
-            raise ValueError(
-                "draft_model= requires kv_layout='paged' (speculative "
-                "decoding runs over the block-pool arena)")
-        kw["kv_layout"] = "paged"
         k = int(kw.pop("spec_k", 4))
         if k < 1:
             raise ValueError(f"spec_k must be >= 1, got {k}")
@@ -764,8 +758,8 @@ class SpeculativeLLMEngine(PagedLLMEngine):
                 self._emit(req, int(emit[s, i]), events)
 
     # -- teardown / stats ----------------------------------------------------
-    def _release_slot_kv(self, slot, req, reason):
-        super()._release_slot_kv(slot, req, reason)
+    def _release_blocks(self, slot, req, reason):
+        super()._release_blocks(slot, req, reason)
         dbl = self._dslot_blocks[slot]
         self._dslot_blocks[slot] = None
         self._dbt[slot] = 0

@@ -54,7 +54,7 @@ def _paged(m, **kw):
     kw.setdefault("min_bucket", 4)
     kw.setdefault("block_size", 4)
     kw.setdefault("prefill_chunk", 8)
-    return LLMEngine(m, kv_layout="paged", **kw)
+    return LLMEngine(m, **kw)
 
 
 def _adapter_engine(m, slots=3, rank=4, **kw):
@@ -85,12 +85,6 @@ def _arena_reconciles(eng):
 
 
 class TestValidationAndFactors:
-    def test_adapter_slots_requires_paged_layout(self):
-        with pytest.raises(ValueError, match="adapter_slots"):
-            from paddle_tpu.serving import LLMEngine
-            LLMEngine(_model(), kv_layout="slots", max_slots=2,
-                      max_seq_len=32, adapter_slots=2)
-
     def test_adapter_request_on_adapter_free_engine_refused(self):
         eng = _paged(_model())
         with pytest.raises(ValueError, match="adapter"):
@@ -530,7 +524,7 @@ class TestFleetAdapters:
         solo.release_kv()
         with ServingFleet(m, replicas=2, threaded=False, max_slots=2,
                           max_seq_len=32, min_bucket=4, queue_size=16,
-                          kv_layout="paged", block_size=4,
+                          block_size=4,
                           prefill_chunk=8, heartbeat_timeout_s=30.0,
                           adapter_slots=2, adapter_rank=4) as fleet:
             fleet.register_adapter("t1", _factors(1))
@@ -566,7 +560,7 @@ class TestFleetAdapters:
         m = _model()
         with ServingFleet(m, replicas=2, threaded=False, max_slots=2,
                           max_seq_len=32, min_bucket=4, queue_size=16,
-                          kv_layout="paged", block_size=4,
+                          block_size=4,
                           prefill_chunk=8, heartbeat_timeout_s=30.0,
                           adapter_slots=2, adapter_rank=4) as fleet:
             fleet.register_adapter("t1", _factors(1))

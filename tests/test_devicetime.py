@@ -197,7 +197,7 @@ class TestEngineSampling:
 
         def engine():
             return LLMEngine(model, max_slots=2, max_seq_len=32,
-                             min_bucket=4, kv_layout="paged",
+                             min_bucket=4,
                              block_size=4, prefill_chunk=8)
 
         def run(eng):

@@ -73,7 +73,6 @@ def _fleet(m, **kw):
     kw.setdefault("min_bucket", 4)
     kw.setdefault("queue_size", 16)
     kw.setdefault("heartbeat_timeout_s", 30.0)
-    kw.setdefault("kv_layout", "paged")
     kw.setdefault("block_size", BS)
     kw.setdefault("n_blocks", 128)
     kw.setdefault("prefill_chunk", 16)
@@ -96,12 +95,6 @@ def _assert_pools_reconcile(fleet):
 
 # -- construction ------------------------------------------------------------
 class TestConstruction:
-    def test_requires_paged_layout(self, model):
-        with pytest.raises(ValueError, match="paged"):
-            ServingFleet(model, replicas=2, prefill_replicas=1,
-                         threaded=False, kv_layout="slots",
-                         max_seq_len=64)
-
     def test_requires_a_decode_replica(self, model):
         with pytest.raises(ValueError, match="decode"):
             _fleet(model, replicas=2, prefill_replicas=2)
@@ -559,7 +552,7 @@ def _engine(m, **kw):
     kw.setdefault("min_bucket", 4)
     kw.setdefault("block_size", BS)
     kw.setdefault("prefill_chunk", 16)
-    return LLMEngine(m, kv_layout="paged", **kw)
+    return LLMEngine(m, **kw)
 
 
 def _engine_reconciles(eng):

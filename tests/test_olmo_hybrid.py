@@ -224,7 +224,7 @@ def test_state_in_bfloat16_fails_the_tolerance(model, cfg, ids):
 # through LLMEngine
 # ---------------------------------------------------------------------------
 def _engine(model, **kw):
-    args = dict(kv_layout="paged", block_size=16, max_slots=2,
+    args = dict(block_size=16, max_slots=2,
                 max_seq_len=256, n_blocks=40, prefill_chunk=64)
     args.update(kw)
     return LLMEngine(model, **args)
@@ -269,6 +269,16 @@ def test_engine_serves_what_the_reference_puts_first(model, cfg):
         assert _gap(cfg, p, h.tokens) < TOL
 
 
+def test_engine_without_a_keyword_serves_a_model_with_state(model):
+    """No second layout to refuse: ``LLMEngine(model)`` builds the one
+    engine, which holds the recurrent state beside the pool."""
+    eng = LLMEngine(model, max_slots=2, max_seq_len=64)
+    assert type(eng) is LLMEngine
+    st = eng.stats()
+    assert st["state_bytes"] > 0 and st["blocks_total"] == 2 * 4
+    assert "kv_layout='slots'" not in RecurrentStateUnsupported.__doc__
+
+
 def test_prefix_cache_is_resolved_off_and_says_so(model):
     eng = _engine(model, prefix_cache=True)
     assert eng.prefix is None and eng.stats()["prefix_cache"] is False
@@ -277,7 +287,7 @@ def test_prefix_cache_is_resolved_off_and_says_so(model):
     gpt = GPTForCausalLM(GPTConfig(vocab_size=64, hidden_size=32,
                                    num_layers=2, num_heads=4, max_seq_len=64,
                                    use_flash_attention=False))
-    st = LLMEngine(gpt, kv_layout="paged", max_slots=2).stats()
+    st = LLMEngine(gpt, max_slots=2).stats()
     assert st["prefix_cache"] is True and st["state_bytes"] == 0
 
 
@@ -289,13 +299,12 @@ def _gpt(vocab=512):
 
 
 @pytest.mark.parametrize("how", [
-    "slots", "kv_dtype", "host_kv_blocks", "adapter_slots", "mesh",
+    "kv_dtype", "host_kv_blocks", "adapter_slots", "mesh",
     "draft_model", "hybrid_draft", "export_request", "adopt_migration"])
 def test_what_would_lose_the_state_is_refused(model, how):
     if how == "mesh":
         mesh = jax.sharding.Mesh(np.array(jax.devices()[:2]), ("mp",))
     build = {
-        "slots": lambda: LLMEngine(model, max_slots=2, max_seq_len=64),
         "kv_dtype": lambda: _engine(model, kv_dtype="int8"),
         "host_kv_blocks": lambda: _engine(model, host_kv_blocks=8),
         "adapter_slots": lambda: _engine(model, adapter_slots=2),
@@ -383,18 +392,18 @@ def test_padded_heads_never_reach_the_output(interpret_mode):
 def test_gpt_pool_pads_too(interpret_mode):
     """The open fault of head counts that are not whole tiles: a GPT of 3
     heads of 128 now decodes through the kernel over a pool of 8, and
-    serves what the unpadded slot arena serves."""
+    serves what ``generate`` does over its unpadded cache."""
     from paddle_tpu.models import GPTConfig, GPTForCausalLM
     gpt = GPTForCausalLM(GPTConfig(
         vocab_size=128, hidden_size=384, num_layers=2, num_heads=3,
         max_seq_len=64, use_flash_attention=False))
     gpt.eval()
     prompt = np.arange(3, 30, dtype=np.int32)
-    eng = LLMEngine(gpt, kv_layout="paged", max_slots=2, prefill_chunk=16)
+    eng = LLMEngine(gpt, max_slots=2, prefill_chunk=16)
     assert eng.stats()["kv_kernel"] == "pallas" and eng._pk.shape[3] == 8
     a = eng.add_request(prompt, max_new_tokens=6, seed=0)
     _drain(eng)
-    slots = LLMEngine(gpt, max_slots=2)
-    b = slots.add_request(prompt, max_new_tokens=6, seed=0)
-    _drain(slots)
-    assert a.tokens == b.tokens
+    import paddle_tpu as paddle
+    want = np.asarray(gpt.generate(paddle.to_tensor(prompt[None]),
+                                   max_new_tokens=6).numpy())[0, len(prompt):]
+    assert a.tokens == want.tolist()

@@ -45,7 +45,7 @@ def _paged(m, **kw):
     kw.setdefault("min_bucket", 8)
     kw.setdefault("block_size", 4)
     kw.setdefault("prefill_chunk", 8)
-    return LLMEngine(m, kv_layout="paged", **kw)
+    return LLMEngine(m, **kw)
 
 
 def _run(eng, handles, limit=300):
@@ -494,7 +494,7 @@ class TestQuantizedEngines:
             assert h.tokens == r
 
     # PTQ identity is also gated by check_counters.py's direct
-    # prefill_slot logit-drift check; full-suite only.
+    # prefill logit-drift check; full-suite only.
     @pytest.mark.slow
     def test_ptq_weights_token_identity(self):
         m = _model()
@@ -542,9 +542,9 @@ class TestQuantizedEngines:
         m = _model()
         with pytest.raises(ValueError, match="kv_dtype"):
             _paged(m, kv_dtype="int4")
-        with pytest.raises(ValueError, match="paged"):
-            LLMEngine(m, max_slots=2, max_seq_len=32, min_bucket=4,
-                      kv_dtype="int8")            # slot arena can't quantize
+        # no keyword needed: the one layout is the one that quantizes
+        assert LLMEngine(m, max_slots=2, max_seq_len=32, min_bucket=4,
+                         kv_dtype="int8").stats()["kv_dtype"] == "int8"
         with pytest.raises(ValueError, match="weight_dtype"):
             _paged(m, weight_dtype="fp4")
         from paddle_tpu.serving.kvcache import BlockPool

@@ -1,7 +1,7 @@
 """Paged KV-cache subsystem (paddle_tpu.serving.kvcache / .paged).
 
-The load-bearing contracts: (1) the paged engine is TOKEN-IDENTICAL to
-the legacy slot arena and to sequential GPT.generate — block tables,
+The load-bearing contracts: (1) the engine is TOKEN-IDENTICAL to
+sequential GPT.generate — block tables,
 prefix sharing, copy-on-write, and chunked prefill must be invisible in
 the tokens; (2) block accounting never tears — all-or-nothing
 reservation, refcounted sharing, LRU eviction only of unreferenced
@@ -42,7 +42,7 @@ def _paged(m, **kw):
     kw.setdefault("min_bucket", 4)
     kw.setdefault("block_size", 4)
     kw.setdefault("prefill_chunk", 8)
-    return LLMEngine(m, kv_layout="paged", **kw)
+    return LLMEngine(m, **kw)
 
 
 def _ref_generate(m, prompt, max_new, **kw):
@@ -218,17 +218,19 @@ class TestPrefixCache:
 
 
 class TestPagedIdentity:
-    def test_greedy_vs_generate_and_slot_engine(self):
+    def test_greedy_vs_generate_at_two_geometries(self):
+        """The defaults (a prompt in one block and one chunk) and small
+        blocks and chunks both serve what ``generate`` does."""
         m = _model()
         from paddle_tpu.serving import LLMEngine
         rng = np.random.default_rng(0)
         prompts = [rng.integers(0, 64, size=n).tolist()
                    for n in (5, 3, 9, 6, 11)]
         refs = [_ref_generate(m, p, 6) for p in prompts]
-        slot = LLMEngine(m, max_slots=3, max_seq_len=32, min_bucket=4)
-        hs = [slot.add_request(p, max_new_tokens=6, seed=i)
+        whole = LLMEngine(m, max_slots=3, max_seq_len=32, min_bucket=4)
+        hs = [whole.add_request(p, max_new_tokens=6, seed=i)
               for i, p in enumerate(prompts)]
-        _run(slot, hs)
+        _run(whole, hs)
         paged = _paged(m)
         hp = [paged.add_request(p, max_new_tokens=6, seed=i)
               for i, p in enumerate(prompts)]
@@ -493,7 +495,7 @@ class TestFleetPagedChaos:
         rng = np.random.default_rng(11)
         sys_p = rng.integers(0, 64, size=8).tolist()
         with ServingFleet(m, replicas=2, max_slots=2, max_seq_len=32,
-                          min_bucket=4, threaded=False, kv_layout="paged",
+                          min_bucket=4, threaded=False,
                           block_size=4, prefill_chunk=8) as fleet:
             reqs = [fleet.submit(sys_p + rng.integers(0, 64, size=3).tolist(),
                                  max_new_tokens=4, seed=i)

@@ -113,6 +113,18 @@ class TestChannelScales:
         assert np.all(np.abs(dq - w) <= np.asarray(s) / 2 + 1e-7)
 
 
+def _prompt_logits(m, w, ids):
+    """The logits after ``ids[1, T]``: one prefill chunk over a pool that
+    holds this one request (blocks of 4, none shared)."""
+    import jax.numpy as jnp
+    c = m.config
+    T = ids.shape[1]
+    pool = jnp.zeros((c.num_layers, T // 4, 4, c.num_heads,
+                      c.hidden_size // c.num_heads), jnp.dtype(c.dtype))
+    return np.asarray(m.prefill_paged(
+        w, ids, 0, T, jnp.arange(T // 4, dtype=jnp.int32), pool, pool)[-1])
+
+
 class TestPTQDecodeState:
     def test_swaps_exactly_the_matmul_weights(self):
         m = _model()
@@ -139,9 +151,7 @@ class TestPTQDecodeState:
         w_fp = m.decode_state()
         w_q = ptq_int8_decode_state(m)
         ids = jnp.asarray(np.arange(16)[None, :] % 64, jnp.int32)
-        _, _, ref = m.prefill_slot(w_fp, ids, 16)
-        _, _, got = m.prefill_slot(w_q, ids, 16)
-        ref, got = np.asarray(ref), np.asarray(got)
+        ref, got = _prompt_logits(m, w_fp, ids), _prompt_logits(m, w_q, ids)
         drift = np.abs(got - ref).max()
         assert drift <= 0.05 * np.abs(ref).max(), drift
 
@@ -152,7 +162,5 @@ class TestPTQDecodeState:
         w_q = ptq_int8_decode_state(m, observer="percentile",
                                     percentile=99.9)
         ids = jnp.asarray(np.arange(12)[None, :] % 64, jnp.int32)
-        _, _, ref = m.prefill_slot(w_fp, ids, 12)
-        _, _, got = m.prefill_slot(w_q, ids, 12)
-        ref, got = np.asarray(ref), np.asarray(got)
+        ref, got = _prompt_logits(m, w_fp, ids), _prompt_logits(m, w_q, ids)
         assert np.abs(got - ref).max() <= 0.05 * np.abs(ref).max()

@@ -648,6 +648,12 @@ def _record_program(eng, attr, key=None):
     return seen
 
 
+# the engine's own geometry (one block and one chunk hold a whole prompt
+# of these tests) and one where a prompt spans blocks and chunks
+_GEOMETRIES = {"defaults": {},
+               "small_blocks": dict(block_size=4, prefill_chunk=8)}
+
+
 class TestSamplingTail:
     @pytest.mark.parametrize("mix", sorted(_KNOB_MIXES))
     def test_next_tokens_bitwise_old_decode_tail(self, mix):
@@ -717,12 +723,12 @@ class TestSamplingTail:
         assert float(jnp.sum(jnp.where(masked, jax.nn.softmax(lg), 0))) < 1e-4
 
     def test_sorts_only_inside_a_conditional_branch(self):
-        """The paged decode program's and a prefill chunk program's
+        """The decode program's and a prefill chunk program's
         lowered HLO keep every ``sort`` inside a ``conditional``'s branch:
         the ``cond`` sits outside the per-row ``vmap`` and did not turn
         into a select that runs both sides."""
         m = _model()
-        eng = _engine(m, kv_layout="paged", block_size=4, prefill_chunk=8)
+        eng = _engine(m, block_size=4, prefill_chunk=8)
         h = eng.add_request([3, 1, 4, 1, 5], max_new_tokens=2)
         _run(eng, [h])                               # builds the programs
         bucket = next(iter(eng._pchunk_jits))
@@ -741,8 +747,8 @@ class TestSamplingTail:
             *_tail_operands("all_greedy")).as_text(dialect="hlo")
         assert _sorts_outside_conditionals(bad)[1]
 
-    @pytest.mark.parametrize("layout", ["slot", "paged"])
-    def test_neutral_row_ignores_a_filtering_neighbour(self, layout):
+    @pytest.mark.parametrize("geometry", sorted(_GEOMETRIES))
+    def test_neutral_row_ignores_a_filtering_neighbour(self, geometry):
         """A request's output does not depend on who shares its batch: a
         sampling row with neutral knobs draws the same tokens alone and
         beside a row that filters (the one predicate is any(do_sample),
@@ -751,8 +757,7 @@ class TestSamplingTail:
         rng = np.random.default_rng(12)
         pa = rng.integers(0, 64, size=6).tolist()
         pb = rng.integers(0, 64, size=5).tolist()
-        kw = ({} if layout == "slot" else
-              dict(kv_layout="paged", block_size=4, prefill_chunk=8))
+        kw = _GEOMETRIES[geometry]
         ref = _ref_generate(m, pa, 8, do_sample=True, seed=21)
         eng = _engine(m, **kw)
         alone = eng.add_request(pa, max_new_tokens=8, do_sample=True,
@@ -767,8 +772,8 @@ class TestSamplingTail:
         assert list(alone.tokens) == list(beside.tokens)
         assert np.array_equal(alone.tokens, ref)
 
-    @pytest.mark.parametrize("layout", ["slot", "paged"])
-    def test_sampled_steps_counts_steps_with_a_sampling_row(self, layout):
+    @pytest.mark.parametrize("geometry", sorted(_GEOMETRIES))
+    def test_sampled_steps_counts_steps_with_a_sampling_row(self, geometry):
         """``serving.decode.sampled_steps`` beside ``serving.decode_steps``
         in the mixed batch of ``test_mixed_greedy_and_sampled_slots``: the
         sampling request decodes 3 times (its first token is the
@@ -778,8 +783,7 @@ class TestSamplingTail:
         rng = np.random.default_rng(3)
         pg = rng.integers(0, 64, size=5).tolist()
         ps = rng.integers(0, 64, size=7).tolist()
-        kw = ({} if layout == "slot" else
-              dict(kv_layout="paged", block_size=4, prefill_chunk=8))
+        kw = _GEOMETRIES[geometry]
         eng = _engine(m, **kw)
         before = counters.snapshot()
         hg = eng.add_request(pg, max_new_tokens=8)
@@ -797,3 +801,117 @@ class TestSamplingTail:
         h = eng.add_request(pg, max_new_tokens=3)
         _run(eng, [h])
         assert counters.snapshot()["serving.decode.sampled_steps"] == 0
+
+
+# ---------------------------------------------------------------------------
+# One KV layout (PR 30): ``LLMEngine(model)`` is the paged engine, and the
+# switch that chose between two layouts is gone.  Every case fails at the
+# parent commit.
+# ---------------------------------------------------------------------------
+def _draft():
+    from paddle_tpu.models import GPTConfig, GPTForCausalLM
+    d = GPTForCausalLM(GPTConfig(vocab_size=64, hidden_size=32,
+                                 num_layers=1, num_heads=4, max_seq_len=32,
+                                 use_flash_attention=False))
+    d.eval()
+    return d
+
+
+class TestOneLayout:
+    @pytest.mark.parametrize("key", ["blocks_total", "blocks_live",
+                                     "prefix_cache", "kv_kernel"])
+    def test_engine_without_a_keyword_reports_its_block_pool(self, key):
+        st = _engine(_model()).stats()
+        assert key in st and "kv_layout" not in st
+
+    def test_engine_without_a_keyword_answers_a_prefix_probe(self):
+        m = _model()
+        eng = _engine(m)
+        prompt = list(range(1, 21))
+        assert eng.prefix_probe(prompt) == (0, 0)
+        _run(eng, [eng.add_request(prompt, max_new_tokens=2)])
+        # a whole block of 16 and a partial one of 3 (the last prompt
+        # token is always prefilled), both on the device
+        assert eng.prefix_probe(prompt) == (19, 0)
+        assert eng.prefix_peek(prompt) == 19
+
+    def test_defaults_hold_every_slot_at_full_length(self):
+        """``LLMEngine(model, max_slots=B)`` keeps the memory it had:
+        a pool of ``B * ceil(S_max / 16) + 1`` blocks, chunks of
+        ``min(S_max, 128)``, the prefix cache on."""
+        from paddle_tpu.serving import LLMEngine
+        eng = LLMEngine(_model(), max_slots=5)
+        assert (eng.n_blocks, eng.block_size) == (5 * 2 + 1, 16)
+        assert eng.prefill_chunk == 32 and eng.prefix is not None
+        assert eng._pk.shape[:3] == (2, 11, 16)
+
+    @pytest.mark.parametrize("how", ["plain", "draft_model"])
+    def test_the_class_built_is_the_public_one(self, how):
+        from paddle_tpu import serving
+        from paddle_tpu.serving import LLMEngine, SpeculativeLLMEngine
+        if how == "plain":
+            assert type(LLMEngine(_model(), max_slots=2)) is LLMEngine
+        else:
+            eng = LLMEngine(_model(), max_slots=2, draft_model=_draft())
+            assert type(eng) is SpeculativeLLMEngine
+            assert SpeculativeLLMEngine.__bases__ == (LLMEngine,)
+        assert not hasattr(serving, "PagedLLMEngine")
+        assert "PagedLLMEngine" not in serving.__all__
+
+    @pytest.mark.parametrize("how", ["engine", "draft_model", "positional"])
+    def test_any_other_layout_is_refused_by_name(self, how):
+        from paddle_tpu.serving import LLMEngine
+        kw = {"draft_model": _draft()} if how == "draft_model" else {}
+        with pytest.raises(ValueError, match="removed in PR 30"):
+            if how == "positional":
+                LLMEngine(_model(), 2, None, 64, 4, None, "slots")
+            else:
+                LLMEngine(_model(), kv_layout="slots", **kw)
+        # the one value the benchmark's cell files still pass
+        assert LLMEngine(_model(), kv_layout="paged", max_slots=2,
+                         **kw).stats()["blocks_total"] > 0
+
+    def test_fleet_takes_no_layout(self):
+        from paddle_tpu.serving import ServingFleet
+        with pytest.raises(TypeError, match="kv_layout"):
+            ServingFleet(_model(), replicas=1, threaded=False,
+                         kv_layout="paged")
+
+    @pytest.mark.parametrize("cls,n", [("LLMEngine", 20),
+                                       ("ServingFleet", 32)])
+    def test_constructor_parameter_counts(self, cls, n):
+        """ROADMAP D7's counts (``self`` not counted)."""
+        import inspect
+        from paddle_tpu import serving
+        params = inspect.signature(getattr(serving, cls).__init__).parameters
+        assert len(params) - 1 == n, sorted(params)
+
+    def test_lifecycle_module_imports_neither_engine_above_it(self):
+        """``serving/engine.py`` (queue, deadlines, finish, drain) names
+        ``paged`` only inside the function that resolves the class's old
+        address, and ``speculative`` nowhere."""
+        import ast
+        import inspect
+        from paddle_tpu.serving import engine
+        tree = ast.parse(inspect.getsource(engine))
+        top = [n for n in tree.body
+               if isinstance(n, (ast.Import, ast.ImportFrom))]
+        named = {a.name for n in top for a in n.names} | {
+            n.module for n in top if isinstance(n, ast.ImportFrom)}
+        assert not named & {"paged", "speculative"}
+        lazy = [n.module for n in ast.walk(tree)
+                if isinstance(n, ast.ImportFrom) and n not in top]
+        assert lazy == ["paged"]
+
+    def test_lifecycle_alone_cannot_be_constructed(self):
+        from paddle_tpu.serving import LLMEngine, engine
+        with pytest.raises(TypeError, match="LLMEngine"):
+            engine._RequestLifecycle(2, 32, 4, None, 0, 8)
+        assert not hasattr(engine._RequestLifecycle, "step")
+        assert engine.LLMEngine is LLMEngine      # the old address
+
+    @pytest.mark.parametrize("phase", ["prefill", "decode"])
+    def test_model_serves_through_its_paged_method_only(self, phase):
+        m = _model()
+        assert hasattr(m, phase + "_paged")
+        assert not [n for n in dir(m) if n.startswith(phase + "_slot")]

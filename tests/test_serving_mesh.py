@@ -60,7 +60,7 @@ def _paged(m, **kw):
     kw.setdefault("min_bucket", 4)
     kw.setdefault("block_size", 4)
     kw.setdefault("prefill_chunk", 8)
-    return LLMEngine(m, kv_layout="paged", **kw)
+    return LLMEngine(m, **kw)
 
 
 def _run(eng, sampled=False, limit=300):
@@ -120,7 +120,7 @@ def test_mesh1_speculative_engine_bit_identical_with_counter_parity():
 
 
 def test_mesh1_tag_empty_and_programs_shared():
-    from paddle_tpu.serving.engine import _model_programs
+    from paddle_tpu.serving.paged import _model_programs
     mesh = _mesh(1)
     m = _fresh_model()
     e1 = _paged(m)
@@ -194,7 +194,7 @@ def test_mp2_fleet_replicas_construct_mesh_engines():
     mesh = _mesh(2)
     m = _fresh_model()
     fleet = ServingFleet(m, replicas=1, max_slots=3, max_seq_len=32,
-                         min_bucket=4, kv_layout="paged", block_size=4,
+                         min_bucket=4, block_size=4,
                          prefill_chunk=8, mesh=mesh)
     try:
         rep = fleet._replicas[0]

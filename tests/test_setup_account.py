@@ -222,7 +222,7 @@ def tiny_engine():
         vocab_size=64, hidden_size=32, num_layers=2, num_heads=4,
         max_seq_len=32, use_flash_attention=False))
     model.eval()
-    eng = LLMEngine(model, kv_layout="paged", max_slots=2, max_seq_len=32,
+    eng = LLMEngine(model, max_slots=2, max_seq_len=32,
                     min_bucket=4, block_size=4, prefill_chunk=8)
     h = eng.add_request(np.arange(1, 7, dtype=np.int32), max_new_tokens=3,
                         seed=0)

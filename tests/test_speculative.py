@@ -61,7 +61,7 @@ def _spec(target, draft, **kw):
     kw.setdefault("spec_k", 3)
     kw.setdefault("n_blocks", _nb(kw["max_slots"], kw["max_seq_len"],
                                   kw["block_size"]))
-    return LLMEngine(target, draft_model=draft, kv_layout="paged", **kw)
+    return LLMEngine(target, draft_model=draft, **kw)
 
 
 def _paged(target, **kw):
@@ -71,7 +71,7 @@ def _paged(target, **kw):
     kw.setdefault("min_bucket", 4)
     kw.setdefault("block_size", 4)
     kw.setdefault("prefill_chunk", 8)
-    return LLMEngine(target, kv_layout="paged", **kw)
+    return LLMEngine(target, **kw)
 
 
 def _ref_generate(m, prompt, max_new, **kw):
@@ -423,10 +423,7 @@ class TestAcceptanceCounters:
 
     def test_constructor_validation(self):
         from paddle_tpu.models import GPTConfig, GPTForCausalLM
-        from paddle_tpu.serving import LLMEngine
         target, draft = _models()
-        with pytest.raises(ValueError, match="kv_layout"):
-            LLMEngine(target, draft_model=draft, kv_layout="slots")
         with pytest.raises(ValueError, match="spec_k"):
             _spec(target, draft, spec_k=0)
         paddle.seed(5)
@@ -449,7 +446,7 @@ class TestFleetChaos:
         kw.setdefault("min_bucket", 4)
         kw.setdefault("heartbeat_timeout_s", 30.0)
         return ServingFleet(target, draft_model=draft, spec_k=2,
-                            kv_layout="paged", block_size=4,
+                            block_size=4,
                             prefill_chunk=8, n_blocks=_nb(kw["max_slots"]),
                             **kw)
 

@@ -58,15 +58,14 @@ def _model():
     return _MODEL
 
 
-def _engine(layout="paged", **kw):
+def _engine(**kw):
     from paddle_tpu.serving import LLMEngine
     kw.setdefault("max_slots", 3)
     kw.setdefault("max_seq_len", 32)
     kw.setdefault("min_bucket", 4)
-    if layout == "paged":
-        kw.setdefault("block_size", 4)
-        kw.setdefault("prefill_chunk", 8)
-    return LLMEngine(_model(), kv_layout=layout, **kw)
+    kw.setdefault("block_size", 4)
+    kw.setdefault("prefill_chunk", 8)
+    return LLMEngine(_model(), **kw)
 
 
 def _drain(eng, handles, limit=200):
@@ -396,8 +395,10 @@ class TestEngineStep:
             eng.step()
         assert len(host_tracer.lifecycle()) == n
 
-    def test_slot_engine_has_the_same_children(self):
-        eng = _engine("slots")
+    def test_default_geometry_has_the_same_children(self):
+        """One block and one chunk a prompt (block 16, chunk 32): the
+        step has the same children and counts its one pool."""
+        eng = _engine(block_size=16, prefill_chunk=None)
         _warm(eng)
         host_tracer.start()
         try:
@@ -408,7 +409,7 @@ class TestEngineStep:
         names = {e[0] for e in events}
         assert set(STEP_CHILDREN) <= names
         step = next(e for e in events if e[0] == "serving.step")
-        assert step[5] is None       # no pool of blocks: nothing to count
+        assert step[5]["blocks_total"] == eng.pool.capacity == 3 * 2
 
     def test_one_clock_for_stamps_spans_and_histograms(self):
         eng = _engine()
@@ -460,10 +461,10 @@ class TestNothingUnread:
 
     def test_blocks_live_is_counted_only_for_someone_who_profiles(
             self, monkeypatch):
-        from paddle_tpu.serving.paged import PagedLLMEngine
+        from paddle_tpu.serving import LLMEngine
         calls = []
-        real = PagedLLMEngine._blocks_live
-        monkeypatch.setattr(PagedLLMEngine, "_blocks_live",
+        real = LLMEngine._blocks_live
+        monkeypatch.setattr(LLMEngine, "_blocks_live",
                             lambda self: calls.append(1) or real(self))
         eng = _engine()
         _warm(eng)

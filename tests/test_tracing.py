@@ -116,7 +116,7 @@ class TestSampling:
 
 
 class TestSpanTrees:
-    def test_slot_engine_span_tree(self, model):
+    def test_engine_span_tree(self, model):
         _on(1.0)
         eng = _engine(model)
         h = _serve(eng, [eng.add_request([1, 2, 3, 4, 5],
@@ -125,7 +125,8 @@ class TestSpanTrees:
         assert ctx is not None and ctx.finished
         names = _names(ctx)
         assert "queue" in names
-        assert "prefill" in names
+        assert "kv.reserve" in names
+        assert names.count("prefill.chunk") == 1    # 5 tokens, chunk 32
         # prefill emits token 1; decode iterations emit the rest
         assert names.count("decode.iter") == 2
         assert "evict" in names                     # terminal marker
@@ -136,9 +137,9 @@ class TestSpanTrees:
         assert all(d["stage_ns"][s] > 0
                    for s in ("queue", "prefill", "decode"))
 
-    def test_paged_engine_records_kv_and_chunk_spans(self, model):
+    def test_small_chunks_record_one_span_each(self, model):
         _on(1.0)
-        eng = _engine(model, kv_layout="paged", block_size=4,
+        eng = _engine(model, block_size=4,
                       prefill_chunk=8)
         h = _serve(eng, [eng.add_request(list(range(1, 13)),
                                          max_new_tokens=3)])[0]
@@ -320,7 +321,7 @@ class TestOpsEndpoint:
                 srv.url(f"/traces/{h.trace.trace_id}"))
             t = json.loads(body)
             assert code == 200 and t["rid"] == h.rid
-            assert any(s["name"] == "prefill" for s in t["spans"])
+            assert any(s["name"] == "prefill.chunk" for s in t["spans"])
 
             code, body = self._get(srv.url("/goodput"))
             g = json.loads(body)
@@ -353,5 +354,5 @@ class TestExport:
         assert len(recs) >= 1
         assert any(r["status"] == "length" for r in recs)
         ev = rtrace.to_chrome_trace()["traceEvents"]
-        assert any(e.get("ph") == "X" and e.get("name") == "prefill"
+        assert any(e.get("ph") == "X" and e.get("name") == "prefill.chunk"
                    for e in ev)
