@@ -35,6 +35,9 @@ Well-known names (see README "Observability" for the full table):
   serving.decode_tokens / serving.evictions / serving.evictions.<reason>
   serving.decode.sampled_steps (decode launches with a running
       do_sample row: the ones whose sampling tail ran the filters)
+  serving.decode.upload_steps (decode launches that uploaded at least
+      one per-slot operand: a slot changed hands since the launch
+      before; the others took every operand from the device)
   serving.retraces (serving program compiles; 0 in steady state)
   serving.queue_wait_ns
   serving.deadline_expired (queued past-deadline, evicted pre-prefill)

@@ -189,9 +189,13 @@ class StateArena:
 
     def operand(self, x):
         """Commit a per-step operand (block tables, positions, sampling
-        params) — replicated on a multi-device arena so it never forces
-        a resharding transfer inside the dispatched program."""
-        if self.multi_device:
+        params) — replicated on the arena's mesh so it never forces a
+        resharding transfer inside the dispatched program, and typed like
+        the same operand when a program hands it back (the decode
+        program's carried tokens, positions and keys: on a mesh, of one
+        device too, an output's type names the mesh, and an upload that
+        did not would trace the program a second time)."""
+        if self.mesh is not None:
             return jax.device_put(x, NamedSharding(self.mesh, P()))
         return jnp.asarray(x)
 

@@ -27,7 +27,6 @@ import time
 import zlib
 from collections import deque
 
-import jax
 import numpy as np
 
 from ..profiler import counters
@@ -187,16 +186,6 @@ class _RequestLifecycle:
         # raw tenant id (adapter_slots=0: no tenants, no such histograms)
         self.adapter_slots = int(adapter_slots or 0)
         self.tenant_buckets = int(tenant_buckets)
-
-        # host mirrors of the per-slot decode inputs
-        key_size = jax.random.key_data(jax.random.key(0)).shape[0]
-        self._tok = np.zeros(B, np.int32)
-        self._pos = np.zeros(B, np.int32)
-        self._keys = np.zeros((B, key_size), np.uint32)
-        self._temp = np.ones(B, np.float32)
-        self._topk = np.zeros(B, np.int32)
-        self._topp = np.ones(B, np.float32)
-        self._dosample = np.zeros(B, np.bool_)
 
         self._slots: list = [None] * B
         self._free = list(range(B - 1, -1, -1))  # slot 0 handed out first
@@ -363,9 +352,6 @@ class _RequestLifecycle:
                 s = req.slot
                 self._slots[s] = None
                 self._free.append(s)
-                self._dosample[s] = False
-                self._tok[s] = 0
-                self._pos[s] = 0
                 req.slot = None
         counters.inc("serving.evictions")
         counters.inc(f"serving.evictions.{reason}")

@@ -46,6 +46,15 @@ the key-split schedule replicates ``generate``'s exactly (only the final
 chunk's sample is consumed), so engine output is token-identical to
 running each request alone through ``generate``.
 
+Those per-slot arrays (token, position, key, knobs, block table, the
+``running`` bit) live on the device between launches.  The decode program
+masks the rows that are not running itself and returns the next launch's
+tokens, positions and keys; the host uploads an array again only after
+something else wrote it (``_write_slot``: admission, a last prefill
+chunk, a finish, a spill or restore, an adoption).  A launch on which no
+slot changed hands makes no host-to-device copy and one read-back, the
+tokens (``serving.decode.upload_steps`` counts the others).
+
 The request's own life (queue, deadlines, cancellation, the finish
 compare-and-set, ``drain``) is ``serving.engine._RequestLifecycle``,
 which this class extends; ``serving.speculative`` extends this one.
@@ -61,6 +70,7 @@ import zlib
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from ..kernels import paged_attention as _pa
 from ..profiler import counters
@@ -103,6 +113,41 @@ def _model_programs(model):
     except TypeError:  # unhashable / non-weakrefable model object
         cache = model.__dict__.setdefault("_serving_programs", {})
     return cache
+
+
+# the decode program's per-slot operands, in the order it takes them
+# (an adapter engine appends the slab pytree and "aid")
+_DECODE_OPERANDS = ("bt", "tok", "pos", "running", "keys", "dosample",
+                    "temp", "topk", "topp")
+
+
+def _mask_idle(running, bt, pos, do_sample, aid=None):
+    """What the decode program computes on for a row that is not running
+    (idle, mid-prefill, parked for migration): the trash block at
+    position 0, no sampling, the base model's adapter row.  The ONE
+    decode program runs every launch with fixed shapes, whatever subset
+    of rows is live; a parked sampling row cannot send a greedy batch
+    down the sampling tail's long branch."""
+    return (jnp.where(running[:, None], bt, 0), jnp.where(running, pos, 0),
+            do_sample & running,
+            None if aid is None else jnp.where(running, aid, 0))
+
+
+def _sample_and_carry(mesh, logits, running, tok, pos, keys_data, do_sample,
+                      temp, top_k, top_p):
+    """The end of every decode program: the rows' next tokens (the shared
+    sampling tail), and with them the next launch's own operands as this
+    launch leaves them: a running row's new token, position and key;
+    every other row's as they came in.  Replicated on the arena's mesh,
+    like the uploads they stand in for."""
+    nxt, new_keys = next_tokens(logits, keys_data, do_sample, temp, top_k,
+                                top_p)
+    out = (jnp.where(running, nxt, tok), pos + running.astype(pos.dtype),
+           jnp.where(running[:, None], new_keys, keys_data))
+    if mesh is not None:
+        rep = NamedSharding(mesh, PartitionSpec())
+        out = tuple(jax.lax.with_sharding_constraint(x, rep) for x in out)
+    return out
 
 
 class LLMEngine(_RequestLifecycle):
@@ -297,9 +342,27 @@ class LLMEngine(_RequestLifecycle):
             self.kv_kernel = _pa.kernel_mode(nhp, hd)
         if self.kv_kernel == "pallas":
             _pa.preload()
-        # per-slot block tables (host mirror; rides decode as an operand)
+        # The per-slot decode state.  Each array lives twice: a host
+        # mirror (what the scheduler, the spill and the migration read)
+        # and, in ``_dev``, the device copy that the decode launch takes.
+        # The decode program carries tokens, positions and keys forward
+        # itself; whatever else writes a row goes through _write_slot,
+        # which names the array in ``_stale`` so that the next launch
+        # uploads it again.  A launch with nothing stale uploads nothing.
+        key_size = jax.random.key_data(jax.random.key(0)).shape[0]
         self._bt = np.zeros((B, self.max_blocks), np.int32)
+        self._tok = np.zeros(B, np.int32)
+        self._pos = np.zeros(B, np.int32)
         self._running = np.zeros(B, np.bool_)
+        self._keys_host = np.zeros((B, key_size), np.uint32)
+        self._dosample = np.zeros(B, np.bool_)
+        self._temp = np.ones(B, np.float32)
+        self._topk = np.zeros(B, np.int32)
+        self._topp = np.ones(B, np.float32)
+        # adapter arena row per slot (row 0 = base model)
+        self._aid = np.zeros(B, np.int32)
+        self._dev = {}
+        self._stale = set(_DECODE_OPERANDS)
         self._slot_blocks = [None] * B
         self._prefill_state = {}      # slot -> {"req": Request, "done": n}
         self._pchunk_jits = {}        # chunk bucket -> jitted prefill
@@ -334,9 +397,9 @@ class LLMEngine(_RequestLifecycle):
                 dispatch=self._adapter_dispatch)
         else:
             self.adapters = None
-        # per-slot adapter arena row (host mirror; rides every dispatch
-        # as an int32 operand — row 0 = base model)
-        self._aid = np.zeros(B, np.int32)
+        # the decode program's per-slot operands, in its order
+        self._operand_names = _DECODE_OPERANDS + (
+            ("aid",) if self.adapters is not None else ())
         # per-engine prefix-cache accounting (the fleet sums these; the
         # same events also feed the process-global counters registry)
         self.kv_prefix_hits = 0
@@ -395,6 +458,54 @@ class LLMEngine(_RequestLifecycle):
     def _st(self, v):
         for n in self._state_names:
             self.arena.bind("state." + n, None if v is None else v[n])
+
+    # -- the per-slot decode state ---------------------------------------
+    def _write_slot(self, where, **rows):
+        """The one writer of a slot's decode state from outside the
+        decode program: ``rows`` names the arrays (``tok``, ``pos``,
+        ``keys``, ``bt``, ``running``, ``dosample``, ``temp``, ``topk``,
+        ``topp``, ``aid``) and ``where`` indexes them (a slot, or
+        ``(slot, i)`` for one entry of a table).  The host mirror takes
+        the value and the array is marked stale, so the next decode
+        launch uploads it again.  Caller holds ``_cond`` where another
+        thread may step the engine."""
+        for name, value in rows.items():
+            getattr(self, "_" + name)[where] = value
+        self._stale.update(rows)
+
+    @property
+    def _keys(self):
+        """Host copy of the rows' key data.  The decode program carries
+        the chain on the device, so the copy is fetched when someone
+        asks (a last prefill chunk, ``export_request``, the speculative
+        round), not once a launch."""
+        with self._cond:
+            if self._keys_host is None:
+                self._keys_host = np.array(self._dev["keys"])
+            return self._keys_host
+
+    @_keys.setter
+    def _keys(self, value):
+        with self._cond:
+            self._keys_host = value
+            self._stale.add("keys")
+
+    def _decode_operands(self):
+        """The decode launch's per-slot operands, in the program's
+        order, and whether any had to be uploaded: only the arrays that
+        _write_slot marked since the last launch are; the rest are the
+        device arrays of the launch before."""
+        names = self._operand_names
+        with self._cond:
+            stale, self._stale = self._stale.intersection(names), set()
+            # copies: a backend may alias a host array it is handed
+            fresh = {n: np.array(getattr(self, "_" + n)) for n in stale}
+        for n, value in fresh.items():
+            self._dev[n] = self.arena.operand(value)
+        ops = [self._dev[n] for n in names]
+        if self.adapters is not None:
+            ops.insert(-1, self.adapters.slabs())
+        return tuple(ops), bool(fresh)
 
     def release_kv(self):
         """Drop the device KV storage (a dead replica's arena is garbage
@@ -613,46 +724,64 @@ class LLMEngine(_RequestLifecycle):
                     else None)
             head_axis = "mp" if mesh is not None else None
 
+            # the carried operands stay replicated on the arena's mesh,
+            # as arena.operand uploads them
+            rep = self.arena.mesh
+
             def build():
                 lora = self.adapters is not None
 
+                # every variant takes the rows' UNMASKED state and masks
+                # it itself, and returns, after the tokens and the pools,
+                # the positions and keys of the next launch; the tokens it
+                # returns are the next launch's too
                 if self.slot_state:
                     def decode(w, pk, pv, st, bt, tok, pos, running,
                                keys_data, do_sample, temp, top_k, top_p):
                         counters.inc("serving.retraces")
+                        bt_e, pos_e, ds_e, _ = _mask_idle(
+                            running, bt, pos, do_sample)
                         logits, pk, pv, st = model.decode_paged(
-                            w, tok, pos, bt, pk, pv, st, running,
+                            w, tok, pos_e, bt_e, pk, pv, st, running,
                             kernel=mode)
-                        nxt, new_keys = next_tokens(
-                            logits, keys_data, do_sample, temp, top_k, top_p)
-                        return nxt, pk, pv, st, new_keys
+                        nxt, pos, keys_data = _sample_and_carry(
+                            rep, logits, running, tok, pos, keys_data, ds_e,
+                            temp, top_k, top_p)
+                        return nxt, pk, pv, st, pos, keys_data
                     return jax.jit(decode, donate_argnums=(1, 2, 3))
 
                 if self.kv_dtype:
-                    def decode(w, pk, pv, sk, sv, bt, tok, pos, keys_data,
-                               do_sample, temp, top_k, top_p, *ad):
+                    def decode(w, pk, pv, sk, sv, bt, tok, pos, running,
+                               keys_data, do_sample, temp, top_k, top_p,
+                               *ad):
                         counters.inc("serving.retraces")
                         aw, aid = ad if lora else (None, None)
+                        bt_e, pos_e, ds_e, aid = _mask_idle(
+                            running, bt, pos, do_sample, aid)
                         logits, pk, pv, sk, sv = model.decode_paged(
-                            w, tok, pos, bt, pk, pv, sk, sv, kernel=mode,
-                            mesh=mesh, head_axis=head_axis,
+                            w, tok, pos_e, bt_e, pk, pv, sk, sv,
+                            kernel=mode, mesh=mesh, head_axis=head_axis,
                             adapters=aw, adapter_ids=aid)
-                        nxt, new_keys = next_tokens(
-                            logits, keys_data, do_sample, temp, top_k, top_p)
-                        return nxt, pk, pv, sk, sv, new_keys
+                        nxt, pos, keys_data = _sample_and_carry(
+                            rep, logits, running, tok, pos, keys_data, ds_e,
+                            temp, top_k, top_p)
+                        return nxt, pk, pv, sk, sv, pos, keys_data
                     return jax.jit(decode, donate_argnums=(1, 2, 3, 4))
 
-                def decode(w, pk, pv, bt, tok, pos, keys_data,
+                def decode(w, pk, pv, bt, tok, pos, running, keys_data,
                            do_sample, temp, top_k, top_p, *ad):
                     counters.inc("serving.retraces")
                     aw, aid = ad if lora else (None, None)
+                    bt_e, pos_e, ds_e, aid = _mask_idle(
+                        running, bt, pos, do_sample, aid)
                     logits, pk, pv = model.decode_paged(
-                        w, tok, pos, bt, pk, pv, kernel=mode,
+                        w, tok, pos_e, bt_e, pk, pv, kernel=mode,
                         mesh=mesh, head_axis=head_axis,
                         adapters=aw, adapter_ids=aid)
-                    nxt, new_keys = next_tokens(
-                        logits, keys_data, do_sample, temp, top_k, top_p)
-                    return nxt, pk, pv, new_keys
+                    nxt, pos, keys_data = _sample_and_carry(
+                        rep, logits, running, tok, pos, keys_data, ds_e,
+                        temp, top_k, top_p)
+                    return nxt, pk, pv, pos, keys_data
                 return jax.jit(decode, donate_argnums=(1, 2))
             key = self._prog_key("decode_paged")
             with span("serving.program_build", level=0, key=key):
@@ -983,7 +1112,7 @@ class LLMEngine(_RequestLifecycle):
                 self._drop_host_key(k)
             self.pool.release(b)
             table[i] = TRASH_BLOCK
-            self._bt[slot, i] = 0
+            self._write_slot((slot, i), bt=0)
             ent["idx"].add(i)
             counters.inc("serving.kv.tier.spilled_blocks")
             self.kv_tier_spilled += 1
@@ -1033,7 +1162,7 @@ class LLMEngine(_RequestLifecycle):
             b = self.pool.alloc()
             self._restore_block(b, bufs)
             table[i] = b
-            self._bt[slot, i] = b
+            self._write_slot((slot, i), bt=b)
             restored.append(i)
         if restored:
             jax.block_until_ready(self._pk)
@@ -1202,10 +1331,8 @@ class LLMEngine(_RequestLifecycle):
                 self.kv_prefix_misses += 1
                 counters.inc("serving.kv.prefix_misses")
             self._slot_blocks[slot] = table
-            self._bt[slot] = 0
-            self._bt[slot, :len(table)] = table
-            self._aid[slot] = aslot
-            self._running[slot] = False
+            self._write_slot(slot, bt=self._table_row(table), aid=aslot,
+                             running=False)
             req.state = "prefilling"
             req.slot = slot
             self._slots[slot] = req
@@ -1214,6 +1341,11 @@ class LLMEngine(_RequestLifecycle):
                       shared=len(shared), cached_tokens=cached)
         events.append({"type": "admitted", "request": req})
         return True
+
+    def _table_row(self, table):
+        row = np.zeros(self.max_blocks, np.int32)
+        row[:len(table)] = table
+        return row
 
     def _admit(self, events):
         now = time.monotonic()
@@ -1257,25 +1389,34 @@ class LLMEngine(_RequestLifecycle):
         with span("serving.prefill.operands"):
             ids = np.zeros((1, C), np.int32)
             ids[0, :take_n] = req.prompt[start:start + take_n]
-            # every chunk is fed the request's ORIGINAL seed key; only the
-            # final chunk's sample/key are consumed, so the key-split chain
-            # is exactly generate's one-split-after-prefill
-            key_data = np.asarray(
-                jax.random.key_data(jax.random.key(req.seed)))
+            op = self.arena.operand
+            if "key" not in st:
+                # what is constant for the request is made with its first
+                # chunk.  Every chunk is fed the request's ORIGINAL seed
+                # key; only the final chunk's sample/key are consumed, so
+                # the key-split chain is exactly generate's
+                # one-split-after-prefill.  The slot's table was written
+                # whole at admission and is not written again before the
+                # last chunk; the adapter row ([1]-shaped to match the
+                # chunk's batch) likewise
+                st["key"] = np.asarray(
+                    jax.random.key_data(jax.random.key(req.seed)))
+                st["bt"] = op(self._bt[slot].copy())
+                st["aid"] = (op(np.asarray([self._aid[slot]], np.int32))
+                             if self.adapters is not None else None)
             self._observe("serving.prefill_occupancy", take_n / C)
             tr = req.trace
             t0_tr = time.perf_counter_ns() if tr is not None else 0
             pf = self._pchunk_for(C)
-            head = (self._w, self.arena.operand(ids), np.int32(start),
-                    np.int32(take_n), self.arena.operand(self._bt[slot]))
-            tail = (key_data, np.bool_(req.do_sample),
+            head = (self._w, op(ids), np.int32(start), np.int32(take_n),
+                    st["bt"])
+            tail = (st["key"], np.bool_(req.do_sample),
                     np.float32(req.temperature), np.int32(req.top_k),
                     np.float32(req.top_p))
             if self.adapters is not None:
-                # slab pytree + this request's arena row ([1]-shaped to
-                # match the chunk's batch) as trailing operands
-                tail = tail + (self.adapters.slabs(), self.arena.operand(
-                    np.asarray([self._aid[slot]], np.int32)))
+                # slab pytree + this request's arena row as trailing
+                # operands
+                tail = tail + (self.adapters.slabs(), st["aid"])
             if self.slot_state:
                 pargs = (*head, self._pk, self._pv, self._st,
                          np.int32(slot), *tail)
@@ -1312,13 +1453,12 @@ class LLMEngine(_RequestLifecycle):
             counters.inc("serving.prefill_batches")
             # only the last chunk's sample is consumed: the one read-back
             with span("serving.prefill.wait"):
-                self._tok[slot] = int(tok)
-                self._pos[slot] = T
-                self._keys[slot] = np.asarray(new_key)
-            self._temp[slot] = req.temperature
-            self._topk[slot] = req.top_k
-            self._topp[slot] = req.top_p
-            self._dosample[slot] = req.do_sample
+                tok, new_key = int(tok), np.asarray(new_key)
+            with self._cond:
+                self._write_slot(
+                    slot, tok=tok, pos=T, keys=new_key,
+                    temp=req.temperature, topk=req.top_k, topp=req.top_p,
+                    dosample=req.do_sample, running=not req.hold)
             if req.hold:
                 # disaggregated hand-off point: the row parks instead of
                 # entering decode — _running stays False so the decode
@@ -1329,13 +1469,12 @@ class LLMEngine(_RequestLifecycle):
                 # _emit may finish the request (EOS / max_new == 1), in
                 # which case there is nothing left to migrate.
                 req.state = "held"
-                self._emit(req, int(tok), events)
+                self._emit(req, tok, events)
                 if req.state == "held":
                     events.append({"type": "prefilled", "request": req})
             else:
                 req.state = "running"
-                self._running[slot] = True
-                self._emit(req, int(tok), events)
+                self._emit(req, tok, events)
 
     def _prefill_chunks(self, events):
         """One chunk per prefilling slot per step (round-robin in slot
@@ -1368,37 +1507,19 @@ class LLMEngine(_RequestLifecycle):
         self._observe("serving.decode_occupancy",
                       len(active) / self.max_slots)
         with span("serving.decode.operands"):
-            # non-running rows (idle or mid-prefill) are tabled to the
-            # trash block at position 0: the ONE decode program runs every
-            # launch with fixed shapes, whatever subset of rows is live
-            bt_eff = np.where(self._running[:, None], self._bt,
-                              0).astype(np.int32)
-            pos_eff = np.where(self._running, self._pos, 0).astype(np.int32)
-            # a row that holds a slot without running (parked for
-            # migration, adopted and not yet resumed) keeps its request's
-            # flag; masked, so it cannot send a greedy batch's launch down
-            # the sampling tail's long branch
-            ds_eff = self._dosample & self._running
             t0 = time.perf_counter()
             tr_on = rtrace.enabled()
             t0_tr = time.perf_counter_ns() if tr_on else 0
             dec = self._pdecode()
-            op = self.arena.operand
-            tail = (op(bt_eff), op(self._tok),
-                    op(pos_eff), op(self._keys),
-                    op(ds_eff), op(self._temp),
-                    op(self._topk), op(self._topp))
-            if self.adapters is not None:
-                # non-running rows decode against the base row (id 0) —
-                # same trick as the trash-block tabling above
-                aid_eff = np.where(self._running, self._aid,
-                                   0).astype(np.int32)
-                tail = tail + (self.adapters.slabs(), op(aid_eff))
+            # on most launches no slot changed hands since the last one:
+            # every operand is then a device array that launch left, and
+            # nothing is uploaded
+            tail, uploaded = self._decode_operands()
+            sampled = bool((self._dosample & self._running).any())
             if self.slot_state:
                 # the rows' recurrent state rides next to the pools; a row
                 # that is not running keeps its own bit for bit
-                dargs = (self._w, self._pk, self._pv, self._st, *tail[:3],
-                         op(self._running), *tail[3:])
+                dargs = (self._w, self._pk, self._pv, self._st, *tail)
                 dn = (1, 2, 3)
             elif self.kv_dtype:
                 dargs = (self._w, self._pk, self._pv, self._sk, self._sv,
@@ -1413,14 +1534,19 @@ class LLMEngine(_RequestLifecycle):
         with span("serving.decode.dispatch"):
             _dt = _devicetime.note(dname)
             if self.slot_state:
-                nxt, self._pk, self._pv, self._st, new_keys = dec(*dargs)
+                (nxt, self._pk, self._pv, self._st, pos,
+                 keys) = dec(*dargs)
             elif self.kv_dtype:
-                (nxt, self._pk, self._pv, self._sk, self._sv,
-                 new_keys) = dec(*dargs)
+                (nxt, self._pk, self._pv, self._sk, self._sv, pos,
+                 keys) = dec(*dargs)
             else:
-                nxt, self._pk, self._pv, new_keys = dec(*dargs)
+                nxt, self._pk, self._pv, pos, keys = dec(*dargs)
             _devicetime.observe(_dt, nxt)
-        with span("serving.decode.wait"):
+            with self._cond:
+                # the program's own outputs are the next launch's operands
+                self._dev.update(tok=nxt, pos=pos, keys=keys)
+                self._keys_host = None
+        with span("serving.decode.wait"):    # the one read-back
             nxt = np.asarray(nxt)
         if tr_on:
             t1_tr = time.perf_counter_ns()
@@ -1428,16 +1554,16 @@ class LLMEngine(_RequestLifecycle):
                 if r.trace is not None:
                     r.trace.add_span("decode.iter", t0_tr, t1_tr,
                                      batch=len(active))
-        with span("serving.decode.wait"):    # the second read-back
-            self._keys = np.array(new_keys)  # mutable host copy
         # one token emitted per active slot this launch
         self._note_decode(len(active), time.perf_counter() - t0)
         counters.inc("serving.decode_steps")
-        counters.inc("serving.decode.sampled_steps", int(ds_eff.any()))
+        counters.inc("serving.decode.sampled_steps", int(sampled))
+        counters.inc("serving.decode.upload_steps", int(uploaded))
         counters.inc("serving.decode_tokens", len(active))
         if self.kv_dtype:
             counters.inc("serving.kv.quant.decode_tokens", len(active))
         with span("serving.decode.emit"):
+            # the mirrors of what the program carried forward itself
             for s, req in active:
                 self._tok[s] = nxt[s]
                 self._pos[s] += 1
@@ -1645,17 +1771,11 @@ class LLMEngine(_RequestLifecycle):
             req.slot = slot
             self._slots[slot] = req
             self._slot_blocks[slot] = table
-            self._bt[slot] = 0
-            self._bt[slot, :len(table)] = table
-            self._aid[slot] = aslot
-            self._running[slot] = True
-            self._tok[slot] = int(mig["tok"])
-            self._pos[slot] = pos
-            self._keys[slot] = np.asarray(mig["key"])
-            self._temp[slot] = req.temperature
-            self._topk[slot] = req.top_k
-            self._topp[slot] = req.top_p
-            self._dosample[slot] = req.do_sample
+            self._write_slot(
+                slot, bt=self._table_row(table), aid=aslot, running=True,
+                tok=int(mig["tok"]), pos=pos, keys=np.asarray(mig["key"]),
+                temp=req.temperature, topk=req.top_k, topp=req.top_p,
+                dosample=req.do_sample)
             self._outstanding += max(
                 0, req.max_new_tokens - len(req.tokens))
             self._adopt_extra(slot, req, mig)
@@ -1708,14 +1828,13 @@ class LLMEngine(_RequestLifecycle):
         table = self._slot_blocks[slot]
         self._slot_blocks[slot] = None
         st = self._prefill_state.pop(slot, None)
-        self._running[slot] = False
-        self._bt[slot] = 0
         if self.adapters is not None and req.adapter is not None \
                 and self._aid[slot]:
             # drop the request's adapter pin; the tenant stays resident
             # (warm for the next same-tenant request, LRU otherwise)
             self.adapters.release(req.adapter)
-        self._aid[slot] = 0
+        self._write_slot(slot, running=False, bt=0, aid=0, dosample=False,
+                         tok=0, pos=0)
         self._held_idle.pop(req.rid, None)
         ent = self._req_host.pop(req.rid, None)
         if ent is not None:

@@ -735,6 +735,8 @@ class SpeculativeLLMEngine(LLMEngine):
         counters.inc("serving.spec.verify_steps")
         counters.inc("serving.decode_steps")
         counters.inc("serving.decode.sampled_steps", int(ds_eff.any()))
+        # a round uploads its rows' state from the host mirrors, every time
+        counters.inc("serving.decode.upload_steps")
         emitted = int(sum(int(n_emit[s]) for s, _ in active))
         self._note_decode(emitted, time.perf_counter() - t0)
         counters.inc("serving.decode_tokens", emitted)

@@ -330,9 +330,10 @@ class TestPagedIdentity:
 class TestSamplingTailPredicate:
     def test_parked_sampling_row_keeps_greedy_batch_on_short_branch(self):
         """A sampling request that holds a slot WITHOUT running (parked
-        after prefill for migration) keeps its flag on the host, but the
-        decode launch uploads ``False`` for its row: the greedy rows
-        decoding beside it never take the sampling tail's long branch
+        after prefill for migration) keeps its flag, on the host and in
+        the launch's operand, but not its ``running`` bit, and the decode
+        program masks the one by the other: the greedy rows decoding
+        beside it never take the sampling tail's long branch
         (``serving.decode.sampled_steps`` stays 0), and their tokens are
         their own."""
         m = _model()
@@ -347,8 +348,9 @@ class TestSamplingTailPredicate:
             eng.step()
         dec, uploads = eng._pdecode(), []
 
-        def shim(*args):       # (w, pk, pv, bt, tok, pos, keys, do_sample, ..)
-            uploads.append(np.asarray(args[7]).copy())
+        def shim(*args):   # (w, pk, pv, bt, tok, pos, running, keys, do_sample, ..)
+            uploads.append((np.asarray(args[8]).copy(),
+                            np.asarray(args[6]).copy()))
             return dec(*args)
         eng._pdecode_jit = shim
         hs = [eng.add_request(p, max_new_tokens=5) for p in pg]
@@ -358,8 +360,10 @@ class TestSamplingTailPredicate:
         assert d.get("serving.decode.sampled_steps", 0) == 0
         assert "serving.decode.sampled_steps" in counters.snapshot()
         assert held.state == "held" and eng._dosample[held.slot]
-        assert uploads[0].dtype == np.bool_
-        assert not any(u.any() for u in uploads)
+        assert uploads[0][0].dtype == uploads[0][1].dtype == np.bool_
+        assert all(ds[held.slot] and not run[held.slot]
+                   for ds, run in uploads)
+        assert not any((ds & run).any() for ds, run in uploads)
         for h, p in zip(hs, pg):
             assert list(h.tokens) == _ref_generate(m, p, 5)
         held.cancel()
@@ -749,3 +753,288 @@ class TestHostTierRouting:
         assert cache.probe(seq, limit=8) == (8, 0)
         cache.clear()
         assert cache.digest() == frozenset()
+
+
+# ---------------------------------------------------------------------------
+# the per-slot decode state lives on the device between launches (PR 31)
+# ---------------------------------------------------------------------------
+_KINDS = ("plain", "kv_dtype", "slot_state")
+_HYBRID = None
+_UPLOADS, _STEPS = "serving.decode.upload_steps", "serving.decode_steps"
+
+
+def _hybrid():
+    """A one-period Olmo-hybrid model: three delta-rule layers and one of
+    full attention, so the engine's programs are the ``slot_state`` ones."""
+    global _HYBRID
+    if _HYBRID is None:
+        from paddle_tpu.models.olmo_hybrid import (OlmoHybridConfig,
+                                                   OlmoHybridForCausalLM)
+        cfg = {"vocab_size": 64, "hidden_size": 32, "intermediate_size": 64,
+               "num_hidden_layers": 4, "num_attention_heads": 2,
+               "num_key_value_heads": 2, "max_position_embeddings": 64,
+               "rms_norm_eps": 1e-6,
+               "layer_types": ["linear_attention"] * 3 + ["full_attention"],
+               "linear_num_key_heads": 2, "linear_num_value_heads": 2,
+               "linear_key_head_dim": 8, "linear_value_head_dim": 16,
+               "linear_conv_kernel_dim": 4}
+        paddle.seed(17)
+        _HYBRID = OlmoHybridForCausalLM(OlmoHybridConfig.from_hf(
+            cfg, initializer_range=0.1, dtype="float32"))
+        _HYBRID.eval()
+    return _HYBRID
+
+
+def _kind_engine(kind, **kw):
+    if kind == "slot_state":
+        return _paged(_hybrid(), **kw)
+    if kind == "kv_dtype":
+        kw["kv_dtype"] = "int8"
+    return _paged(_model(), **kw)
+
+
+def _alone(kind, prompt, **kw):
+    """The request's tokens when it has an engine of that kind to itself."""
+    eng = _kind_engine(kind)
+    h = eng.add_request(prompt, **kw)
+    _run(eng, [h])
+    return list(h.tokens)
+
+
+def _key_after(seed, splits):
+    import jax
+    key = jax.random.key(seed)
+    for _ in range(splits):
+        key = jax.random.split(key)[0]
+    return np.asarray(jax.random.key_data(key))
+
+
+def _device_is_the_host(eng):
+    """Every per-slot operand that nothing has written since the last
+    launch reads the same on the device as in its host mirror (the keys'
+    mirror is fetched from the device, so it is compared by chain in the
+    tests instead)."""
+    for name in ("bt", "tok", "pos", "running", "dosample", "temp", "topk",
+                 "topp"):
+        if name in eng._dev and name not in eng._stale:
+            assert np.array_equal(np.asarray(eng._dev[name]),
+                                  getattr(eng, "_" + name)), name
+
+
+class TestResidentDecodeState:
+    @pytest.mark.parametrize("kind", _KINDS)
+    def test_tokens_across_slot_turnover(self, kind):
+        """A row joins while others decode, a finished row's slot is
+        handed on, greedy and sampling rows with different knobs share
+        launches: every request's tokens are the ones it gets with an
+        engine to itself (for the plain engine: ``generate``'s)."""
+        rng = np.random.default_rng(51)
+        reqs = {
+            "a": dict(max_new_tokens=9),
+            "b": dict(max_new_tokens=4, do_sample=True, temperature=0.8,
+                      top_k=5, seed=11),
+            "c": dict(max_new_tokens=6, do_sample=True, top_p=0.9, seed=12),
+            "d": dict(max_new_tokens=5),
+            "e": dict(max_new_tokens=5, do_sample=True, temperature=1.3,
+                      seed=13),
+        }
+        prompts = {n: rng.integers(0, 64, size=s).tolist()
+                   for n, s in zip(reqs, (5, 7, 6, 4, 9))}
+        eng = _kind_engine(kind)
+        hs = {n: eng.add_request(prompts[n], **reqs[n]) for n in "ab"}
+        for _ in range(2):
+            eng.step()
+            _device_is_the_host(eng)
+        hs["c"] = eng.add_request(prompts["c"], **reqs["c"])   # joins late
+        while not hs["b"].is_finished:
+            eng.step()
+            _device_is_the_host(eng)
+        freed = {s for s, r in enumerate(eng._slots) if r is None}
+        hs["d"] = eng.add_request(prompts["d"], **reqs["d"])
+        hs["e"] = eng.add_request(prompts["e"], **reqs["e"])
+        eng.step()
+        assert hs["d"].slot in freed                # b's row, handed on
+        while not all(h.is_finished for h in hs.values()):
+            eng.step()
+            _device_is_the_host(eng)
+        for n, h in hs.items():
+            assert list(h.tokens) == _alone(kind, prompts[n], **reqs[n]), n
+            if kind == "plain":
+                kw = {k: v for k, v in reqs[n].items()
+                      if k != "max_new_tokens"}
+                assert list(h.tokens) == _ref_generate(
+                    _model(), prompts[n], reqs[n]["max_new_tokens"], **kw)
+        assert not eng._running.any() and not eng._bt.any()
+
+    @pytest.mark.parametrize("kind", _KINDS)
+    def test_key_chain_is_one_split_a_launch(self, kind):
+        """The chain the device carries is today's: the seed's key, one
+        split by the last prefill chunk, one by every decode launch the
+        row ran in, whatever joins beside it (a joining row makes the
+        host upload ALL rows' keys: the running row's must be current)."""
+        rng = np.random.default_rng(52)
+        eng = _kind_engine(kind)
+        h = eng.add_request(rng.integers(0, 64, size=6).tolist(),
+                            max_new_tokens=12, do_sample=True, seed=77)
+        other = None
+        while len(h.tokens) < 9:
+            if len(h.tokens) == 4 and other is None:
+                other = eng.add_request(
+                    rng.integers(0, 64, size=5).tolist(), max_new_tokens=3)
+            eng.step()
+            slot, n = h.slot, len(h.tokens)
+            assert np.array_equal(eng._keys[slot], _key_after(77, n))
+            assert eng._pos[slot] == 6 + n - 1
+            assert eng._tok[slot] == h.tokens[-1]
+            _device_is_the_host(eng)
+        assert other is not None and other.is_finished
+
+    @pytest.mark.parametrize("kind", ("plain", "kv_dtype"))
+    def test_export_after_resident_steps_is_current(self, kind):
+        """A parked row's token, position and key are what its last chunk
+        left, however many launches its neighbours ran meanwhile (its
+        ``running`` bit is off, so the program carries its row through),
+        and the engine that adopts it continues the request's own chain."""
+        rng = np.random.default_rng(53)
+        ph = rng.integers(0, 64, size=7).tolist()
+        kw = dict(max_new_tokens=6, do_sample=True, top_k=8, seed=31)
+        src, dst = _kind_engine(kind), _kind_engine(kind)
+        held = src.add_request(ph, hold_after_prefill=True, **kw)
+        beside = src.add_request(rng.integers(0, 64, size=5).tolist(),
+                                 max_new_tokens=8)
+        _run(src, [beside])
+        assert held.state == "held" and len(held.tokens) == 1
+        mig = src.export_request(held)
+        assert mig["tok"] == held.tokens[0] and mig["pos"] == len(ph)
+        assert np.array_equal(mig["key"], _key_after(31, 1))
+        busy = dst.add_request(rng.integers(0, 64, size=4).tolist(),
+                               max_new_tokens=12)
+        for _ in range(3):
+            dst.step()
+        before = counters.snapshot()
+        new, _ = dst.adopt_migration(mig, src)
+        src.finish_migrated(held)
+        dst.step()                     # the launch that sees the adoption
+        d = counters.delta(before)
+        assert (d[_STEPS], d[_UPLOADS]) == (1, 1)
+        assert len(new.tokens) == 2
+        _device_is_the_host(dst)
+        _run(dst, [new, busy])
+        assert list(new.tokens) == _alone(kind, ph, **kw)
+        if kind == "plain":
+            assert list(new.tokens) == _ref_generate(
+                _model(), ph, 6, do_sample=True, top_k=8, seed=31)
+
+    def test_spill_and_restore_reach_the_next_launch(self):
+        """The host tier trashes a parked row's table entries and pages
+        them back on export; each writes the table from outside the decode
+        program, so the launch after each uploads it."""
+        rng = np.random.default_rng(54)
+        eng = _paged(_model(), host_kv_blocks=16, spill_idle_steps=2)
+        held = eng.add_request(rng.integers(0, 64, size=11).tolist(),
+                               max_new_tokens=4, seed=3,
+                               hold_after_prefill=True)
+        beside = eng.add_request(rng.integers(0, 64, size=5).tolist(),
+                                 max_new_tokens=14)
+        while held.state != "held":
+            eng.step()
+        before = counters.snapshot()
+        row = eng._bt[held.slot].copy()
+        while held.rid not in eng._req_host:
+            eng.step()                                  # ...and it spills
+        assert not eng._bt[held.slot, :2].any() and row[:2].all()
+        eng.step()
+        _device_is_the_host(eng)
+        d = counters.delta(before)
+        assert d[_UPLOADS] == 1 and d[_STEPS] >= 2
+        mig = eng.export_request(held)                  # pages it back
+        assert "bt" in eng._stale and eng._bt[held.slot, :2].all()
+        eng.step()
+        _device_is_the_host(eng)
+        assert counters.delta(before)[_UPLOADS] == 2
+        assert list(mig["table"]) == list(eng._slot_blocks[held.slot])
+        assert not beside.is_finished
+
+
+class TestUploadStepsCounter:
+    @pytest.mark.parametrize("kind", _KINDS)
+    def test_steady_steps_upload_nothing(self, kind):
+        """K launches with no admission and no finish: ``decode_steps``
+        rises by K and ``upload_steps`` stays, and the launches run with
+        host-to-device transfers refused outright."""
+        import jax
+        rng = np.random.default_rng(55)
+        eng = _kind_engine(kind)
+        hs = [eng.add_request(rng.integers(0, 64, size=n).tolist(),
+                              max_new_tokens=12, **kw)
+              for n, kw in ((5, {}), (6, dict(do_sample=True, seed=4)))]
+        for _ in range(3):
+            eng.step()
+        counters.reset(_UPLOADS)
+        before = counters.snapshot()
+        with jax.transfer_guard_host_to_device("disallow_explicit"):
+            for _ in range(5):
+                eng.step()
+        d = counters.delta(before)
+        assert d[_STEPS] == 5 and d.get(_UPLOADS, 0) == 0
+        # registered all the same, at 0
+        assert counters.snapshot()[_UPLOADS] == 0
+        _run(eng, hs)
+        for h in hs:
+            assert len(h.tokens) == 12
+
+    def test_an_upload_step_is_refused_under_the_guard(self):
+        """The guard does bite on this backend: a launch after a write to
+        a slot's state needs its upload."""
+        import jax
+        eng = _paged(_model())
+        h = eng.add_request([1, 2, 3], max_new_tokens=8)
+        for _ in range(2):
+            eng.step()
+        eng._write_slot(h.slot, temp=h.temperature)
+        with jax.transfer_guard_host_to_device("disallow_explicit"):
+            with pytest.raises(Exception, match="host-to-device"):
+                eng.step()
+
+    def test_admission_and_finish_each_add_one(self):
+        rng = np.random.default_rng(56)
+        eng = _paged(_model())
+        long = eng.add_request(rng.integers(0, 64, size=5).tolist(),
+                               max_new_tokens=16)
+        for _ in range(3):
+            eng.step()
+        before = counters.snapshot()
+
+        def ups():
+            return counters.delta(before).get(_UPLOADS, 0)
+        eng.step()
+        assert ups() == 0
+        short = eng.add_request(rng.integers(0, 64, size=4).tolist(),
+                                max_new_tokens=3)
+        eng.step()                      # admitted, prefilled, first launch
+        assert ups() == 1 and len(short.tokens) == 2
+        eng.step()                      # its last token: the row finishes
+        assert ups() == 1 and short.is_finished
+        eng.step()                      # the launch that sees the row gone
+        assert ups() == 2
+        eng.step()
+        assert ups() == 2 and not long.is_finished
+        assert counters.delta(before)[_STEPS] == 5
+
+    def test_speculative_round_counts_its_uploads(self):
+        """The speculative engine shares the arrays and uploads them from
+        the host mirrors every round; the counter says so."""
+        from paddle_tpu.models import GPTConfig, GPTForCausalLM
+        paddle.seed(9)
+        draft = GPTForCausalLM(GPTConfig(
+            vocab_size=64, hidden_size=16, num_layers=1, num_heads=2,
+            max_seq_len=32, use_flash_attention=False))
+        draft.eval()
+        eng = _paged(_model(), draft_model=draft)
+        before = counters.snapshot()
+        h = eng.add_request([5, 6, 7, 8], max_new_tokens=6, do_sample=True,
+                            seed=8)
+        _run(eng, [h])
+        d = counters.delta(before)
+        assert d[_UPLOADS] == d[_STEPS] > 0 and len(h.tokens) == 6
+

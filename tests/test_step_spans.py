@@ -336,8 +336,7 @@ class TestEngineStep:
             order = [k[0] for k in kids if k[0].startswith("serving.decode")]
             assert order == ["serving.decode.operands",
                              "serving.decode.dispatch",
-                             "serving.decode.wait",    # the tokens
-                             "serving.decode.wait",    # the keys
+                             "serving.decode.wait",    # the tokens, alone
                              "serving.decode.emit"]
 
     def test_step_counts(self, paged_steps):
@@ -550,14 +549,51 @@ class TestRequestTraceKeepsItsMeaning:
         spans, events = traced_request
         iters = sorted((t0, t1) for n, t0, t1 in spans if n == "decode.iter")
         operands = _host(events, "serving.decode.operands")
-        waits = _host(events, "serving.decode.wait")
-        tokens, keys = waits[0::2], waits[1::2]
-        assert iters and len(iters) == len(tokens) == len(keys)
-        for (i0, i1), (o0, o1), (_, w1), (k0, _) in zip(
-                iters, operands, tokens, keys):
-            # from after the masked tables to the read-back of the tokens;
-            # the keys are read back after it, as they were
-            assert o0 < i0 < o1 and w1 <= i1 <= k0
+        tokens = _host(events, "serving.decode.wait")
+        emits = _host(events, "serving.decode.emit")
+        assert iters and len(iters) == len(tokens) == len(emits)
+        for (i0, i1), (o0, o1), (_, w1), (e0, _) in zip(
+                iters, operands, tokens, emits):
+            # from inside the operands span to the launch's one read-back,
+            # the tokens; the keys stay on the device, so what follows is
+            # the emit loop
+            assert o0 < i0 < o1 and w1 <= i1 <= e0
+
+    def test_a_second_chunk_makes_no_key_and_uploads_no_table(
+            self, monkeypatch):
+        """What is constant for a request is made with its first chunk:
+        the ``serving.prefill.operands`` span of a later chunk holds no
+        ``jax.random.key`` round trip and uploads the ids alone."""
+        eng = _engine()
+        _warm(eng)
+        made, uploads = [], []
+        real_key, real_op = jax.random.key, eng.arena.operand
+        monkeypatch.setattr(jax.random, "key",
+                            lambda *a, **k: made.append(a) or real_key(*a, **k))
+        monkeypatch.setattr(
+            eng.arena, "operand", lambda x: uploads.append(
+                (time.perf_counter_ns(), np.shape(x))) or real_op(x))
+        h = eng.add_request(np.arange(1, 14, dtype=np.int32),   # two chunks
+                            max_new_tokens=2, seed=5)
+        chunks = counters.get("serving.kv.prefill_chunks")
+        eng.step()
+        assert counters.get("serving.kv.prefill_chunks") == chunks + 1
+        assert made == [(5,)] and len(uploads) == 2     # ids, table row
+        del made[:], uploads[:]
+        host_tracer.start()
+        try:
+            eng.step()                 # the last chunk and the first launch
+        finally:
+            events = host_tracer.stop()
+        assert counters.get("serving.kv.prefill_chunks") == chunks + 2
+        assert made == [] and h.tokens
+        (o0, o1), = _host(events, "serving.prefill.operands")
+        (ids,) = [shape for t, shape in uploads if o0 <= t <= o1]
+        assert len(ids) == 2 and ids[0] == 1
+        # every other upload of the step is the decode launch's
+        (d0, d1), = _host(events, "serving.decode.operands")
+        assert all(d0 <= t <= d1 for t, _ in uploads[1:])
+        _drain(eng, [h])
 
     def test_the_read_back_happens_after_the_chunk_is_counted(
             self, monkeypatch):

@@ -203,7 +203,7 @@ class TestFleetTracing:
         names = _names(ctx)
         assert "replica_died" in names
         assert "redispatch" in names
-        assert names.count("prefill") == 2          # original + replay
+        assert names.count("prefill.chunk") == 2    # original + replay
         assert ctx.keep_reason == "tail:retried"
         assert rtrace.get_trace(tid)["rid"] == h.rid
 
