@@ -355,7 +355,7 @@ class OlmoHybridForCausalLM(Layer):
 
     # -- serving entry points (paddle_tpu.serving.LLMEngine, paged) ----------
     def prefill_paged(self, w, ids, start, length, bt, pool_k, pool_v,
-                      state, slot):
+                      state, slot, kernel=None):
         """One chunked-prefill step: ``ids [1, C]`` holds ``length`` tokens
         of one request at positions ``[start, start + length)``; ``bt`` is
         its block table, ``pool_k``/``pool_v`` the stacked pools of the
@@ -365,7 +365,9 @@ class OlmoHybridForCausalLM(Layer):
         0`` (a slot's last owner leaves nothing behind), advanced over the
         ``length`` live positions only, and written back.  Returns
         ``(pool_k, pool_v, state, logits [1, V])`` with the logits read at
-        the chunk's last live token."""
+        the chunk's last live token.  ``kernel`` is the engine's choice
+        for the cache's kernels, as :meth:`decode_paged` takes it; this
+        family's prefill has one form and ignores it."""
         c = self.config
         B, C = ids.shape
         nh = c.num_heads

@@ -38,6 +38,12 @@ Well-known names (see README "Observability" for the full table):
   serving.decode.upload_steps (decode launches that uploaded at least
       one per-slot operand: a slot changed hands since the launch
       before; the others took every operand from the device)
+  serving.moe.assignments / serving.moe.tokens ((token, held expert)
+      pairs computed / tokens routed by an expert model's layers; kept on
+      the device by the serving programs, fetched by
+      LLMEngine.step_state() and published by the model's moe_load()
+      alone) / serving.moe.load_max_over_mean
+      (gauge: busiest held expert over the mean, as last read)
   serving.retraces (serving program compiles; 0 in steady state)
   serving.queue_wait_ns
   serving.deadline_expired (queued past-deadline, evicted pre-prefill)

@@ -22,7 +22,8 @@ from .arena import (DEFAULT_SHARD_RULES, KV_POOL_SPEC,  # noqa: F401
                     StateArena)
 from .autoscale import FleetAutoscaler  # noqa: F401
 from .engine import (EngineBackpressure, EngineClosed,  # noqa: F401
-                     RecurrentStateUnsupported, Request, bucket_length)
+                     LatentCacheUnsupported, RecurrentStateUnsupported,
+                     Request, bucket_length)
 from .fleet import FleetRequest, Replica, ServingFleet  # noqa: F401
 from .kvcache import (BlockPool, BlockPoolExhausted,  # noqa: F401
                       PrefixCache, blocks_for_tokens)
@@ -33,7 +34,7 @@ from .speculative import SpeculativeLLMEngine  # noqa: F401
 
 __all__ = ["LLMEngine", "SpeculativeLLMEngine", "Request",
            "EngineBackpressure", "EngineClosed", "RecurrentStateUnsupported",
-           "bucket_length",
+           "LatentCacheUnsupported", "bucket_length",
            "filter_logits", "sample_tokens", "residual_sample",
            "ServingFleet", "FleetRequest", "Replica", "FleetAutoscaler",
            "Router", "RetryAfter", "BlockPool", "BlockPoolExhausted",

@@ -7,7 +7,8 @@ token per step regardless of arrival time, and evicted on EOS /
 ``max_new_tokens`` / deadline / cancellation with the row immediately
 rehandable.  This module holds that half and nothing of the device: the
 ``Request`` handle, the refusals (``EngineBackpressure``,
-``EngineClosed``, ``RecurrentStateUnsupported``), and
+``EngineClosed``, ``RecurrentStateUnsupported``,
+``LatentCacheUnsupported``), and
 ``_RequestLifecycle`` — the bounded queue and ``add_request``, the finish
 compare-and-set, the sweep of cancelled and late requests, token
 emission with its TTFT / ITL histograms, ``generate`` / ``drain`` and
@@ -76,6 +77,17 @@ class RecurrentStateUnsupported(RuntimeError):
     ``draft_model=``, and by ``export_request`` / ``adopt_migration``
     (the prefix cache resolves to off instead: reuse is an optimisation,
     not a request)."""
+
+
+class LatentCacheUnsupported(RuntimeError):
+    """Refused for a model whose cache is one latent row per token and
+    layer with no head axis (``cache_spec()["kv_row"]``): the feature
+    stores, shards, copies or rolls back K/V by head, and would serve a
+    latent pool silently wrong.  Raised at construction for ``kv_dtype=``,
+    ``host_kv_blocks=``, ``adapter_slots=``, ``mesh=`` and
+    ``draft_model=``, and by ``export_request`` / ``adopt_migration``
+    (the prefix cache resolves to off instead: its copy-on-write clone
+    copies blocks by head)."""
 
 
 class Request:

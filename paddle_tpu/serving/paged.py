@@ -72,6 +72,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec
 
+from ..kernels import mla_attention as _mla
 from ..kernels import paged_attention as _pa
 from ..profiler import counters
 from ..profiler import devicetime as _devicetime
@@ -81,8 +82,8 @@ from ..profiler import trace as rtrace
 from ..profiler.host_tracer import span
 from .arena import StateArena
 from .engine import (EngineBackpressure, EngineClosed,
-                     RecurrentStateUnsupported, Request, _RequestLifecycle,
-                     bucket_length)
+                     LatentCacheUnsupported, RecurrentStateUnsupported,
+                     Request, _RequestLifecycle, bucket_length)
 from .kvcache import (TRASH_BLOCK, BlockPool, BlockPoolExhausted,
                       HostKVTier, HostTierLost, PrefixCache,
                       blocks_for_tokens)
@@ -152,7 +153,8 @@ def _sample_and_carry(mesh, logits, running, tok, pos, keys_data, do_sample,
 
 class LLMEngine(_RequestLifecycle):
     """Continuous-batching engine over one causal LM (``GPTForCausalLM``,
-    ``OlmoHybridForCausalLM``: anything with ``cache_spec()``,
+    ``OlmoHybridForCausalLM``, ``DeepseekV2ForCausalLM``: anything with
+    ``cache_spec()``,
     ``decode_state()``, ``prefill_paged`` and ``decode_paged``).
 
     ``add_request()`` enqueues (bounded queue, optional blocking
@@ -213,23 +215,34 @@ class LLMEngine(_RequestLifecycle):
             raise ValueError(f"weight_dtype must be None or 'int8', "
                              f"got {weight_dtype!r}")
         c = model.config
-        # what the model caches: paged K/V for ``kv_layers`` layers, and
-        # one row per slot of each ``slot_state`` array (recurrent layers)
+        # what the model caches: paged K/V for ``kv_layers`` layers (per
+        # head, or with ``kv_row`` one latent row per token and no head
+        # axis), one row per slot of each ``slot_state`` array (recurrent
+        # layers), and the ``step_state`` arrays that every program takes
+        # and hands on (an expert model's load counts)
         cache = model.cache_spec()
         self.slot_state = dict(cache["slot_state"])
-        if self.slot_state:
+        self._step_spec = dict(cache.get("step_state", {}))
+        self.kv_row = int(cache.get("kv_row", 0))
+        if self.slot_state or self.kv_row:
             asked = {"kv_dtype=": kv_dtype is not None,
                      "host_kv_blocks=": int(host_kv_blocks or 0) > 0,
                      "adapter_slots=": int(adapter_slots or 0) > 0,
                      "mesh=": mesh is not None}
             if any(asked.values()):
-                raise RecurrentStateUnsupported(
-                    f"{type(model).__name__} keeps recurrent state per "
-                    "request, which "
+                refusal, what = (
+                    (RecurrentStateUnsupported,
+                     "keeps recurrent state per request") if self.slot_state
+                    else (LatentCacheUnsupported,
+                          "caches one latent row per token, with no head "
+                          "axis"))
+                raise refusal(
+                    f"{type(model).__name__} {what}, which "
                     + ", ".join(k for k, v in asked.items() if v)
                     + " cannot carry yet")
             # a prefix hit adopts K/V blocks and would skip the tokens
-            # that built the recurrent state
+            # that built the recurrent state; its copy-on-write clone
+            # copies a block by head, which a latent row has not
             prefix_cache = False
         S = int(max_seq_len or c.max_seq_len)
         if not c.use_rope and S > c.max_seq_len:
@@ -286,28 +299,47 @@ class LLMEngine(_RequestLifecycle):
         adt = _pa.KV_DTYPES[self.kv_dtype] if self.kv_dtype else dt
         from .arena import KV_POOL_SPEC
         L = self.kv_layers
-        # one chip's pool stores whole (8, 128) tiles of heads where that
-        # lets the block-table walk run (30 heads of 128 as 32); a pool
-        # whose head axis shards over a mesh keeps the model's own count
-        nhp = nh if self.arena.mesh is not None else _pa.pool_heads(nh, hd)
-        self.arena.declare(
-            "pool_k", jnp.zeros((L, self.n_blocks, bs, nhp, hd), adt),
-            spec=KV_POOL_SPEC)
-        self.arena.declare(
-            "pool_v", jnp.zeros((L, self.n_blocks, bs, nhp, hd), adt),
-            spec=KV_POOL_SPEC)
-        self._block_bytes = 2 * L * bs * nhp * hd * jnp.dtype(adt).itemsize
+        if self.kv_row:
+            # a latent cache: ONE pool of rows stored as whole lane tiles
+            # (576 values as 640), replicated (nothing to split); the
+            # programs take and hand on a second pool that is None
+            nhp, hd = 1, _mla.pool_row(self.kv_row)
+            self.arena.declare(
+                "pool_k", jnp.zeros((L, self.n_blocks, bs, hd), adt))
+            self.arena.declare("pool_v", None)
+            self._block_bytes = L * bs * hd * jnp.dtype(adt).itemsize
+        else:
+            # one chip's pool stores whole (8, 128) tiles of heads where
+            # that lets the block-table walk run (30 heads of 128 as 32);
+            # a pool whose head axis shards over a mesh keeps the model's
+            # own count
+            nhp = (nh if self.arena.mesh is not None
+                   else _pa.pool_heads(nh, hd))
+            self.arena.declare(
+                "pool_k", jnp.zeros((L, self.n_blocks, bs, nhp, hd), adt),
+                spec=KV_POOL_SPEC)
+            self.arena.declare(
+                "pool_v", jnp.zeros((L, self.n_blocks, bs, nhp, hd), adt),
+                spec=KV_POOL_SPEC)
+            self._block_bytes = (2 * L * bs * nhp * hd
+                                 * jnp.dtype(adt).itemsize)
         # recurrent layers: one row per slot of each array the model
         # names, next to the pools and donated through the same programs
-        self._state_names = tuple(sorted(self.slot_state))
-        for name in self._state_names:
+        slot_names = tuple(sorted(self.slot_state))
+        for name in slot_names:
             lead, per_slot, sdt = self.slot_state[name]
             self.arena.declare(
                 "state." + name,
                 jnp.zeros(tuple(lead) + (B,) + tuple(per_slot), sdt))
         # fixed at construction, whatever the requests' lengths
         self._state_bytes = self.arena.device_bytes(
-            *("state." + n for n in self._state_names))
+            *("state." + n for n in slot_names))
+        # what the programs carry forward beside the pools: the rows'
+        # recurrent state and the model's own running counts
+        for name, (shape, sdt) in self._step_spec.items():
+            self.arena.declare("state." + name,
+                               jnp.zeros(tuple(shape), sdt))
+        self._state_names = slot_names + tuple(sorted(self._step_spec))
         if self.kv_dtype:
             # per-token fp32 scales at the same (layer, block, position)
             # address as the quantized tiles (donated alongside them);
@@ -333,7 +365,9 @@ class LLMEngine(_RequestLifecycle):
         # holds, and baked into the program-cache key.  Pools left
         # replicated on a mesh (indivisible heads) keep the twin: GSPMD
         # partitions it, and cannot partition a Mosaic call
-        if self.arena.kv_head_axis:
+        if self.kv_row:
+            self.kv_kernel = _mla.kernel_mode(c.num_heads, hd)
+        elif self.arena.kv_head_axis:
             self.kv_kernel = _pa.kernel_mode(
                 nh // self.arena.mesh.shape["mp"], hd)
         elif self.arena.multi_device:
@@ -658,6 +692,7 @@ class LLMEngine(_RequestLifecycle):
         fn = self._pchunk_jits.get(bucket)
         if fn is None:
             model = self.model
+            mode = self.kv_kernel
 
             def build():
                 # adapter engines append the slab pytree + per-row ids as
@@ -665,12 +700,13 @@ class LLMEngine(_RequestLifecycle):
                 # them); donation indices are untouched
                 lora = self.adapters is not None
 
-                if self.slot_state:
+                if self._state_names:
                     def pchunk(w, ids, start, length, bt, pk, pv, st, slot,
                                key_data, do_sample, temp, top_k, top_p):
                         counters.inc("serving.retraces")  # trace-time only
                         pk, pv, st, logits = model.prefill_paged(
-                            w, ids, start, length, bt, pk, pv, st, slot)
+                            w, ids, start, length, bt, pk, pv, st, slot,
+                            kernel=mode)
                         tok, new_key = LLMEngine._first_token(
                             logits, key_data, do_sample, temp, top_k, top_p)
                         return pk, pv, st, tok, new_key
@@ -735,7 +771,7 @@ class LLMEngine(_RequestLifecycle):
                 # it itself, and returns, after the tokens and the pools,
                 # the positions and keys of the next launch; the tokens it
                 # returns are the next launch's too
-                if self.slot_state:
+                if self._state_names:
                     def decode(w, pk, pv, st, bt, tok, pos, running,
                                keys_data, do_sample, temp, top_k, top_p):
                         counters.inc("serving.retraces")
@@ -1417,7 +1453,7 @@ class LLMEngine(_RequestLifecycle):
                 # slab pytree + this request's arena row as trailing
                 # operands
                 tail = tail + (self.adapters.slabs(), st["aid"])
-            if self.slot_state:
+            if self._state_names:
                 pargs = (*head, self._pk, self._pv, self._st,
                          np.int32(slot), *tail)
                 dn = (5, 6, 7)
@@ -1433,7 +1469,7 @@ class LLMEngine(_RequestLifecycle):
             self._maybe_audit(pname, pf, *pargs, donate_argnums=dn)
         with span("serving.prefill.dispatch"):
             _dt = _devicetime.note(pname)
-            if self.slot_state:
+            if self._state_names:
                 self._pk, self._pv, self._st, tok, new_key = pf(*pargs)
             elif self.kv_dtype:
                 (self._pk, self._pv, self._sk, self._sv, tok,
@@ -1516,7 +1552,7 @@ class LLMEngine(_RequestLifecycle):
             # nothing is uploaded
             tail, uploaded = self._decode_operands()
             sampled = bool((self._dosample & self._running).any())
-            if self.slot_state:
+            if self._state_names:
                 # the rows' recurrent state rides next to the pools; a row
                 # that is not running keeps its own bit for bit
                 dargs = (self._w, self._pk, self._pv, self._st, *tail)
@@ -1533,7 +1569,7 @@ class LLMEngine(_RequestLifecycle):
             self._maybe_audit(dname, dec, *dargs, donate_argnums=dn)
         with span("serving.decode.dispatch"):
             _dt = _devicetime.note(dname)
-            if self.slot_state:
+            if self._state_names:
                 (nxt, self._pk, self._pv, self._st, pos,
                  keys) = dec(*dargs)
             elif self.kv_dtype:
@@ -1800,7 +1836,21 @@ class LLMEngine(_RequestLifecycle):
         if self.slot_state:
             raise RecurrentStateUnsupported(
                 f"{what} moves K/V blocks and would leave the request's "
-                f"recurrent state ({', '.join(self._state_names)}) behind")
+                f"recurrent state ({', '.join(sorted(self.slot_state))}) "
+                "behind")
+        if self.kv_row:
+            raise LatentCacheUnsupported(
+                f"{what} copies K/V blocks by head, which a latent row "
+                "has not")
+
+    def step_state(self):
+        """The ``step_state`` arrays the programs carry on the device
+        (``cache_spec()["step_state"]``: an expert model's load counts),
+        fetched to the host: ``{name: array}``, empty for a model that
+        names none.  The one place they are read (a decode launch keeps
+        its one read-back); to be called between steps."""
+        return {name: np.asarray(self.arena.get("state." + name))
+                for name in self._step_spec}
 
     def _adopt_extra(self, slot, req, mig):
         """Subclass hook: rebuild engine-local state the migration

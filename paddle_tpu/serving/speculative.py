@@ -68,7 +68,8 @@ from ..profiler import devicetime as _devicetime
 from ..profiler import flight
 from ..profiler import trace as rtrace
 from ..profiler.host_tracer import span
-from .engine import RecurrentStateUnsupported, bucket_length
+from .engine import (LatentCacheUnsupported, RecurrentStateUnsupported,
+                     bucket_length)
 from .kvcache import blocks_for_tokens
 from .paged import LLMEngine, _model_programs
 from .sampling import filter_logits, next_tokens, residual_sample
@@ -177,6 +178,11 @@ class SpeculativeLLMEngine(LLMEngine):
                 "draft_model= with a target or a draft that has recurrent "
                 "layers: verification rolls K/V back by position, and a "
                 "recurrent state has none")
+        if any(m.cache_spec().get("kv_row") for m in (model, draft)):
+            raise LatentCacheUnsupported(
+                "draft_model= with a target or a draft that caches latent "
+                "rows: the verify and roll-back programs address K/V by "
+                "head")
         self.draft_model = draft
         self.spec_k = k
         super().__init__(model, *args, **kw)

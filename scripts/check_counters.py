@@ -1709,6 +1709,61 @@ def run():
             violations[f"recurrent:identity@{h.rid}"] = (
                 h.tokens, lg.argmax(-1).tolist())
 
+    # ---- latent-cache / expert gate: carried counts, no retrace ----------
+    # A model with latent attention and routed experts (models/
+    # deepseek_v2.py) caches one row per token with no head axis and has
+    # its programs carry the expert layers' load counts on the device.
+    # The measure window must trace nothing and register no
+    # ``serving.moe.*`` name (a decode launch keeps its one read-back);
+    # ``model.moe_load(engine.step_state())`` then publishes exactly the
+    # window's tokens, every (token, layer) pair's ``num_experts_per_tok``
+    # choices with all the experts held, and the served tokens are the
+    # model's own forward pass's.  No earlier engine may have registered
+    # those names.
+    from paddle_tpu.models.deepseek_v2 import (DeepseekV2Config,
+                                               DeepseekV2ForCausalLM)
+    if any(k.startswith("serving.moe.") for k in counters.snapshot()):
+        violations["moe:registered_without_experts"] = (True, False)
+    dmodel = DeepseekV2ForCausalLM(DeepseekV2Config(
+        vocab_size=64, hidden_size=32, intermediate_size=64,
+        moe_intermediate_size=16, num_layers=3, num_heads=2, q_lora_rank=16,
+        kv_lora_rank=16, qk_nope_head_dim=8, qk_rope_head_dim=4,
+        v_head_dim=8, n_shared_experts=1, n_routed_experts=8, n_group=4,
+        topk_group=2, num_experts_per_tok=2, max_seq_len=64))
+    dmodel.eval()
+    deng = LLMEngine(dmodel, max_slots=1, max_seq_len=32, min_bucket=4,
+                     block_size=4, prefill_chunk=8)
+    pserve(deng, SERVE_LENS_WARM)
+    dmodel.moe_load(deng.step_state())
+    dbefore = counters.snapshot()
+    dhs = pserve(deng, SERVE_LENS_MEASURE)
+    dsteady_moe = counters.delta(dbefore)
+    for k in ("serving.retraces", "jit.traces", "serving.moe.tokens",
+              "serving.moe.assignments"):
+        if dsteady_moe.get(k, 0):
+            violations[f"moe:{k}"] = (dsteady_moe.get(k, 0), 0)
+    dload = dmodel.moe_load(deng.step_state())
+    dmoved = counters.delta(dbefore)
+    dtokens = sum(len(h.prompt) + len(h.tokens) - 1 for h in dhs)
+    want_moe = {"serving.moe.tokens": dtokens,
+                "serving.moe.assignments": dtokens * 2 * 2}
+    for k, want in want_moe.items():
+        if dmoved.get(k, 0) != want:
+            violations[f"moe:published:{k}"] = (dmoved.get(k, 0), want)
+    dst = deng.stats()
+    if not (dst["prefix_cache"] is False and deng._pv is None
+            and dload["load_max_over_mean"] >= 1.0):
+        violations["moe:stats"] = (
+            (dst["prefix_cache"], dload["load_max_over_mean"]),
+            "(False, >= 1)")
+    for h in dhs:
+        ids = np.concatenate([h.prompt, np.asarray(h.tokens[:-1], np.int32)])
+        lg = np.asarray(dmodel.forward_logits(
+            dmodel.decode_state(), ids[None]))[0][len(h.prompt) - 1:]
+        if lg.argmax(-1).tolist() != h.tokens:
+            violations[f"moe:identity@{h.rid}"] = (
+                h.tokens, lg.argmax(-1).tolist())
+
     result = {"metric": "steady_state_counter_violations",
               "value": len(violations),
               "unit": f"violations/{MEASURE} steps "

@@ -231,3 +231,88 @@ def test_hybrid_decode_step_updates_both_caches_in_place(chip):
     state_bytes = 12 * B * (H * dk * dv * 4 + 3 * 11520 * 2)
     assert mem.alias_size_in_bytes >= (
         2 * 4 * n_blocks * bs * nhp * hd * 2 + state_bytes), mem
+
+
+# ---------------------------------------------------------------------------
+# DeepSeek-V2 at the longdoc cell's shape: 32 rows x 2,080 blocks of 16
+# tokens, 16,385 blocks, 5 layers stacked, 128 heads over rows of 640
+# ---------------------------------------------------------------------------
+def _deepseek(chip):
+    import json
+    from paddle_tpu.models import deepseek_v2 as ds
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "deepseek-v2.json")) as f:
+        hf = json.load(f)
+    real = ds.DeepseekV2ForCausalLM.__new__(ds.DeepseekV2ForCausalLM)
+    real.__dict__["config"] = c = ds.DeepseekV2Config.from_hf(
+        hf, experts_held=(hf["experts_held_first"], hf["n_routed_experts"]),
+        n_routed_experts=hf["published"]["n_routed_experts"],
+        dtype="bfloat16")
+    w = {n: _sds(chip, shape, dt)
+         for n, (shape, _, dt) in ds.param_shapes(c).items()}
+    assert w["expert_gu_w"].shape == (4, 40, 5120, 3072)
+    assert w["router_w"].shape == (4, 5120, 160)
+    st = {n: _sds(chip, tuple(shape), dt)
+          for n, (shape, dt) in real.cache_spec()["step_state"].items()}
+    return real, w, st
+
+
+def test_mla_decode_compiles(chip):
+    from paddle_tpu.kernels import mla_attention as mla
+    B, max_blocks, n_blocks, row = 32, 2080, 16385, mla.pool_row(576)
+    _compile(lambda q, pool, layer, bt, pos: mla.mla_decode_attn(
+        q, pool, layer, bt, pos, 512),
+        _sds(chip, (B, 128, row), jnp.bfloat16),
+        _sds(chip, (5, n_blocks, 16, row), jnp.bfloat16),
+        _sds(chip, (), jnp.int32), _sds(chip, (B, max_blocks), jnp.int32),
+        _sds(chip, (B,), jnp.int32), kernels=["mla_decode_attn"])
+
+
+def test_latent_decode_step_updates_the_pool_in_place(chip):
+    """``DeepseekV2ForCausalLM.decode_paged`` at the longdoc cell's shape:
+    the latent walk is in it, the one pool and the expert counts alias
+    through, no stacked weight is copied or sliced out for the scan over the
+    expert layers (one layer's 40 experts are 1.9 GB), the grouped products
+    are the compiler's own ``ragged-dot`` calls, nothing the size of the
+    pool is kept as a temporary, and the only sorts are the sampling
+    tail's two."""
+    real, w, st = _deepseek(chip)
+    B, max_blocks, n_blocks = 32, 2080, 16385
+    pool = _sds(chip, (5, n_blocks, 16, 640), jnp.bfloat16)
+
+    def decode(w, pool, st, bt, tok, pos, running):
+        return real.decode_paged(w, tok, pos, bt, pool, None, st, running,
+                                 kernel="pallas")
+
+    compiled = jax.jit(decode, donate_argnums=(1, 2)).lower(
+        w, pool, st, _sds(chip, (B, max_blocks), jnp.int32),
+        _sds(chip, (B,), jnp.int32), _sds(chip, (B,), jnp.int32),
+        _sds(chip, (B,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert re.search(r"%mla_decode_attn(\.\d+)? = [^\n]*custom-call\(", text)
+    assert len(re.findall(r"%ragged-dot[\w.-]* = [^\n]*custom-call\(",
+                          text)) >= 2
+    assert not re.search(r"bf16\[(4|5|40|160),\d{4,}[\d,]*\]\S* copy\(", text)
+    assert not re.search(r" sort\(", text)      # the model's part has none
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 64 * 2 ** 20, mem
+    assert mem.alias_size_in_bytes >= 5 * n_blocks * 16 * 640 * 2, mem
+
+
+@pytest.mark.parametrize("C", [1024, 8])
+def test_mla_prefill_fold_compiles(chip, C):
+    """A chunk's fold of one 2,048-key tile at the published widths (128
+    heads, keys of 192, values of 128), at the widest bucket and the
+    narrowest."""
+    from paddle_tpu.kernels import mla_attention as mla
+    H, K = 128, 2048
+    state = (_sds(chip, (H, C, 1), jnp.float32),
+             _sds(chip, (H, C, 1), jnp.float32),
+             _sds(chip, (H, C, 128), jnp.float32))
+    _compile(lambda q, k, v, q0, k0, *st: mla.mla_prefill_fold(
+        q, k, v, q0, k0, st),
+        _sds(chip, (H, C, 192), jnp.bfloat16),
+        _sds(chip, (H, K, 192), jnp.bfloat16),
+        _sds(chip, (H, K, 128), jnp.bfloat16), _sds(chip, (), jnp.int32),
+        _sds(chip, (), jnp.int32), *state, kernels=["mla_prefill_attn"])
