@@ -110,7 +110,7 @@ def _token_by_token(q, k, v, g, beta, s, upto=None):
     return jnp.moveaxis(o, 0, 1), s
 
 
-@pytest.mark.parametrize("T", [16, 64, 192])
+@pytest.mark.parametrize("T", [16, 64, 192, 512, 8])
 def test_chunked_rule_is_the_recurrence(T):
     q, k, v, g, beta, s0 = _rule_inputs(T)
     o, s = gd.gdn_chunk(q, k, v, g, beta, s0)
@@ -119,8 +119,8 @@ def test_chunked_rule_is_the_recurrence(T):
     np.testing.assert_allclose(s, s_ref, atol=2e-4)
 
 
-def test_padded_bucket_leaves_the_state_where_the_tokens_end():
-    T, live = 128, 77
+@pytest.mark.parametrize("T,live", [(128, 77), (512, 300), (8, 5)])
+def test_padded_bucket_leaves_the_state_where_the_tokens_end(T, live):
     q, k, v, g, beta, s0 = _rule_inputs(T, seed=1)
     mask = (jnp.arange(T) < live)[None, :, None]
     o, s = gd.gdn_chunk(q, k, v, jnp.where(mask, g, 0.0),
@@ -128,6 +128,57 @@ def test_padded_bucket_leaves_the_state_where_the_tokens_end():
     o_ref, s_ref = _token_by_token(q, k, v, g, beta, s0, upto=live)
     np.testing.assert_allclose(o[:, :live], o_ref, atol=2e-4)
     np.testing.assert_allclose(s, s_ref, atol=2e-4)
+
+
+def _hard_systems(c, shared, N=24, K=96, R=288):
+    """``N`` systems ``(I + A) X = rhs`` of the chunked rule at its hard
+    corner: ``beta`` in 1.8-2.0 and ``g`` near 0, so nothing damps the
+    entries under the diagonal, and keys that share ``shared`` of their
+    norm, so those entries are all of one sign and near ``2 shared^2``."""
+    r = np.random.default_rng(c + int(10 * shared))
+    unit = lambda x: x / np.linalg.norm(x, axis=-1, keepdims=True)
+    k = unit(shared * unit(r.standard_normal((N, 1, K)))
+             + (1 - shared ** 2) ** 0.5 * unit(r.standard_normal((N, c, K))))
+    beta = r.uniform(1.8, 2.0, (N, c))
+    G = np.cumsum(-r.uniform(0.0, 1e-3, (N, c)), -1)
+    decay = np.exp(np.tril(G[:, :, None] - G[:, None, :]))
+    A = np.tril(beta[..., None] * decay * (k @ k.transpose(0, 2, 1)), -1)
+    return A.astype(np.float32), r.standard_normal((N, c, R)).astype(
+        np.float32)
+
+
+@pytest.mark.parametrize("shared", [0.0, 0.5, 0.9])
+@pytest.mark.parametrize("c", [8, 16, 32, 37, 64])
+def test_blocked_solve_is_forward_substitution(c, shared):
+    """The solve alone against row-by-row substitution in float64; 37 rows
+    are not whole blocks (the eager forward pass of a short sequence)."""
+    A, rhs = _hard_systems(c, shared)
+    want = rhs.astype(np.float64)
+    for t in range(1, c):
+        want[:, t] -= np.einsum("ns,nsr->nr", A[:, t, :t].astype(np.float64),
+                                want[:, :t])
+    got = np.asarray(gd._solve_unit_lower(jnp.asarray(A), jnp.asarray(rhs)))
+    assert got.shape == rhs.shape and got.dtype == np.float32
+    assert np.abs(got - want).max() < 1e-5 * np.abs(want).max()
+
+
+def test_no_triangular_solve_is_left(model):
+    """The chunk's system goes through products on every backend: neither
+    the rule at the docqa cell's chunk nor the model's chunk program lowers
+    to a ``triangular_solve``, and the file no longer reaches for one."""
+    f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)
+    text = jax.jit(gd.gdn_chunk).lower(
+        f32(1, 512, 30, 96), f32(1, 512, 30, 96), f32(1, 512, 30, 192),
+        f32(1, 512, 30), f32(1, 512, 30), f32(1, 30, 96, 192)).as_text()
+    assert "dot_general" in text and "triangular" not in text.lower()
+    pk, pv, st = _pools(model, max_blocks=4, B=2)
+    text = jax.jit(model.prefill_paged).lower(
+        model.decode_state(), np.zeros((1, 64), np.int32), np.int32(0),
+        np.int32(64), np.arange(1, 5, dtype=np.int32), pk, pv, st,
+        np.int32(0)).as_text()
+    assert "dot_general" in text and "triangular" not in text.lower()
+    with open(gd.__file__) as f:
+        assert "jax.scipy" not in f.read()
 
 
 def test_conv_carries_its_tail_across_chunks():
@@ -155,21 +206,28 @@ def test_forward_is_the_reference(model, cfg, ids):
 # ---------------------------------------------------------------------------
 # chunked prefill, then decode, through the paged cache: on logits
 # ---------------------------------------------------------------------------
+def _pools(model, max_blocks, B, bs=16, state_dtype="float32",
+           heads_in_pool=None):
+    """Empty K/V pools of ``max_blocks + 1`` blocks and the per-slot state
+    of ``B`` rows, the state filled with garbage (a last owner's)."""
+    spec = model.cache_spec()
+    nh, hd = spec["kv_heads"], spec["head_dim"]
+    nhp = heads_in_pool or pa.pool_heads(nh, hd)
+    pk = jnp.zeros((spec["kv_layers"], max_blocks + 1, bs, nhp, hd),
+                   jnp.float32)
+    st = {name: jnp.full(tuple(lead) + (B,) + tuple(per), 7.0,
+                         state_dtype if name == "gdn_state" else dt)
+          for name, (lead, per, dt) in spec["slot_state"].items()}
+    return pk, pk, st
+
+
 def _serve_logits(model, ids, T, n, chunk=64, B=4, slot=2, bs=16,
                   state_dtype="float32", heads_in_pool=None, kernel=None):
     """Logits at positions ``T - 1 .. T + n - 2``: ``ids[:T]`` prefilled in
     chunks into slot ``slot``, then ``ids[T:]`` decoded one by one.  The
     slot's state starts as garbage (a last owner's), the other rows' too."""
-    spec = model.cache_spec()
-    nh, hd = spec["kv_heads"], spec["head_dim"]
-    nhp = heads_in_pool or pa.pool_heads(nh, hd)
     max_blocks = -(-(T + n) // bs)
-    pk = jnp.zeros((spec["kv_layers"], max_blocks + 1, bs, nhp, hd),
-                   jnp.float32)
-    pv = pk
-    st = {name: jnp.full(tuple(lead) + (B,) + tuple(per), 7.0,
-                         state_dtype if name == "gdn_state" else dt)
-          for name, (lead, per, dt) in spec["slot_state"].items()}
+    pk, pv, st = _pools(model, max_blocks, B, bs, state_dtype, heads_in_pool)
     before = jax.tree_util.tree_map(np.asarray, st)
     w = model.decode_state()
     row = np.arange(1, max_blocks + 1, dtype=np.int32)

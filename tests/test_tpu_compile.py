@@ -233,6 +233,21 @@ def test_hybrid_decode_step_updates_both_caches_in_place(chip):
         2 * 4 * n_blocks * bs * nhp * hd * 2 + state_bytes), mem
 
 
+def test_gdn_chunk_compiles_at_the_docqa_chunk(chip):
+    """The chunked delta rule at the docqa cell's chunk (512 positions, 30
+    heads of ``d_k`` 96 / ``d_v`` 192, float32: neither width is whole
+    lanes): plain XLA, so what could go wrong is a refusal of the blocked
+    solve's shapes or a library ``triangular_solve`` coming back."""
+    from paddle_tpu.kernels import gated_delta as gd
+    B, T, H, dk, dv = 1, 512, 30, 96, 192
+    f32 = lambda *shape: _sds(chip, shape, jnp.float32)      # noqa: E731
+    compiled = jax.jit(gd.gdn_chunk).lower(
+        f32(B, T, H, dk), f32(B, T, H, dk), f32(B, T, H, dv), f32(B, T, H),
+        f32(B, T, H), f32(B, H, dk, dv)).compile()
+    assert "triangular" not in compiled.as_text().lower()
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20
+
+
 # ---------------------------------------------------------------------------
 # DeepSeek-V2 at the longdoc cell's shape: 32 rows x 2,080 blocks of 16
 # tokens, 16,385 blocks, 5 layers stacked, 128 heads over rows of 640
