@@ -27,6 +27,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from ..profiler import counters
+
 
 def _top_k(x, k):
     """``jax.lax.top_k`` for a small ``k`` as ``k`` arg-max passes (ties to
@@ -98,3 +100,26 @@ def held_expert_ffn(x, weight, expert, gu_w, down_w, first, layer=0,
     y = jnp.where(held.reshape(N * k, 1),
                   ys[place] * weight.reshape(N * k, 1), 0.0)
     return y.reshape(N, k, -1).sum(1), count
+
+
+def publish_load(state, seen):
+    """The expert layers' load so far from an engine's ``step_state()``
+    reading (``moe_assignments [layers, held]``, ``moe_tokens``):
+    ``assignments`` ((token, held expert) pairs computed), ``tokens``
+    (tokens routed), ``per_expert`` and ``load_max_over_mean`` (the
+    busiest held expert of any layer against the mean; 1.0 is even
+    routing).  Publishes what was added since the last call (``seen``, the
+    caller's running totals, updated in place) as the counters
+    ``serving.moe.assignments`` and ``serving.moe.tokens``, and the gauge
+    ``serving.moe.load_max_over_mean``."""
+    per = state["moe_assignments"]
+    load = {"assignments": int(per.sum()),
+            "tokens": int(state["moe_tokens"]), "per_expert": per,
+            "load_max_over_mean": (float(per.max() / per.mean())
+                                   if per.any() else 0.0)}
+    for k in ("assignments", "tokens"):
+        counters.inc("serving.moe." + k, load[k] - seen[k])
+        seen[k] = load[k]
+    counters.set_gauge("serving.moe.load_max_over_mean",
+                       load["load_max_over_mean"])
+    return load

@@ -51,7 +51,6 @@ from ..kernels import moe as _moe
 from ..kernels._shapes import NEG_INF
 from ..kernels.rms_norm import rms_norm_reference as _rms
 from ..nn.layer.layers import Layer
-from ..profiler import counters
 from ..profiler import host_tracer as _trace
 
 #: cached positions a prefill chunk up-projects and attends to per step
@@ -345,17 +344,7 @@ class DeepseekV2ForCausalLM(Layer):
         ``serving.moe.assignments`` and ``serving.moe.tokens``, and the
         gauge ``serving.moe.load_max_over_mean``: the records' one
         writer, for one engine a model."""
-        per = state["moe_assignments"]
-        load = {"assignments": int(per.sum()),
-                "tokens": int(state["moe_tokens"]), "per_expert": per,
-                "load_max_over_mean": (float(per.max() / per.mean())
-                                       if per.any() else 0.0)}
-        for k in ("assignments", "tokens"):
-            counters.inc("serving.moe." + k, load[k] - self._moe_seen[k])
-            self._moe_seen[k] = load[k]
-        counters.set_gauge("serving.moe.load_max_over_mean",
-                           load["load_max_over_mean"])
-        return load
+        return _moe.publish_load(state, self._moe_seen)
 
     def decode_state(self):
         """Raw device weights for the serving programs (one pytree the

@@ -44,6 +44,11 @@ Well-known names (see README "Observability" for the full table):
       LLMEngine.step_state() and published by the model's moe_load()
       alone) / serving.moe.load_max_over_mean
       (gauge: busiest held expert over the mean, as last read)
+  serving.diffusion.row_passes / serving.diffusion.commits /
+      serving.diffusion.revealed (a block-decoding engine alone,
+      serving/block_decode.py: running rows of each decode launch, one
+      pass of a row's block each / blocks committed / masked positions
+      revealed; serving.decode_tokens counts tokens EMITTED there)
   serving.retraces (serving program compiles; 0 in steady state)
   serving.queue_wait_ns
   serving.deadline_expired (queued past-deadline, evicted pre-prefill)

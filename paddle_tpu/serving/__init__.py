@@ -13,7 +13,8 @@ finished prompts to decode replicas by block-granular KV migration, with
 block pools' head axis and the weight matrices across chips while the
 compiled programs stay single (GSPMD inserts in-graph collectives).  See
 ``serving.paged`` (the engine: cache and programs), ``serving.engine``
-(the request lifecycle under it), ``serving.fleet`` and ``serving.arena``
+(the request lifecycle under it), ``serving.block_decode`` (the engine of
+a model that generates by diffusion over blocks), ``serving.fleet`` and ``serving.arena``
 for the design notes and README "Serving" / "Elastic serving" / "Disaggregated
 serving" / "Sharded serving" for the API tour.
 """
@@ -21,7 +22,9 @@ serving" / "Sharded serving" for the API tour.
 from .arena import (DEFAULT_SHARD_RULES, KV_POOL_SPEC,  # noqa: F401
                     StateArena)
 from .autoscale import FleetAutoscaler  # noqa: F401
-from .engine import (EngineBackpressure, EngineClosed,  # noqa: F401
+from .block_decode import BlockDecodeLLMEngine  # noqa: F401
+from .engine import (BlockDecodeUnsupported,  # noqa: F401
+                     EngineBackpressure, EngineClosed,
                      LatentCacheUnsupported, RecurrentStateUnsupported,
                      Request, bucket_length)
 from .fleet import FleetRequest, Replica, ServingFleet  # noqa: F401
@@ -32,9 +35,10 @@ from .router import RetryAfter, Router  # noqa: F401
 from .sampling import filter_logits, residual_sample, sample_tokens  # noqa: F401
 from .speculative import SpeculativeLLMEngine  # noqa: F401
 
-__all__ = ["LLMEngine", "SpeculativeLLMEngine", "Request",
-           "EngineBackpressure", "EngineClosed", "RecurrentStateUnsupported",
-           "LatentCacheUnsupported", "bucket_length",
+__all__ = ["LLMEngine", "SpeculativeLLMEngine", "BlockDecodeLLMEngine",
+           "Request", "EngineBackpressure", "EngineClosed",
+           "RecurrentStateUnsupported", "LatentCacheUnsupported",
+           "BlockDecodeUnsupported", "bucket_length",
            "filter_logits", "sample_tokens", "residual_sample",
            "ServingFleet", "FleetRequest", "Replica", "FleetAutoscaler",
            "Router", "RetryAfter", "BlockPool", "BlockPoolExhausted",

@@ -8,7 +8,7 @@ token per step regardless of arrival time, and evicted on EOS /
 rehandable.  This module holds that half and nothing of the device: the
 ``Request`` handle, the refusals (``EngineBackpressure``,
 ``EngineClosed``, ``RecurrentStateUnsupported``,
-``LatentCacheUnsupported``), and
+``LatentCacheUnsupported``, ``BlockDecodeUnsupported``), and
 ``_RequestLifecycle`` — the bounded queue and ``add_request``, the finish
 compare-and-set, the sweep of cancelled and late requests, token
 emission with its TTFT / ITL histograms, ``generate`` / ``drain`` and
@@ -90,6 +90,19 @@ class LatentCacheUnsupported(RuntimeError):
     copies blocks by head)."""
 
 
+class BlockDecodeUnsupported(RuntimeError):
+    """Refused for a model that decodes by blocks
+    (``cache_spec()["decode_block"]``: a launch is one denoising pass over
+    a block of positions a row, and a row's K/V enter the pool only when
+    its block commits): the feature assumes one token and one cached
+    position a row and launch, and would serve such a model silently
+    wrong.  Raised at construction for ``draft_model=``, ``kv_dtype=``,
+    ``host_kv_blocks=``, ``adapter_slots=`` and ``mesh=``, by
+    ``add_request(hold_after_prefill=True)`` and by ``export_request`` /
+    ``adopt_migration`` (the prefix cache resolves to off instead: a hit
+    would have to end on a whole block)."""
+
+
 class Request:
     """One generation request and its live state (also the user handle:
     ``add_request`` returns it; iterate it to stream tokens)."""
@@ -98,7 +111,8 @@ class Request:
                  "temperature", "top_k", "top_p", "eos_token_id", "seed",
                  "state", "finish_reason", "tokens", "slot", "arrival_ns",
                  "last_emit_ns", "deadline", "_cancel", "_engine", "error",
-                 "tag", "trace", "hold", "adapter")
+                 "tag", "trace", "hold", "adapter", "denoise_steps",
+                 "reveal_threshold")
 
     def __init__(self, rid, prompt, max_new_tokens, do_sample, temperature,
                  top_k, top_p, eos_token_id, seed, deadline, engine):
@@ -127,6 +141,11 @@ class Request:
         self.trace = None         # TraceContext when request tracing is on
         self.hold = False         # park after prefill for KV migration
         self.adapter = None       # tenant id (LoRA adapter), None = base
+        # block decoding (serving.block_decode): passes in which a block's
+        # masked positions are revealed, and the confidence above which a
+        # pass reveals more than its share (None: the static schedule)
+        self.denoise_steps = None
+        self.reveal_threshold = None
 
     @property
     def is_finished(self):
@@ -411,8 +430,10 @@ class _RequestLifecycle:
                 counters.inc("serving.deadline_expired")
                 self._finish(req, "deadline", events)
 
-    def _emit(self, req, tok, events):
-        """Record one generated token; finish on EOS / length.  The event
+    def _emit(self, req, tok, events, **extra):
+        """Record one generated token; finish on EOS / length (``extra``
+        joins the token's event: a block-decoding engine's
+        ``reveal_step``).  The event
         carries the token's stream index, stamped HERE where it is
         synchronous — consumers that batch events per step (the fleet's
         replay prefix check) see ``req.tokens`` already advanced past this
@@ -433,7 +454,7 @@ class _RequestLifecycle:
         with self._cond:
             self._outstanding -= 1
         events.append({"type": "token", "request": req, "token": int(tok),
-                       "index": len(req.tokens) - 1})
+                       "index": len(req.tokens) - 1, **extra})
         if req.eos_token_id is not None and int(tok) == req.eos_token_id:
             self._finish(req, "eos", events)
         elif len(req.tokens) >= req.max_new_tokens:

@@ -153,9 +153,13 @@ def _sample_and_carry(mesh, logits, running, tok, pos, keys_data, do_sample,
 
 class LLMEngine(_RequestLifecycle):
     """Continuous-batching engine over one causal LM (``GPTForCausalLM``,
-    ``OlmoHybridForCausalLM``, ``DeepseekV2ForCausalLM``: anything with
-    ``cache_spec()``,
-    ``decode_state()``, ``prefill_paged`` and ``decode_paged``).
+    ``OlmoHybridForCausalLM``, ``DeepseekV2ForCausalLM``,
+    ``SdarMoeForCausalLM``: anything with ``cache_spec()``,
+    ``decode_state()``, ``prefill_paged`` and ``decode_paged``).  A model
+    whose ``cache_spec()`` names a ``decode_block`` (generation by
+    diffusion over blocks: a launch is one denoising pass of every running
+    row's block) is served by the subclass in ``serving.block_decode``,
+    which this constructor returns for it.
 
     ``add_request()`` enqueues (bounded queue, optional blocking
     backpressure); ``step()`` admits into free slots by reserving K/V
@@ -185,10 +189,17 @@ class LLMEngine(_RequestLifecycle):
     def __new__(cls, *args, **kw):
         # a draft_model= routes construction to the speculative subclass,
         # so `LLMEngine(model, draft_model=...)` is the one public spelling
-        # (serving.speculative imports this module; resolve lazily)
-        if cls is LLMEngine and kw.get("draft_model") is not None:
-            from .speculative import SpeculativeLLMEngine
-            return super().__new__(SpeculativeLLMEngine)
+        # and a model that decodes by blocks to the block-decoding one
+        # (both import this module; resolve lazily)
+        if cls is LLMEngine:
+            model = args[0] if args else kw.get("model")
+            spec = getattr(model, "cache_spec", None)
+            if spec is not None and spec().get("decode_block"):
+                from .block_decode import BlockDecodeLLMEngine
+                return super().__new__(BlockDecodeLLMEngine)
+            if kw.get("draft_model") is not None:
+                from .speculative import SpeculativeLLMEngine
+                return super().__new__(SpeculativeLLMEngine)
         return super().__new__(cls)
 
     def __init__(self, model, max_slots=8, max_seq_len=None, queue_size=64,
@@ -428,7 +439,7 @@ class LLMEngine(_RequestLifecycle):
             self.adapters = AdapterArena(
                 self.model, self.arena, _model_programs(self.model),
                 self.adapter_slots, self.adapter_rank,
-                dispatch=self._adapter_dispatch)
+                dispatch=self._dispatch)
         else:
             self.adapters = None
         # the decode program's per-slot operands, in its order
@@ -548,11 +559,11 @@ class LLMEngine(_RequestLifecycle):
         if self.adapters is not None:
             self.adapters.release_slabs()
 
-    def _adapter_dispatch(self, name, fn, args, dn):
-        """Capture/audit/devicetime bracket for the adapter arena's load
-        program — the same discipline every other engine dispatch gets,
-        handed to the arena as a callback so it never reaches into
-        engine internals."""
+    def _dispatch(self, name, fn, args, dn):
+        """``fn(*args)`` inside the capture/audit/devicetime bracket every
+        engine dispatch gets.  The adapter arena's load program takes it as
+        a callback, so it never reaches into engine internals; a subclass
+        with programs of its own launches them through it."""
         self._maybe_capture(name, fn, *args)
         self._maybe_audit(name, fn, *args, donate_argnums=dn)
         _dt = _devicetime.note(name)
@@ -688,141 +699,146 @@ class LLMEngine(_RequestLifecycle):
         return (f"{base}@{self.kv_kernel}:{self.kv_dtype or 'raw'}"
                 f"{lo}{self.arena.tag}")
 
+    def _build_pchunk(self):
+        """The jitted chunk program (one per engine, every bucket a
+        shape of it); a subclass with another chunk overrides this."""
+        model = self.model
+        mode = self.kv_kernel
+        # adapter engines append the slab pytree + per-row ids as
+        # trailing operands (never donated — the gather reads
+        # them); donation indices are untouched
+        lora = self.adapters is not None
+
+        if self._state_names:
+            def pchunk(w, ids, start, length, bt, pk, pv, st, slot,
+                       key_data, do_sample, temp, top_k, top_p):
+                counters.inc("serving.retraces")  # trace-time only
+                pk, pv, st, logits = model.prefill_paged(
+                    w, ids, start, length, bt, pk, pv, st, slot,
+                    kernel=mode)
+                tok, new_key = LLMEngine._first_token(
+                    logits, key_data, do_sample, temp, top_k, top_p)
+                return pk, pv, st, tok, new_key
+            return jax.jit(pchunk, donate_argnums=(5, 6, 7))
+
+        if self.kv_dtype:
+            def pchunk(w, ids, start, length, bt, pk, pv, sk, sv,
+                       key_data, do_sample, temp, top_k, top_p,
+                       *ad):
+                counters.inc("serving.retraces")  # trace-time only
+                aw, aid = ad if lora else (None, None)
+                pk, pv, sk, sv, logits = model.prefill_paged(
+                    w, ids, start, length, bt, pk, pv, sk, sv,
+                    adapters=aw, adapter_ids=aid)
+                tok, new_key = LLMEngine._first_token(
+                    logits, key_data, do_sample, temp, top_k, top_p)
+                return pk, pv, sk, sv, tok, new_key
+            return jax.jit(pchunk, donate_argnums=(5, 6, 7, 8))
+
+        def pchunk(w, ids, start, length, bt, pk, pv, key_data,
+                   do_sample, temp, top_k, top_p, *ad):
+            counters.inc("serving.retraces")  # trace-time only
+            aw, aid = ad if lora else (None, None)
+            pk, pv, logits = model.prefill_paged(
+                w, ids, start, length, bt, pk, pv,
+                adapters=aw, adapter_ids=aid)
+            tok, new_key = LLMEngine._first_token(
+                logits, key_data, do_sample, temp, top_k, top_p)
+            return pk, pv, tok, new_key
+        return jax.jit(pchunk, donate_argnums=(5, 6))
+
     def _pchunk_for(self, bucket):
         fn = self._pchunk_jits.get(bucket)
         if fn is None:
-            model = self.model
-            mode = self.kv_kernel
-
-            def build():
-                # adapter engines append the slab pytree + per-row ids as
-                # trailing operands (never donated — the gather reads
-                # them); donation indices are untouched
-                lora = self.adapters is not None
-
-                if self._state_names:
-                    def pchunk(w, ids, start, length, bt, pk, pv, st, slot,
-                               key_data, do_sample, temp, top_k, top_p):
-                        counters.inc("serving.retraces")  # trace-time only
-                        pk, pv, st, logits = model.prefill_paged(
-                            w, ids, start, length, bt, pk, pv, st, slot,
-                            kernel=mode)
-                        tok, new_key = LLMEngine._first_token(
-                            logits, key_data, do_sample, temp, top_k, top_p)
-                        return pk, pv, st, tok, new_key
-                    return jax.jit(pchunk, donate_argnums=(5, 6, 7))
-
-                if self.kv_dtype:
-                    def pchunk(w, ids, start, length, bt, pk, pv, sk, sv,
-                               key_data, do_sample, temp, top_k, top_p,
-                               *ad):
-                        counters.inc("serving.retraces")  # trace-time only
-                        aw, aid = ad if lora else (None, None)
-                        pk, pv, sk, sv, logits = model.prefill_paged(
-                            w, ids, start, length, bt, pk, pv, sk, sv,
-                            adapters=aw, adapter_ids=aid)
-                        tok, new_key = LLMEngine._first_token(
-                            logits, key_data, do_sample, temp, top_k, top_p)
-                        return pk, pv, sk, sv, tok, new_key
-                    return jax.jit(pchunk, donate_argnums=(5, 6, 7, 8))
-
-                def pchunk(w, ids, start, length, bt, pk, pv, key_data,
-                           do_sample, temp, top_k, top_p, *ad):
-                    counters.inc("serving.retraces")  # trace-time only
-                    aw, aid = ad if lora else (None, None)
-                    pk, pv, logits = model.prefill_paged(
-                        w, ids, start, length, bt, pk, pv,
-                        adapters=aw, adapter_ids=aid)
-                    tok, new_key = LLMEngine._first_token(
-                        logits, key_data, do_sample, temp, top_k, top_p)
-                    return pk, pv, tok, new_key
-                return jax.jit(pchunk, donate_argnums=(5, 6))
             key = self._prog_key("prefill_paged")
             with span("serving.program_build", level=0, key=key,
                       bucket=bucket):
-                fn = self.arena.program(_model_programs(model), key, build)
+                fn = self.arena.program(_model_programs(self.model), key,
+                                        self._build_pchunk)
             self._pchunk_jits[bucket] = fn
             counters.set_gauge("serving.prefill_programs",
                                len(self._pchunk_jits))
         return fn
 
+    def _build_pdecode(self):
+        """The jitted decode program; a subclass with another decode
+        launch overrides this."""
+        model = self.model
+        mode = self.kv_kernel
+        # the pallas kernel is per-head independent, so under a mesh
+        # whose KV head axis actually sharded it runs through a
+        # shard_map over "mp" (see kernels.paged_attention); the
+        # gather twin needs nothing — GSPMD partitions it from the
+        # committed input shardings alone
+        mesh = (self.arena.mesh
+                if mode == "pallas" and self.arena.kv_head_axis
+                else None)
+        head_axis = "mp" if mesh is not None else None
+
+        # the carried operands stay replicated on the arena's mesh,
+        # as arena.operand uploads them
+        rep = self.arena.mesh
+        lora = self.adapters is not None
+
+        # every variant takes the rows' UNMASKED state and masks
+        # it itself, and returns, after the tokens and the pools,
+        # the positions and keys of the next launch; the tokens it
+        # returns are the next launch's too
+        if self._state_names:
+            def decode(w, pk, pv, st, bt, tok, pos, running,
+                       keys_data, do_sample, temp, top_k, top_p):
+                counters.inc("serving.retraces")
+                bt_e, pos_e, ds_e, _ = _mask_idle(
+                    running, bt, pos, do_sample)
+                logits, pk, pv, st = model.decode_paged(
+                    w, tok, pos_e, bt_e, pk, pv, st, running,
+                    kernel=mode)
+                nxt, pos, keys_data = _sample_and_carry(
+                    rep, logits, running, tok, pos, keys_data, ds_e,
+                    temp, top_k, top_p)
+                return nxt, pk, pv, st, pos, keys_data
+            return jax.jit(decode, donate_argnums=(1, 2, 3))
+
+        if self.kv_dtype:
+            def decode(w, pk, pv, sk, sv, bt, tok, pos, running,
+                       keys_data, do_sample, temp, top_k, top_p,
+                       *ad):
+                counters.inc("serving.retraces")
+                aw, aid = ad if lora else (None, None)
+                bt_e, pos_e, ds_e, aid = _mask_idle(
+                    running, bt, pos, do_sample, aid)
+                logits, pk, pv, sk, sv = model.decode_paged(
+                    w, tok, pos_e, bt_e, pk, pv, sk, sv,
+                    kernel=mode, mesh=mesh, head_axis=head_axis,
+                    adapters=aw, adapter_ids=aid)
+                nxt, pos, keys_data = _sample_and_carry(
+                    rep, logits, running, tok, pos, keys_data, ds_e,
+                    temp, top_k, top_p)
+                return nxt, pk, pv, sk, sv, pos, keys_data
+            return jax.jit(decode, donate_argnums=(1, 2, 3, 4))
+
+        def decode(w, pk, pv, bt, tok, pos, running, keys_data,
+                   do_sample, temp, top_k, top_p, *ad):
+            counters.inc("serving.retraces")
+            aw, aid = ad if lora else (None, None)
+            bt_e, pos_e, ds_e, aid = _mask_idle(
+                running, bt, pos, do_sample, aid)
+            logits, pk, pv = model.decode_paged(
+                w, tok, pos_e, bt_e, pk, pv, kernel=mode,
+                mesh=mesh, head_axis=head_axis,
+                adapters=aw, adapter_ids=aid)
+            nxt, pos, keys_data = _sample_and_carry(
+                rep, logits, running, tok, pos, keys_data, ds_e,
+                temp, top_k, top_p)
+            return nxt, pk, pv, pos, keys_data
+        return jax.jit(decode, donate_argnums=(1, 2))
+
     def _pdecode(self):
         if self._pdecode_jit is None:
-            model = self.model
-            mode = self.kv_kernel
-            # the pallas kernel is per-head independent, so under a mesh
-            # whose KV head axis actually sharded it runs through a
-            # shard_map over "mp" (see kernels.paged_attention); the
-            # gather twin needs nothing — GSPMD partitions it from the
-            # committed input shardings alone
-            mesh = (self.arena.mesh
-                    if mode == "pallas" and self.arena.kv_head_axis
-                    else None)
-            head_axis = "mp" if mesh is not None else None
-
-            # the carried operands stay replicated on the arena's mesh,
-            # as arena.operand uploads them
-            rep = self.arena.mesh
-
-            def build():
-                lora = self.adapters is not None
-
-                # every variant takes the rows' UNMASKED state and masks
-                # it itself, and returns, after the tokens and the pools,
-                # the positions and keys of the next launch; the tokens it
-                # returns are the next launch's too
-                if self._state_names:
-                    def decode(w, pk, pv, st, bt, tok, pos, running,
-                               keys_data, do_sample, temp, top_k, top_p):
-                        counters.inc("serving.retraces")
-                        bt_e, pos_e, ds_e, _ = _mask_idle(
-                            running, bt, pos, do_sample)
-                        logits, pk, pv, st = model.decode_paged(
-                            w, tok, pos_e, bt_e, pk, pv, st, running,
-                            kernel=mode)
-                        nxt, pos, keys_data = _sample_and_carry(
-                            rep, logits, running, tok, pos, keys_data, ds_e,
-                            temp, top_k, top_p)
-                        return nxt, pk, pv, st, pos, keys_data
-                    return jax.jit(decode, donate_argnums=(1, 2, 3))
-
-                if self.kv_dtype:
-                    def decode(w, pk, pv, sk, sv, bt, tok, pos, running,
-                               keys_data, do_sample, temp, top_k, top_p,
-                               *ad):
-                        counters.inc("serving.retraces")
-                        aw, aid = ad if lora else (None, None)
-                        bt_e, pos_e, ds_e, aid = _mask_idle(
-                            running, bt, pos, do_sample, aid)
-                        logits, pk, pv, sk, sv = model.decode_paged(
-                            w, tok, pos_e, bt_e, pk, pv, sk, sv,
-                            kernel=mode, mesh=mesh, head_axis=head_axis,
-                            adapters=aw, adapter_ids=aid)
-                        nxt, pos, keys_data = _sample_and_carry(
-                            rep, logits, running, tok, pos, keys_data, ds_e,
-                            temp, top_k, top_p)
-                        return nxt, pk, pv, sk, sv, pos, keys_data
-                    return jax.jit(decode, donate_argnums=(1, 2, 3, 4))
-
-                def decode(w, pk, pv, bt, tok, pos, running, keys_data,
-                           do_sample, temp, top_k, top_p, *ad):
-                    counters.inc("serving.retraces")
-                    aw, aid = ad if lora else (None, None)
-                    bt_e, pos_e, ds_e, aid = _mask_idle(
-                        running, bt, pos, do_sample, aid)
-                    logits, pk, pv = model.decode_paged(
-                        w, tok, pos_e, bt_e, pk, pv, kernel=mode,
-                        mesh=mesh, head_axis=head_axis,
-                        adapters=aw, adapter_ids=aid)
-                    nxt, pos, keys_data = _sample_and_carry(
-                        rep, logits, running, tok, pos, keys_data, ds_e,
-                        temp, top_k, top_p)
-                    return nxt, pk, pv, pos, keys_data
-                return jax.jit(decode, donate_argnums=(1, 2))
             key = self._prog_key("decode_paged")
             with span("serving.program_build", level=0, key=key):
                 self._pdecode_jit = self.arena.program(
-                    _model_programs(model), key, build)
+                    _model_programs(self.model), key, self._build_pdecode)
         return self._pdecode_jit
 
     def _pcopy(self):
@@ -1238,9 +1254,7 @@ class LLMEngine(_RequestLifecycle):
         ids = np.asarray(
             prompt._data if hasattr(prompt, "_data") else prompt,
             dtype=np.int32).reshape(-1)
-        need = blocks_for_tokens(
-            max(1, int(ids.shape[0]) + int(max_new_tokens) - 1),
-            self.pool.block_size)
+        need = self._blocks_needed(int(ids.shape[0]), int(max_new_tokens))
         if need > self.pool.capacity:
             raise ValueError(
                 f"request needs {need} KV blocks but the pool only has "
@@ -1249,6 +1263,12 @@ class LLMEngine(_RequestLifecycle):
         return super().add_request(ids, max_new_tokens=max_new_tokens, **kw)
 
     # -- admission: all-or-nothing block reservation -------------------------
+    def _blocks_needed(self, T, max_new):
+        """K/V blocks a request of ``T`` prompt tokens can ever touch: the
+        last token sampled is never written back."""
+        return blocks_for_tokens(max(1, T + max_new - 1),
+                                 self.pool.block_size)
+
     def _reserve(self, req, events):
         """Match the prefix cache, then reserve every block the request
         can ever touch (``ceil((T + max_new - 1)/bs)`` minus shared
@@ -1258,8 +1278,7 @@ class LLMEngine(_RequestLifecycle):
         this request id."""
         from ..resilience import faultinject as _fi
         T = int(req.prompt.shape[0])
-        bs = self.pool.block_size
-        total = blocks_for_tokens(max(1, T + req.max_new_tokens - 1), bs)
+        total = self._blocks_needed(T, req.max_new_tokens)
         tr = req.trace
         t0_tr = time.perf_counter_ns() if tr is not None else 0
         with self._cond:

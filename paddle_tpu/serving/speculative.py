@@ -68,7 +68,8 @@ from ..profiler import devicetime as _devicetime
 from ..profiler import flight
 from ..profiler import trace as rtrace
 from ..profiler.host_tracer import span
-from .engine import (LatentCacheUnsupported, RecurrentStateUnsupported,
+from .engine import (BlockDecodeUnsupported, LatentCacheUnsupported,
+                     RecurrentStateUnsupported,
                      bucket_length)
 from .kvcache import blocks_for_tokens
 from .paged import LLMEngine, _model_programs
@@ -178,6 +179,10 @@ class SpeculativeLLMEngine(LLMEngine):
                 "draft_model= with a target or a draft that has recurrent "
                 "layers: verification rolls K/V back by position, and a "
                 "recurrent state has none")
+        if draft.cache_spec().get("decode_block"):
+            raise BlockDecodeUnsupported(
+                "draft_model= with a draft that decodes by blocks: a "
+                "proposal is one token a row and launch")
         if any(m.cache_spec().get("kv_row") for m in (model, draft)):
             raise LatentCacheUnsupported(
                 "draft_model= with a target or a draft that caches latent "

@@ -1764,6 +1764,48 @@ def run():
             violations[f"moe:identity@{h.rid}"] = (
                 h.tokens, lg.argmax(-1).tolist())
 
+    # ---- block-decode gate: one read-back, the diffusion records ---------
+    # A model that generates by diffusion over blocks (models/sdar.py) is
+    # served by serving/block_decode.py: a decode launch is one pass of
+    # every running row's block, a row yields its tokens when its block
+    # commits.  The measure window must trace nothing; its records count a
+    # launch (``serving.decode_steps``), the rows it passed
+    # (``serving.diffusion.row_passes``), the blocks committed, the
+    # positions revealed, and the tokens EMITTED
+    # (``serving.decode_tokens``); no earlier engine may have registered a
+    # ``serving.diffusion.*`` name.
+    from paddle_tpu.models.sdar import SdarConfig, SdarMoeForCausalLM
+    if any(k.startswith("serving.diffusion.") for k in counters.snapshot()):
+        violations["diffusion:registered_without_blocks"] = (True, False)
+    bmodel = SdarMoeForCausalLM(SdarConfig(
+        vocab_size=64, hidden_size=32, moe_intermediate_size=16,
+        num_layers=2, num_heads=4, num_kv_heads=2, head_dim=8,
+        num_experts=8, num_experts_per_tok=2, max_seq_len=64,
+        mask_token_id=63))
+    bmodel.eval()
+    beng = LLMEngine(bmodel, max_slots=1, max_seq_len=32, min_bucket=4,
+                     block_size=4, prefill_chunk=8)
+    pserve(beng, SERVE_LENS_WARM)
+    bbefore = counters.snapshot()
+    bhs = pserve(beng, SERVE_LENS_MEASURE)
+    bsteady = counters.delta(bbefore)
+    # prompts of 4 and 5 tokens, 3 new tokens each: a block of 4 masked
+    # positions (4 passes and the commit) and one of 3 (3 and the commit)
+    want_blocks = {"serving.retraces": 0, "jit.traces": 0,
+                   "serving.decode_steps": 9,
+                   "serving.diffusion.row_passes": 9,
+                   "serving.diffusion.commits": 2,
+                   "serving.diffusion.revealed": 7,
+                   "serving.decode_tokens": 6}
+    for k, want in want_blocks.items():
+        if bsteady.get(k, 0) != want:
+            violations[f"diffusion:{k}"] = (bsteady.get(k, 0), want)
+    if not (beng.stats()["prefix_cache"] is False and beng._pv is None
+            and all(len(h.tokens) == 3 for h in bhs)):
+        violations["diffusion:stats"] = (
+            (beng.stats()["prefix_cache"], [len(h.tokens) for h in bhs]),
+            "(False, [3, 3])")
+
     result = {"metric": "steady_state_counter_violations",
               "value": len(violations),
               "unit": f"violations/{MEASURE} steps "

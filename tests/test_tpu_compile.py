@@ -331,3 +331,93 @@ def test_mla_prefill_fold_compiles(chip, C):
         _sds(chip, (H, K, 192), jnp.bfloat16),
         _sds(chip, (H, K, 128), jnp.bfloat16), _sds(chip, (), jnp.int32),
         _sds(chip, (), jnp.int32), *state, kernels=["mla_prefill_attn"])
+
+
+def _sdar(chip):
+    import json
+    from paddle_tpu.models import sdar
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "sdar-30b-a3b.json")) as f:
+        hf = json.load(f)
+    real = sdar.SdarMoeForCausalLM.__new__(sdar.SdarMoeForCausalLM)
+    real.__dict__["config"] = c = sdar.SdarConfig.from_hf(
+        hf, block_length=hf["block_length"],
+        denoising_steps=hf["denoising_steps"],
+        mask_token_id=hf["mask_token_id"], dtype="bfloat16")
+    w = {n: _sds(chip, shape, dt)
+         for n, (shape, _, dt) in sdar.param_shapes(c).items()}
+    assert w["expert_gu_w"].shape == (6, 128, 2048, 1536)
+    assert w["kv_w"].shape == (6, 2048, 1024)
+    st = {n: _sds(chip, tuple(shape), dt)
+          for n, (shape, dt) in real.cache_spec()["step_state"].items()}
+    return real, w, st
+
+
+def test_block_decode_attn_compiles(chip):
+    """The chat-blocks cell's shape: 32 rows, per K/V head a query tile of
+    8 heads x 4 positions, 4 K/V heads of 128 as rows of 1,024, the
+    block's own four lines padded to a sublane tile."""
+    from paddle_tpu.kernels import block_attention as ba
+    S, max_blocks, n_blocks = 32, 320, 10241
+    _compile(lambda q, new, pool, layer, bt, pos: ba.block_decode_attn(
+        q, new, pool, layer, bt, pos, 4),
+        _sds(chip, (S, 4, 32, 128), jnp.bfloat16),
+        _sds(chip, (S, 4, 1024), jnp.bfloat16),
+        _sds(chip, (6, n_blocks, 16, 1024), jnp.bfloat16),
+        _sds(chip, (), jnp.int32), _sds(chip, (S, max_blocks), jnp.int32),
+        _sds(chip, (S,), jnp.int32), kernels=["block_decode_attn"])
+
+
+def test_block_decode_step_updates_the_pool_in_place(chip):
+    """``serving.block_decode.decode_program`` (``jit_decode`` of a
+    block-decoding engine) at the chat-blocks cell's shape: the walk is in
+    it, the one pool and the expert counts alias through, no stacked
+    weight is copied or sliced out for the scan over the layers (one
+    layer's 128 experts are 1.2 GB), the grouped products are the
+    compiler's own ``ragged-dot`` calls, and the only sorts are the
+    sampling tail's two."""
+    from paddle_tpu.serving import block_decode as bd
+    real, w, st = _sdar(chip)
+    S, max_blocks, n_blocks = 32, 320, 10241
+    pool = _sds(chip, (6, n_blocks, 16, 1024), jnp.bfloat16)
+    i32, f32 = jnp.int32, jnp.float32
+    compiled = jax.jit(
+        bd.decode_program(real, "pallas", 4, real.config.mask_token_id),
+        donate_argnums=(1, 2)).lower(
+        w, pool, st, _sds(chip, (S, max_blocks), i32),
+        _sds(chip, (S, 4), i32), _sds(chip, (S, 4), i32),
+        _sds(chip, (S,), i32), _sds(chip, (S,), i32),
+        _sds(chip, (S,), jnp.bool_), _sds(chip, (S, 2), jnp.uint32),
+        _sds(chip, (S,), jnp.bool_), _sds(chip, (S,), f32),
+        _sds(chip, (S,), i32), _sds(chip, (S,), f32), _sds(chip, (S,), i32),
+        _sds(chip, (S,), f32)).compile()
+    text = compiled.as_text()
+    assert re.search(r"%block_decode_attn(\.\d+)? = [^\n]*custom-call\(",
+                     text)
+    assert len(re.findall(r"%ragged-dot[\w.-]* = [^\n]*custom-call\(",
+                          text)) >= 2
+    assert not re.search(r"bf16\[(6|128),\d{4,}[\d,]*\]\S* copy\(", text)
+    assert len(re.findall(r" sort\(", text)) == 2
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 768 * 2 ** 20, mem
+    assert mem.alias_size_in_bytes >= 6 * n_blocks * 16 * 1024 * 2, mem
+
+
+def test_block_prefill_chunk_compiles_in_place(chip):
+    """The widest chunk of the chat-blocks cell (512 tokens): the pool
+    aliases through, no layer's experts are sliced out, and no logits are
+    made (the head is not even an argument: nothing is sampled)."""
+    real, w, st = _sdar(chip)
+    n_blocks = 10241
+    pool = _sds(chip, (6, n_blocks, 16, 1024), jnp.bfloat16)
+    compiled = jax.jit(real.prefill_paged, donate_argnums=(5, 6)).lower(
+        w, _sds(chip, (1, 512), jnp.int32), _sds(chip, (), jnp.int32),
+        _sds(chip, (), jnp.int32), _sds(chip, (320,), jnp.int32), pool,
+        st).compile()
+    text = compiled.as_text()
+    assert not re.search(r"bf16\[(6|128),\d{4,}[\d,]*\]\S* copy\(", text)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 6 * n_blocks * 16 * 1024 * 2, mem
+    assert mem.argument_size_in_bytes < 10_200_000_000, mem   # no head
+    assert mem.temp_size_in_bytes < 512 * 2 ** 20, mem
