@@ -102,6 +102,9 @@ def head_padding(pool, nh):
             lambda t: t if nhp == nh else t[..., :nh, :])
 
 
+_PRELOADED = [False]
+
+
 def preload():
     """Start importing Pallas on a background thread.  The import pulls
     in every Mosaic dialect (0.8 s on a desktop core, 1.5 s on a v5e's
@@ -109,8 +112,13 @@ def preload():
     trace, in front of the first token.  An engine that resolved to the
     kernel calls this at construction; the prefill programs it builds
     first leave the GIL often enough to hide about a third of it
-    (``PERF.md``, PR 27).  The kernel's own import then finds the module
-    loaded or waits on its lock."""
+    (``PERF.md``, PR 27).  A model whose experts run through a kernel
+    (``kernels.moe.preload``) calls it before it draws its weights, which
+    hides the rest.  The kernel's own import then finds the module loaded
+    or waits on its lock; a second call does nothing."""
+    if _PRELOADED[0]:
+        return
+    _PRELOADED[0] = True
     threading.Thread(target=importlib.import_module,
                      args=("jax.experimental.pallas.tpu",),
                      name="pallas-import", daemon=True).start()

@@ -287,6 +287,7 @@ class DeepseekV2ForCausalLM(Layer):
         t0_ns = time.perf_counter_ns()
         super().__init__()
         self.config = c = config
+        _moe.preload(c.hidden_size, c.moe_intermediate_size, c.dtype)
         from ..nn.initializer import Constant, Normal
         from ..nn.functional.init_utils import param_attr_init
         from ..distributed.sharding_utils import annotate_param

@@ -44,6 +44,11 @@ Well-known names (see README "Observability" for the full table):
       LLMEngine.step_state() and published by the model's moe_load()
       alone) / serving.moe.load_max_over_mean
       (gauge: busiest held expert over the mean, as last read)
+  kernels.moe.grouped_mm.pallas / kernels.moe.grouped_mm.xla (grouped
+      products of the held experts traced with the weight-stationary
+      Pallas kernel / with the jax.lax.ragged_dot twin: the choice is
+      static, so it is counted where programs are built, one a traced
+      call, two an expert layer's trace; a steady state counts nothing)
   serving.diffusion.row_passes / serving.diffusion.commits /
       serving.diffusion.revealed (a block-decoding engine alone,
       serving/block_decode.py: running rows of each decode launch, one

@@ -1719,7 +1719,10 @@ def run():
     # window's tokens, every (token, layer) pair's ``num_experts_per_tok``
     # choices with all the experts held, and the served tokens are the
     # model's own forward pass's.  No earlier engine may have registered
-    # those names.
+    # those names.  Which body the grouped products took is counted where
+    # the programs are traced (``kernels.moe.grouped_mm.pallas`` /
+    # ``.xla``, one a traced call): here, with no TPU, every one is the
+    # ``ragged_dot`` twin, and the measure window traces none.
     from paddle_tpu.models.deepseek_v2 import (DeepseekV2Config,
                                                DeepseekV2ForCausalLM)
     if any(k.startswith("serving.moe.") for k in counters.snapshot()):
@@ -1733,13 +1736,21 @@ def run():
     dmodel.eval()
     deng = LLMEngine(dmodel, max_slots=1, max_seq_len=32, min_bucket=4,
                      block_size=4, prefill_chunk=8)
+    dtraced = counters.snapshot()
     pserve(deng, SERVE_LENS_WARM)
     dmodel.moe_load(deng.step_state())
     dbefore = counters.snapshot()
+    dwarm = counters.delta(dtraced, dbefore)
+    if not (dwarm.get("kernels.moe.grouped_mm.xla", 0) > 0
+            and dwarm.get("kernels.moe.grouped_mm.pallas", 0) == 0):
+        violations["moe:grouped_mm_body"] = (
+            (dwarm.get("kernels.moe.grouped_mm.xla", 0),
+             dwarm.get("kernels.moe.grouped_mm.pallas", 0)), "(>0, 0)")
     dhs = pserve(deng, SERVE_LENS_MEASURE)
     dsteady_moe = counters.delta(dbefore)
     for k in ("serving.retraces", "jit.traces", "serving.moe.tokens",
-              "serving.moe.assignments"):
+              "serving.moe.assignments", "kernels.moe.grouped_mm.xla",
+              "kernels.moe.grouped_mm.pallas"):
         if dsteady_moe.get(k, 0):
             violations[f"moe:{k}"] = (dsteady_moe.get(k, 0), 0)
     dload = dmodel.moe_load(deng.step_state())

@@ -249,6 +249,62 @@ def test_gdn_chunk_compiles_at_the_docqa_chunk(chip):
 
 
 # ---------------------------------------------------------------------------
+# the held experts' grouped products (kernels/moe.py) at the two expert
+# cells' shapes: (token, expert) pairs, stacked layers, held experts, K, N
+# ---------------------------------------------------------------------------
+def _experts_on_the_chip(monkeypatch):
+    """``moe.kernel_mode`` asks ``on_tpu()``, which sees the CPU here: the
+    whole-program compiles answer for the described chip."""
+    from paddle_tpu.kernels import moe
+    monkeypatch.setattr(moe, "on_tpu", lambda: True)
+
+
+def _held_products_are_the_kernel(text):
+    """Both grouped products of every expert layer are the Pallas kernel,
+    under the name the trace's readers look for, and the compiler's own
+    grouped matmul is gone."""
+    assert len(re.findall(r"%ragged-dot-held[\w.]* = [^\n]*custom-call\("
+                          r'[^\n]*custom_call_target="tpu_custom_call"',
+                          text)) == 2
+    assert not re.search(r"%ragged-dot-(none|metadata)", text)
+
+
+_GROUPED_SHAPES = [
+    pytest.param(128 * 8, 6, 128, 2048, 1536, "bfloat16",
+                 id="sdar-decode-gate-up"),
+    pytest.param(128 * 8, 6, 128, 768, 2048, "float32",
+                 id="sdar-decode-down"),
+    pytest.param(512 * 8, 6, 128, 2048, 1536, "bfloat16",
+                 id="sdar-chunk-gate-up"),
+    pytest.param(512 * 8, 6, 128, 768, 2048, "float32",
+                 id="sdar-chunk-down"),
+    pytest.param(32 * 6, 4, 40, 5120, 3072, "bfloat16",
+                 id="deepseek-decode-gate-up"),
+    pytest.param(32 * 6, 4, 40, 1536, 5120, "float32",
+                 id="deepseek-decode-down"),
+    pytest.param(1024 * 6, 4, 40, 5120, 3072, "bfloat16",
+                 id="deepseek-chunk-gate-up"),
+    pytest.param(1024 * 6, 4, 40, 1536, 5120, "float32",
+                 id="deepseek-chunk-down"),
+]
+
+
+@pytest.mark.parametrize("pairs,L,E,K,N,out", _GROUPED_SHAPES)
+def test_grouped_mm_compiles(chip, pairs, L, E, K, N, out):
+    """The weight-stationary kernel alone, the stacked experts of every
+    layer as one operand and the applied layer traced: gate beside up in
+    the compute dtype, down in float32."""
+    from paddle_tpu.kernels import moe
+    tile = moe.row_tile(pairs, E, jnp.bfloat16)
+    R = -(-(pairs + E * (tile - 1)) // tile) * tile
+    _compile(lambda xs, w, count, layer: moe.grouped_mm_pallas(
+        xs, w, count, layer, tile, jnp.dtype(out)),
+        _sds(chip, (R, K), jnp.bfloat16), _sds(chip, (L, E, K, N), jnp.bfloat16),
+        _sds(chip, (E,), jnp.int32), _sds(chip, (), jnp.int32),
+        kernels=["ragged-dot-held"])
+
+
+# ---------------------------------------------------------------------------
 # DeepSeek-V2 at the longdoc cell's shape: 32 rows x 2,080 blocks of 16
 # tokens, 16,385 blocks, 5 layers stacked, 128 heads over rows of 640
 # ---------------------------------------------------------------------------
@@ -284,14 +340,15 @@ def test_mla_decode_compiles(chip):
         _sds(chip, (B,), jnp.int32), kernels=["mla_decode_attn"])
 
 
-def test_latent_decode_step_updates_the_pool_in_place(chip):
+def test_latent_decode_step_updates_the_pool_in_place(chip, monkeypatch):
     """``DeepseekV2ForCausalLM.decode_paged`` at the longdoc cell's shape:
     the latent walk is in it, the one pool and the expert counts alias
     through, no stacked weight is copied or sliced out for the scan over the
     expert layers (one layer's 40 experts are 1.9 GB), the grouped products
-    are the compiler's own ``ragged-dot`` calls, nothing the size of the
-    pool is kept as a temporary, and the only sorts are the sampling
-    tail's two."""
+    are the weight-stationary kernel's two calls and none of the
+    compiler's own ``ragged-dot``, nothing the size of the pool is kept as
+    a temporary, and the only sorts are the sampling tail's two."""
+    _experts_on_the_chip(monkeypatch)
     real, w, st = _deepseek(chip)
     B, max_blocks, n_blocks = 32, 2080, 16385
     pool = _sds(chip, (5, n_blocks, 16, 640), jnp.bfloat16)
@@ -306,13 +363,40 @@ def test_latent_decode_step_updates_the_pool_in_place(chip):
         _sds(chip, (B,), jnp.bool_)).compile()
     text = compiled.as_text()
     assert re.search(r"%mla_decode_attn(\.\d+)? = [^\n]*custom-call\(", text)
-    assert len(re.findall(r"%ragged-dot[\w.-]* = [^\n]*custom-call\(",
-                          text)) >= 2
+    _held_products_are_the_kernel(text)
     assert not re.search(r"bf16\[(4|5|40|160),\d{4,}[\d,]*\]\S* copy\(", text)
     assert not re.search(r" sort\(", text)      # the model's part has none
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 64 * 2 ** 20, mem
     assert mem.alias_size_in_bytes >= 5 * n_blocks * 16 * 640 * 2, mem
+
+
+def test_latent_prefill_chunk_compiles_in_place(chip, monkeypatch):
+    """The widest chunk of the longdoc cell (1,024 tokens, 6,144 (token,
+    expert) pairs of which a quarter are held here): the fold and the
+    grouped products are the kernels (64-row tiles), the pool aliases
+    through and no layer's 40 experts are copied or sliced out."""
+    _experts_on_the_chip(monkeypatch)
+    real, w, st = _deepseek(chip)
+    max_blocks, n_blocks = 2080, 16385
+    pool = _sds(chip, (5, n_blocks, 16, 640), jnp.bfloat16)
+
+    def chunk(w, pool, st, ids, start, length, bt, slot):
+        return real.prefill_paged(w, ids, start, length, bt, pool, None, st,
+                                  slot, kernel="pallas")
+
+    compiled = jax.jit(chunk, donate_argnums=(1, 2)).lower(
+        w, pool, st, _sds(chip, (1, 1024), jnp.int32),
+        _sds(chip, (), jnp.int32), _sds(chip, (), jnp.int32),
+        _sds(chip, (max_blocks,), jnp.int32),
+        _sds(chip, (), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert re.search(r"%mla_prefill_attn[\w.]* = [^\n]*custom-call\(", text)
+    _held_products_are_the_kernel(text)
+    assert not re.search(r"bf16\[(4|5|40|160),\d{4,}[\d,]*\]\S* copy\(", text)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 5 * n_blocks * 16 * 640 * 2, mem
+    assert mem.temp_size_in_bytes < 1536 * 2 ** 20, mem
 
 
 @pytest.mark.parametrize("C", [1024, 8])
@@ -369,15 +453,16 @@ def test_block_decode_attn_compiles(chip):
         _sds(chip, (S,), jnp.int32), kernels=["block_decode_attn"])
 
 
-def test_block_decode_step_updates_the_pool_in_place(chip):
+def test_block_decode_step_updates_the_pool_in_place(chip, monkeypatch):
     """``serving.block_decode.decode_program`` (``jit_decode`` of a
     block-decoding engine) at the chat-blocks cell's shape: the walk is in
     it, the one pool and the expert counts alias through, no stacked
     weight is copied or sliced out for the scan over the layers (one
     layer's 128 experts are 1.2 GB), the grouped products are the
-    compiler's own ``ragged-dot`` calls, and the only sorts are the
-    sampling tail's two."""
+    weight-stationary kernel's two calls and none of the compiler's own
+    ``ragged-dot``, and the only sorts are the sampling tail's two."""
     from paddle_tpu.serving import block_decode as bd
+    _experts_on_the_chip(monkeypatch)
     real, w, st = _sdar(chip)
     S, max_blocks, n_blocks = 32, 320, 10241
     pool = _sds(chip, (6, n_blocks, 16, 1024), jnp.bfloat16)
@@ -395,8 +480,7 @@ def test_block_decode_step_updates_the_pool_in_place(chip):
     text = compiled.as_text()
     assert re.search(r"%block_decode_attn(\.\d+)? = [^\n]*custom-call\(",
                      text)
-    assert len(re.findall(r"%ragged-dot[\w.-]* = [^\n]*custom-call\(",
-                          text)) >= 2
+    _held_products_are_the_kernel(text)
     assert not re.search(r"bf16\[(6|128),\d{4,}[\d,]*\]\S* copy\(", text)
     assert len(re.findall(r" sort\(", text)) == 2
     mem = compiled.memory_analysis()
@@ -404,10 +488,12 @@ def test_block_decode_step_updates_the_pool_in_place(chip):
     assert mem.alias_size_in_bytes >= 6 * n_blocks * 16 * 1024 * 2, mem
 
 
-def test_block_prefill_chunk_compiles_in_place(chip):
+def test_block_prefill_chunk_compiles_in_place(chip, monkeypatch):
     """The widest chunk of the chat-blocks cell (512 tokens): the pool
-    aliases through, no layer's experts are sliced out, and no logits are
-    made (the head is not even an argument: nothing is sampled)."""
+    aliases through, no layer's experts are sliced out (the grouped
+    products are the kernel's, at 32-row tiles), and no logits are made
+    (the head is not even an argument: nothing is sampled)."""
+    _experts_on_the_chip(monkeypatch)
     real, w, st = _sdar(chip)
     n_blocks = 10241
     pool = _sds(chip, (6, n_blocks, 16, 1024), jnp.bfloat16)
@@ -416,6 +502,7 @@ def test_block_prefill_chunk_compiles_in_place(chip):
         _sds(chip, (), jnp.int32), _sds(chip, (320,), jnp.int32), pool,
         st).compile()
     text = compiled.as_text()
+    _held_products_are_the_kernel(text)
     assert not re.search(r"bf16\[(6|128),\d{4,}[\d,]*\]\S* copy\(", text)
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= 6 * n_blocks * 16 * 1024 * 2, mem
