@@ -16,8 +16,9 @@ exceeds ``FLAGS_host_trace_level``, ``span`` returns a shared no-op singleton
 call), so steady-state training and serving pay nothing.
 
 A live span does two things.  Under a profiler session it enters
-``jax.profiler.TraceAnnotation(name)``, so it lands on the host thread of
-the same ``.xplane.pb`` as the device's ops, on the device trace's clock.
+``jax.profiler.TraceAnnotation(name, **counts)``, so it lands on the host
+thread of the same ``.xplane.pb`` as the device's ops, on the device
+trace's clock, with the counts given when it opened as the event's stats.
 And it is kept in memory as ``(name, tid, start_ns, end_ns, depth,
 counts)``: nesting depth comes from a per-thread stack, which also serves
 as the "span context" the NaN/Inf guard reports; ``counts`` is a small
@@ -108,7 +109,10 @@ class _Span:
     def __init__(self, name, counts, profiled, live, lifecycle):
         self.name = name
         self.counts = counts
-        self._ann = TraceAnnotation(name) if profiled else None
+        # counts given at the open ride into the trace as the event's
+        # stats; those of a later note() stay in the store
+        self._ann = (TraceAnnotation(name, **(counts or {})) if profiled
+                     else None)
         self.live = live   # kept in the session store (someone is profiling)
         self._lifecycle = lifecycle
         self._t0 = 0

@@ -64,7 +64,6 @@ import numpy as np
 from ..kernels import block_attention as _ba
 from ..profiler import counters
 from ..profiler import metrics
-from ..profiler import trace as rtrace
 from ..profiler.host_tracer import span
 from .engine import BlockDecodeUnsupported, bucket_length
 from .kvcache import blocks_for_tokens
@@ -270,7 +269,7 @@ class BlockDecodeLLMEngine(LLMEngine):
                 pf = self._pchunk_for(C)
                 pargs = (self._w, op(ids), np.int32(start),
                          np.int32(take_n), st["bt"], self._pk, self._st)
-            with span("serving.prefill.dispatch"):
+            with self._launch_span("serving.prefill.dispatch"):
                 self._pk, self._st = self._dispatch(
                     f"serving.{self._prog_key('prefill_paged')}[c{C}]", pf,
                     pargs, (5, 6))
@@ -291,15 +290,20 @@ class BlockDecodeLLMEngine(LLMEngine):
         btok[:T - whole] = req.prompt[whole:]
         rstep[:T - whole] = GIVEN
         tau = req.reveal_threshold
-        key = np.asarray(jax.random.key_data(jax.random.key(req.seed)))
-        with self._cond:
-            self._write_slot(
-                slot, btok=btok, brstep=rstep, bstep=0, pos=whole, keys=key,
-                temp=req.temperature, topk=req.top_k, topp=req.top_p,
-                dosample=req.do_sample, running=True,
-                nsteps=req.denoise_steps,
-                tau=np.inf if tau is None else tau)
-        req.state = "running"
+        with span("serving.prefill.wait") as w:
+            # the row's key is made on the device behind the chunks: its
+            # read-back is the prefill's, and waits for them
+            key = np.asarray(jax.random.key_data(jax.random.key(req.seed)))
+        self._drained(w)
+        with span("serving.prefill.emit"):
+            with self._cond:
+                self._write_slot(
+                    slot, btok=btok, brstep=rstep, bstep=0, pos=whole,
+                    keys=key, temp=req.temperature, topk=req.top_k,
+                    topp=req.top_p, dosample=req.do_sample, running=True,
+                    nsteps=req.denoise_steps,
+                    tau=np.inf if tau is None else tau)
+            req.state = "running"
 
     # -- decode: one pass of every running row's block -----------------------
     def _decode_step(self, events):
@@ -307,18 +311,17 @@ class BlockDecodeLLMEngine(LLMEngine):
                   if r is not None and r.state == "running"]
         if not active:
             return
-        self._observe("serving.decode_occupancy",
-                      len(active) / self.max_slots)
         B = self.block_length
         with span("serving.decode.operands"):
-            t0 = time.perf_counter()
-            tr_on = rtrace.enabled()
-            t0_tr = time.perf_counter_ns() if tr_on else 0
+            # one clock pair a launch, as the base engine's
+            t0 = time.perf_counter_ns()
+            self._observe("serving.decode_occupancy",
+                          len(active) / self.max_slots)
             dec = self._pdecode()
             tail, uploaded = self._decode_operands()
             sampled = bool((self._dosample & self._running).any())
             dargs = (self._w, self._pk, self._st, *tail)
-        with span("serving.decode.dispatch"):
+        with self._launch_span("serving.decode.dispatch"):
             (out, self._pk, self._st, tok, rstep, step, pos,
              keys) = self._dispatch(
                 f"serving.{self._prog_key('decode_paged')}", dec, dargs,
@@ -328,9 +331,10 @@ class BlockDecodeLLMEngine(LLMEngine):
                 self._dev.update(btok=tok, brstep=rstep, bstep=step,
                                  pos=pos, keys=keys)
                 self._keys_host = None
-        with span("serving.decode.wait"):    # the one read-back
+        with span("serving.decode.wait") as w:    # the one read-back
             out = np.asarray(out)
-        t1_tr = time.perf_counter_ns() if tr_on else 0
+        t1 = time.perf_counter_ns()
+        self._drained(w, t1)
         emitted = revealed = commits = 0
         with span("serving.decode.emit"):
             for s, req in active:
@@ -351,7 +355,7 @@ class BlockDecodeLLMEngine(LLMEngine):
                 self._bstep[s] = 0
                 self._pos[s] = first + B
                 if req.trace is not None:
-                    req.trace.add_span("decode.block", t0_tr, t1_tr,
+                    req.trace.add_span("decode.block", t0, t1,
                                        passes=passes, batch=len(active))
                 events.append({"type": "block", "request": req,
                                "start": first, "passes": passes,
@@ -362,11 +366,11 @@ class BlockDecodeLLMEngine(LLMEngine):
                         continue
                     emitted += 1
                     self._emit(req, int(t), events, reveal_step=int(r))
-        self._note_decode(emitted, time.perf_counter() - t0)
-        counters.inc("serving.decode_steps")
-        counters.inc("serving.decode.sampled_steps", int(sampled))
-        counters.inc("serving.decode.upload_steps", int(uploaded))
-        counters.inc("serving.decode_tokens", emitted)
-        counters.inc("serving.diffusion.row_passes", len(active))
-        counters.inc("serving.diffusion.commits", commits)
-        counters.inc("serving.diffusion.revealed", revealed)
+            self._note_decode(emitted, (t1 - t0) * 1e-9)
+            counters.inc("serving.decode_steps")
+            counters.inc("serving.decode.sampled_steps", int(sampled))
+            counters.inc("serving.decode.upload_steps", int(uploaded))
+            counters.inc("serving.decode_tokens", emitted)
+            counters.inc("serving.diffusion.row_passes", len(active))
+            counters.inc("serving.diffusion.commits", commits)
+            counters.inc("serving.diffusion.revealed", revealed)

@@ -410,6 +410,9 @@ class LLMEngine(_RequestLifecycle):
         self._stale = set(_DECODE_OPERANDS)
         self._slot_blocks = [None] * B
         self._prefill_state = {}      # slot -> {"req": Request, "done": n}
+        # perf_counter_ns at which a read-back left the device with nothing
+        # the engine queued; 0 once a launch took it (or nobody profiles)
+        self._drained_ns = 0
         self._pchunk_jits = {}        # chunk bucket -> jitted prefill
         self._pdecode_jit = None
         self._pcopy_jit = None
@@ -570,6 +573,23 @@ class LLMEngine(_RequestLifecycle):
         out = fn(*args)
         _devicetime.observe(_dt, out)
         return out
+
+    def _drained(self, sp, t_ns=None):
+        """A blocking read-back under the wait span ``sp`` returned: the
+        device holds nothing the engine queued.  Stamped only for someone
+        who profiles, and only the first time since the last launch."""
+        if sp.live and not self._drained_ns:
+            self._drained_ns = t_ns or time.perf_counter_ns()
+
+    def _launch_span(self, name):
+        """The ``serving.*.dispatch`` span of a launch.  The first launch
+        after the device drained opens it with ``gap_ns``, the host time
+        since the drained stamp, in which the device had nothing to run."""
+        if not self._drained_ns:
+            return span(name)
+        gap = time.perf_counter_ns() - self._drained_ns
+        self._drained_ns = 0
+        return span(name, gap_ns=gap)
 
     def register_adapter(self, tenant, factors):
         """Stage ``tenant``'s LoRA factors host-side (see
@@ -1486,7 +1506,7 @@ class LLMEngine(_RequestLifecycle):
             pname = f"serving.{self._prog_key('prefill_paged')}[c{C}]"
             self._maybe_capture(pname, pf, *pargs)
             self._maybe_audit(pname, pf, *pargs, donate_argnums=dn)
-        with span("serving.prefill.dispatch"):
+        with self._launch_span("serving.prefill.dispatch"):
             _dt = _devicetime.note(pname)
             if self._state_names:
                 self._pk, self._pv, self._st, tok, new_key = pf(*pargs)
@@ -1507,29 +1527,33 @@ class LLMEngine(_RequestLifecycle):
             del self._prefill_state[slot]
             counters.inc("serving.prefill_batches")
             # only the last chunk's sample is consumed: the one read-back
-            with span("serving.prefill.wait"):
+            with span("serving.prefill.wait") as w:
                 tok, new_key = int(tok), np.asarray(new_key)
-            with self._cond:
-                self._write_slot(
-                    slot, tok=tok, pos=T, keys=new_key,
-                    temp=req.temperature, topk=req.top_k, topp=req.top_p,
-                    dosample=req.do_sample, running=not req.hold)
-            if req.hold:
-                # disaggregated hand-off point: the row parks instead of
-                # entering decode — _running stays False so the decode
-                # launch tables it to the trash block — until the fleet
-                # migrates its block table to a decode replica.  The
-                # first token was already sampled by the final chunk, so
-                # it is emitted here (TTFT is a prefill-side metric);
-                # _emit may finish the request (EOS / max_new == 1), in
-                # which case there is nothing left to migrate.
-                req.state = "held"
-                self._emit(req, tok, events)
-                if req.state == "held":
-                    events.append({"type": "prefilled", "request": req})
-            else:
-                req.state = "running"
-                self._emit(req, tok, events)
+            self._drained(w)
+            with span("serving.prefill.emit"):
+                with self._cond:
+                    self._write_slot(
+                        slot, tok=tok, pos=T, keys=new_key,
+                        temp=req.temperature, topk=req.top_k,
+                        topp=req.top_p, dosample=req.do_sample,
+                        running=not req.hold)
+                if req.hold:
+                    # disaggregated hand-off point: the row parks instead
+                    # of entering decode — _running stays False so the
+                    # decode launch tables it to the trash block — until
+                    # the fleet migrates its block table to a decode
+                    # replica.  The first token was already sampled by
+                    # the final chunk, so it is emitted here (TTFT is a
+                    # prefill-side metric); _emit may finish the request
+                    # (EOS / max_new == 1), in which case there is
+                    # nothing left to migrate.
+                    req.state = "held"
+                    self._emit(req, tok, events)
+                    if req.state == "held":
+                        events.append({"type": "prefilled", "request": req})
+                else:
+                    req.state = "running"
+                    self._emit(req, tok, events)
 
     def _prefill_chunks(self, events):
         """One chunk per prefilling slot per step (round-robin in slot
@@ -1559,12 +1583,13 @@ class LLMEngine(_RequestLifecycle):
                   if r is not None and r.state == "running"]
         if not active:
             return
-        self._observe("serving.decode_occupancy",
-                      len(active) / self.max_slots)
         with span("serving.decode.operands"):
-            t0 = time.perf_counter()
+            # one clock pair a launch: the tokens/s EMA, the request
+            # trace's decode.iter and, at the read-back, the drained stamp
+            t0 = time.perf_counter_ns()
+            self._observe("serving.decode_occupancy",
+                          len(active) / self.max_slots)
             tr_on = rtrace.enabled()
-            t0_tr = time.perf_counter_ns() if tr_on else 0
             dec = self._pdecode()
             # on most launches no slot changed hands since the last one:
             # every operand is then a device array that launch left, and
@@ -1586,7 +1611,7 @@ class LLMEngine(_RequestLifecycle):
             dname = f"serving.{self._prog_key('decode_paged')}"
             self._maybe_capture(dname, dec, *dargs)
             self._maybe_audit(dname, dec, *dargs, donate_argnums=dn)
-        with span("serving.decode.dispatch"):
+        with self._launch_span("serving.decode.dispatch"):
             _dt = _devicetime.note(dname)
             if self._state_names:
                 (nxt, self._pk, self._pv, self._st, pos,
@@ -1601,23 +1626,24 @@ class LLMEngine(_RequestLifecycle):
                 # the program's own outputs are the next launch's operands
                 self._dev.update(tok=nxt, pos=pos, keys=keys)
                 self._keys_host = None
-        with span("serving.decode.wait"):    # the one read-back
+        with span("serving.decode.wait") as w:    # the one read-back
             nxt = np.asarray(nxt)
-        if tr_on:
-            t1_tr = time.perf_counter_ns()
-            for _s, r in active:
-                if r.trace is not None:
-                    r.trace.add_span("decode.iter", t0_tr, t1_tr,
-                                     batch=len(active))
-        # one token emitted per active slot this launch
-        self._note_decode(len(active), time.perf_counter() - t0)
-        counters.inc("serving.decode_steps")
-        counters.inc("serving.decode.sampled_steps", int(sampled))
-        counters.inc("serving.decode.upload_steps", int(uploaded))
-        counters.inc("serving.decode_tokens", len(active))
-        if self.kv_dtype:
-            counters.inc("serving.kv.quant.decode_tokens", len(active))
+        t1 = time.perf_counter_ns()
+        self._drained(w, t1)
         with span("serving.decode.emit"):
+            if tr_on:
+                for _s, r in active:
+                    if r.trace is not None:
+                        r.trace.add_span("decode.iter", t0, t1,
+                                         batch=len(active))
+            # one token emitted per active slot this launch
+            self._note_decode(len(active), (t1 - t0) * 1e-9)
+            counters.inc("serving.decode_steps")
+            counters.inc("serving.decode.sampled_steps", int(sampled))
+            counters.inc("serving.decode.upload_steps", int(uploaded))
+            counters.inc("serving.decode_tokens", len(active))
+            if self.kv_dtype:
+                counters.inc("serving.kv.quant.decode_tokens", len(active))
             # the mirrors of what the program carried forward itself
             for s, req in active:
                 self._tok[s] = nxt[s]
@@ -1955,21 +1981,23 @@ class LLMEngine(_RequestLifecycle):
             self._decode_step(events)
             with span("serving.admit"):
                 self._admit(events)
-            if sp.live:   # counted only for someone who is profiling
-                live = self._blocks_live()
-                sp.note(blocks_live=live, blocks_total=self.pool.capacity,
-                        kv_live_bytes=live * self._block_bytes,
-                        state_bytes=self._state_bytes)
-        counters.set_gauge(
-            "serving.slot_occupancy",
-            sum(r is not None for r in self._slots) / self.max_slots)
-        used = self.pool.used_blocks
-        counters.set_gauge("serving.kv.blocks_used", used)
-        self._observe("serving.kv.block_occupancy",
-                      used / max(1, self.pool.capacity))
-        if self._host_tier is not None:
-            counters.set_gauge("serving.kv.tier.host_blocks",
-                               self._host_tier.resident)
+            with span("serving.gauges"):
+                if sp.live:   # counted only for someone who is profiling
+                    live = self._blocks_live()
+                    sp.note(blocks_live=live,
+                            blocks_total=self.pool.capacity,
+                            kv_live_bytes=live * self._block_bytes,
+                            state_bytes=self._state_bytes)
+                counters.set_gauge(
+                    "serving.slot_occupancy",
+                    sum(r is not None for r in self._slots) / self.max_slots)
+                used = self.pool.used_blocks
+                counters.set_gauge("serving.kv.blocks_used", used)
+                self._observe("serving.kv.block_occupancy",
+                              used / max(1, self.pool.capacity))
+                if self._host_tier is not None:
+                    counters.set_gauge("serving.kv.tier.host_blocks",
+                                       self._host_tier.resident)
         return events
 
     def _blocks_live(self):

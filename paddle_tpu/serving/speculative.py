@@ -667,6 +667,10 @@ class SpeculativeLLMEngine(LLMEngine):
         dready = self._grow_draft_tables(nv)
         tr_on = rtrace.enabled()
         t0_tr = time.perf_counter_ns() if tr_on else 0
+        if self._drained_ns:
+            # the round's launches carry no gap_ns; they take the stamp a
+            # prefill read-back left, so that no later launch claims it
+            self._drained_ns = 0
         with span("serving.spec.round"):
             df = self._pdraft()
             op = self.arena.operand

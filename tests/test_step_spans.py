@@ -6,7 +6,10 @@ someone is profiling — a ``host_tracer.start()`` session or any
 ``engine.step()`` as ``serving.*`` children of ``serving.step``, with the
 KV pool's live blocks counted on the way out for whoever is profiling, and
 the request trace's own spans meaning what they meant; a ``name=`` on every
-Pallas kernel.  CPU only; nothing here is a timing.
+Pallas kernel.  Since PR 36 the first launch after a read-back carries the
+host time since the device drained (``gap_ns`` on its dispatch span, handed
+to the profiler's trace as well), in the paged and the block-decoding
+engine.  CPU only; nothing here is a timing.
 """
 
 import pathlib
@@ -26,9 +29,12 @@ ROOT = pathlib.Path(__file__).resolve().parent.parent
 
 STEP_CHILDREN = ["serving.sweep", "serving.admit",
                  "serving.prefill.operands", "serving.prefill.dispatch",
-                 "serving.prefill.wait", "serving.decode.operands",
-                 "serving.decode.dispatch", "serving.decode.wait",
-                 "serving.decode.emit"]
+                 "serving.prefill.wait", "serving.prefill.emit",
+                 "serving.decode.operands", "serving.decode.dispatch",
+                 "serving.decode.wait", "serving.decode.emit",
+                 "serving.gauges"]
+DISPATCHES = ("serving.prefill.dispatch", "serving.decode.dispatch")
+WAITS = ("serving.prefill.wait", "serving.decode.wait")
 
 
 @pytest.fixture(autouse=True)
@@ -148,6 +154,41 @@ class TestPrimitive:
         assert [e[0] for e in evts] == ["user_event"] and len(evts[0]) == 6
         assert not hasattr(profiler.RecordEvent("x"), "_ann")
 
+    def test_counts_given_at_the_open_reach_the_trace(self, monkeypatch):
+        """Under a (faked) profiler session a span opened with counts hands
+        them to its ``TraceAnnotation``; a later ``note()`` does not, and a
+        span opened without counts hands over nothing."""
+        built = []
+
+        class Annotation:
+            on = True
+
+            def __init__(self, name, **kw):
+                built.append((name, kw))
+
+            @classmethod
+            def is_enabled(cls):
+                return cls.on
+
+            def __enter__(self):
+                return self
+
+            def __exit__(self, *exc):
+                return False
+
+        monkeypatch.setattr(host_tracer, "TraceAnnotation", Annotation)
+        with host_tracer.span("serving.decode.dispatch", gap_ns=7) as sp:
+            sp.note(rows=3)
+        with host_tracer.span("serving.decode.wait"):
+            pass
+        assert built == [("serving.decode.dispatch", {"gap_ns": 7}),
+                         ("serving.decode.wait", {})]
+        Annotation.on = False                   # nobody profiling
+        del built[:]
+        with host_tracer.span("serving.decode.dispatch", gap_ns=7):
+            pass
+        assert built == []
+
     def test_chrome_trace_carries_counts(self):
         host_tracer.start()
         try:
@@ -245,9 +286,12 @@ def profiled(tmp_path_factory):
         jax.profiler.stop_trace()
     events = host_tracer.events()
     (pb,) = out.glob("plugins/profile/*/*.xplane.pb")
-    names = {ev.name for plane in ProfileData.from_file(str(pb)).planes
-             for line in plane.lines for ev in line.events}
-    return {"events": events, "off": off, "names": names}
+    written = [ev for plane in ProfileData.from_file(str(pb)).planes
+               for line in plane.lines for ev in line.events]
+    gaps = sorted(dict(ev.stats)["gap_ns"] for ev in written
+                  if ev.name in DISPATCHES and "gap_ns" in dict(ev.stats))
+    return {"events": events, "off": off,
+            "names": {ev.name for ev in written}, "gaps": gaps}
 
 
 class TestFollowsTheProfiler:
@@ -263,6 +307,12 @@ class TestFollowsTheProfiler:
                                       "serving.prefill.wait"])
     def test_names_appear_in_the_written_trace(self, profiled, name):
         assert name in profiled["names"]
+
+    def test_a_launch_gap_is_a_stat_of_the_written_event(self, profiled):
+        kept = sorted(e[5]["gap_ns"] for e in profiled["events"]
+                      if e[0] in DISPATCHES and e[5])
+        assert kept and profiled["gaps"] == kept
+        _check_launch_gaps(profiled["events"])
 
     def test_off_again_after_the_session(self, profiled):
         assert not host_tracer.enabled()
@@ -421,6 +471,138 @@ class TestEngineStep:
         for f in ("engine.py", "paged.py"):
             src = (ROOT / "paddle_tpu" / "serving" / f).read_text()
             assert "monotonic_ns" not in src
+
+
+# ---------------------------------------------------------------------------
+# the device's wait for the engine: gap_ns on the first launch after a
+# read-back
+# ---------------------------------------------------------------------------
+def _check_launch_gaps(events):
+    """Walk each thread's step-path spans in time order.  A dispatch after
+    a ``serving.*.wait`` (and no launch between) carries ``gap_ns``, no
+    more than the host time from that first wait's end to the dispatch and
+    no less than from the span after the wait to the span before the
+    dispatch; any other dispatch carries none.  Returns the number of
+    each."""
+    found = {"gap": 0, "queued": 0}
+    for tid in {e[1] for e in events}:
+        leaves = sorted((e for e in events if e[1] == tid
+                         and e[0] != "serving.step"), key=lambda e: e[2])
+        wait = None                       # first read-back since a launch
+        for i, e in enumerate(leaves):
+            if e[0] in WAITS and wait is None:
+                wait = e
+            if e[0] not in DISPATCHES:
+                continue
+            gap = (e[5] or {}).get("gap_ns")
+            if wait is None:
+                assert gap is None, e
+                found["queued"] += 1
+                continue
+            after = next(x for x in leaves if x[2] >= wait[3])
+            assert 0 <= gap <= e[2] - wait[3], (e, wait)
+            assert gap >= leaves[i - 1][3] - after[2], (e, wait)
+            found["gap"] += 1
+            wait = None
+    return found
+
+
+class TestLaunchGaps:
+    def test_paged_engine(self, paged_steps):
+        found = _check_launch_gaps(paged_steps["events"])
+        assert found["gap"] >= 4 and found["queued"] >= 1
+
+    def test_a_session_stamps_nothing_once_it_ends(self, paged_steps):
+        assert not host_tracer.enabled()
+        # the session's last read-back left a stamp; nothing consumed it
+        eng = _engine()
+        eng._drained_ns = 123
+        with eng._launch_span("serving.decode.dispatch") as sp:
+            assert sp is host_tracer.span("nobody profiling")
+        assert eng._drained_ns == 0
+
+    def test_nothing_without_a_session(self, monkeypatch):
+        from paddle_tpu.serving import LLMEngine
+        seen = []
+        real = LLMEngine._drained
+
+        def drained(self, sp, t_ns=None):
+            real(self, sp, t_ns)
+            seen.append(self._drained_ns)
+        monkeypatch.setattr(LLMEngine, "_drained", drained)
+        eng = _engine()
+        _warm(eng)
+        n = host_tracer.span_count()
+        _drain(eng, [eng.add_request(np.arange(1, 14, dtype=np.int32),
+                                     max_new_tokens=4, seed=0)])
+        assert seen and set(seen) == {0}        # never stamped
+        assert host_tracer.span_count() == n
+        assert eng._launch_span("serving.decode.dispatch") is \
+            host_tracer.span("x")
+
+
+# ---------------------------------------------------------------------------
+# the block-decoding engine: the same children, the same gaps
+# ---------------------------------------------------------------------------
+@pytest.fixture(scope="module")
+def block_steps():
+    """A tiny block-decoding engine (``tests/test_sdar.py``'s size): one
+    row decoding while a prompt of three chunks arrives, under a span
+    session."""
+    from paddle_tpu.models import sdar
+    from paddle_tpu.serving import LLMEngine
+    paddle.seed(7)
+    model = sdar.SdarMoeForCausalLM(sdar.SdarConfig(
+        vocab_size=512, hidden_size=64, moe_intermediate_size=32,
+        num_layers=2, num_heads=8, num_kv_heads=2, head_dim=16,
+        num_experts=16, num_experts_per_tok=8, max_seq_len=512,
+        mask_token_id=500, initializer_range=0.1))
+    model.eval()
+    eng = LLMEngine(model, block_size=16, max_slots=3, max_seq_len=128,
+                    n_blocks=25, prefill_chunk=32, min_bucket=16)
+    long_prompt = np.arange(1, 71, dtype=np.int32) % 400      # 32, 32, 4
+    _drain(eng, [eng.add_request(long_prompt, max_new_tokens=4, seed=0)])
+    host_tracer.start()
+    try:
+        a = eng.add_request(np.arange(3, 9, dtype=np.int32),
+                            max_new_tokens=12, seed=0)
+        eng.step()
+        eng.step()
+        b = eng.add_request(long_prompt + 1, max_new_tokens=4, seed=0)
+        _drain(eng, [a, b])
+    finally:
+        events = host_tracer.stop()
+    return {"events": events, "engine": eng}
+
+
+class TestBlockDecodeSteps:
+    def test_children_and_the_order_of_a_launch(self, block_steps):
+        steps = _steps_with_children(block_steps["events"])
+        names = {k[0] for _, kids in steps for k in kids}
+        assert names == set(STEP_CHILDREN)
+        decoding = [kids for _, kids in steps
+                    if any(k[0] == "serving.decode.wait" for k in kids)]
+        assert decoding
+        for kids in decoding:
+            order = [k[0] for k in sorted(kids, key=lambda e: e[2])
+                     if k[0].startswith("serving.decode")]
+            assert order == ["serving.decode.operands",
+                             "serving.decode.dispatch",
+                             "serving.decode.wait",
+                             "serving.decode.emit"]
+
+    def test_launch_gaps(self, block_steps):
+        events = block_steps["events"]
+        found = _check_launch_gaps(events)
+        assert found["gap"] >= 4
+        # a decode launch right behind a chunk that was not the prompt's
+        # last: no read-back between them, so no gap
+        leaves = sorted((e for e in events if e[0] in DISPATCHES + WAITS),
+                        key=lambda e: e[2])
+        behind = [b for a, b in zip(leaves, leaves[1:])
+                  if a[0] == "serving.prefill.dispatch"
+                  and b[0] == "serving.decode.dispatch"]
+        assert behind and all(b[5] is None for b in behind)
 
 
 # ---------------------------------------------------------------------------
