@@ -38,6 +38,10 @@ Well-known names (see README "Observability" for the full table):
   serving.decode.upload_steps (decode launches that uploaded at least
       one per-slot operand: a slot changed hands since the launch
       before; the others took every operand from the device)
+  serving.decode.overlapped_steps (decode launches enqueued while the
+      launch before them was not yet read back: the paged engine reads
+      launch N's tokens after it enqueues N+1; registered at 0 by the
+      block-decoding and speculative engines, which never overlap)
   serving.moe.assignments / serving.moe.tokens ((token, held expert)
       pairs computed / tokens routed by an expert model's layers; kept on
       the device by the serving programs, fetched by

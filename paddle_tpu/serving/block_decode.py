@@ -370,6 +370,8 @@ class BlockDecodeLLMEngine(LLMEngine):
             counters.inc("serving.decode_steps")
             counters.inc("serving.decode.sampled_steps", int(sampled))
             counters.inc("serving.decode.upload_steps", int(uploaded))
+            # each launch is read back before the next is made
+            counters.inc("serving.decode.overlapped_steps", 0)
             counters.inc("serving.decode_tokens", emitted)
             counters.inc("serving.diffusion.row_passes", len(active))
             counters.inc("serving.diffusion.commits", commits)

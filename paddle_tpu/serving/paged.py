@@ -55,6 +55,19 @@ chunk, a finish, a spill or restore, an adoption).  A launch on which no
 slot changed hands makes no host-to-device copy and one read-back, the
 tokens (``serving.decode.upload_steps`` counts the others).
 
+That read-back waits one launch: a step enqueues decode launch N+1 from
+the device arrays launch N left, and only then reads N's tokens back and
+emits them, so the read-back's latency and the host's turn between two
+steps run while the device works on N+1.  At most one launch is unread
+(``_inflight``); the host mirrors of tokens and positions are those of the
+last launch read.  A step reads the launch in flight back FIRST (settles,
+``_settle``) when the next launch must upload an operand, since an upload
+from the mirrors would roll the other rows back one launch, or when a row
+gets its last token from the launch in flight, which must not run once
+more; ``serving.decode.overlapped_steps`` counts the launches enqueued
+behind an unread one.  Each token stream is what it was; the step that
+returns a token is the one after its launch.
+
 The request's own life (queue, deadlines, cancellation, the finish
 compare-and-set, ``drain``) is ``serving.engine._RequestLifecycle``,
 which this class extends; ``serving.speculative`` extends this one.
@@ -149,6 +162,19 @@ def _sample_and_carry(mesh, logits, running, tok, pos, keys_data, do_sample,
         rep = NamedSharding(mesh, PartitionSpec())
         out = tuple(jax.lax.with_sharding_constraint(x, rep) for x in out)
     return out
+
+
+class _Launch:
+    """A decode launch the host has not read back: the rows it ran
+    (``(slot, request)`` as the host saw them at the dispatch), its tokens,
+    what it carries to the next launch (``tok``, ``pos``, ``keys``), the
+    instant its operands began, and the engine's launch count after it."""
+
+    __slots__ = ("rows", "nxt", "carried", "t0", "seq")
+
+    def __init__(self, rows, nxt, carried, t0, seq):
+        self.rows, self.nxt, self.carried = rows, nxt, carried
+        self.t0, self.seq = t0, seq
 
 
 class LLMEngine(_RequestLifecycle):
@@ -408,11 +434,20 @@ class LLMEngine(_RequestLifecycle):
         self._aid = np.zeros(B, np.int32)
         self._dev = {}
         self._stale = set(_DECODE_OPERANDS)
+        # the decode launch not yet read back (a _Launch), the instant of
+        # the last read-back, and the list the last step() returned (a
+        # read-back between steps adds its tokens' events there)
+        self._inflight = None
+        self._read_ns = 0
+        self._events = []
         self._slot_blocks = [None] * B
         self._prefill_state = {}      # slot -> {"req": Request, "done": n}
         # perf_counter_ns at which a read-back left the device with nothing
-        # the engine queued; 0 once a launch took it (or nobody profiles)
+        # the engine queued; 0 once a launch took it (or nobody profiles);
+        # launches the engine made, to tell whether one is queued behind a
+        # read-back
         self._drained_ns = 0
+        self._launches = 0
         self._pchunk_jits = {}        # chunk bucket -> jitted prefill
         self._pdecode_jit = None
         self._pcopy_jit = None
@@ -541,8 +576,10 @@ class LLMEngine(_RequestLifecycle):
     def _decode_operands(self):
         """The decode launch's per-slot operands, in the program's
         order, and whether any had to be uploaded: only the arrays that
-        _write_slot marked since the last launch are; the rest are the
-        device arrays of the launch before."""
+        _write_slot marked since the last launch are (the caller has read
+        any launch in flight back first); the rest are the device arrays
+        of the launch before, and what a launch in flight carries is its
+        own outputs."""
         names = self._operand_names
         with self._cond:
             stale, self._stale = self._stale.intersection(names), set()
@@ -550,15 +587,22 @@ class LLMEngine(_RequestLifecycle):
             fresh = {n: np.array(getattr(self, "_" + n)) for n in stale}
         for n, value in fresh.items():
             self._dev[n] = self.arena.operand(value)
-        ops = [self._dev[n] for n in names]
+        dev = self._dev
+        if self._inflight is not None:
+            dev = {**dev, **self._inflight.carried}
+        ops = [dev[n] for n in names]
         if self.adapters is not None:
             ops.insert(-1, self.adapters.slabs())
         return tuple(ops), bool(fresh)
 
     def release_kv(self):
         """Drop the device KV storage (a dead replica's arena is garbage
-        — the fleet frees its HBM before respawning)."""
+        — the fleet frees its HBM before respawning), and the launch in
+        flight with it.  The requests those two hold reference the engine,
+        so they go too: refcounting alone then frees an engine dropped
+        after this."""
         self._pk = self._pv = self._sk = self._sv = self._st = None
+        self._inflight, self._events = None, []
         if self.adapters is not None:
             self.adapters.release_slabs()
 
@@ -575,9 +619,11 @@ class LLMEngine(_RequestLifecycle):
         return out
 
     def _drained(self, sp, t_ns=None):
-        """A blocking read-back under the wait span ``sp`` returned: the
-        device holds nothing the engine queued.  Stamped only for someone
-        who profiles, and only the first time since the last launch."""
+        """A blocking read-back under the wait span ``sp`` returned and
+        the device holds nothing the engine queued (the caller knows: a
+        read-back with a launch queued behind it is no drain).  Stamped
+        only for someone who profiles, and only the first time since the
+        last launch."""
         if sp.live and not self._drained_ns:
             self._drained_ns = t_ns or time.perf_counter_ns()
 
@@ -585,6 +631,7 @@ class LLMEngine(_RequestLifecycle):
         """The ``serving.*.dispatch`` span of a launch.  The first launch
         after the device drained opens it with ``gap_ns``, the host time
         since the drained stamp, in which the device had nothing to run."""
+        self._launches += 1
         if not self._drained_ns:
             return span(name)
         gap = time.perf_counter_ns() - self._drained_ns
@@ -1579,22 +1626,31 @@ class LLMEngine(_RequestLifecycle):
 
     # -- decode over block tables --------------------------------------------
     def _decode_step(self, events):
+        flight = self._inflight
+        if flight is not None and (self._must_upload()
+                                   or self._leaves_with(flight)):
+            # the mirrors lag the launch in flight by one: read it back
+            # before one of them goes up, and before a row that gets its
+            # last token from it would run once more
+            self._settle(events)
         active = [(s, r) for s, r in enumerate(self._slots)
                   if r is not None and r.state == "running"]
         if not active:
+            self._settle(events)
             return
         with span("serving.decode.operands"):
-            # one clock pair a launch: the tokens/s EMA, the request
-            # trace's decode.iter and, at the read-back, the drained stamp
+            # one clock pair a launch: this one, and at its read-back the
+            # other (the tokens/s EMA, the request trace's decode.iter,
+            # the drained stamp)
             t0 = time.perf_counter_ns()
             self._observe("serving.decode_occupancy",
                           len(active) / self.max_slots)
-            tr_on = rtrace.enabled()
             dec = self._pdecode()
             # on most launches no slot changed hands since the last one:
             # every operand is then a device array that launch left, and
             # nothing is uploaded
             tail, uploaded = self._decode_operands()
+            behind = self._inflight is not None
             sampled = bool((self._dosample & self._running).any())
             if self._state_names:
                 # the rows' recurrent state rides next to the pools; a row
@@ -1611,6 +1667,10 @@ class LLMEngine(_RequestLifecycle):
             dname = f"serving.{self._prog_key('decode_paged')}"
             self._maybe_capture(dname, dec, *dargs)
             self._maybe_audit(dname, dec, *dargs, donate_argnums=dn)
+            counters.inc("serving.decode_steps")
+            counters.inc("serving.decode.sampled_steps", int(sampled))
+            counters.inc("serving.decode.upload_steps", int(uploaded))
+            counters.inc("serving.decode.overlapped_steps", int(behind))
         with self._launch_span("serving.decode.dispatch"):
             _dt = _devicetime.note(dname)
             if self._state_names:
@@ -1622,32 +1682,76 @@ class LLMEngine(_RequestLifecycle):
             else:
                 nxt, self._pk, self._pv, pos, keys = dec(*dargs)
             _devicetime.observe(_dt, nxt)
-            with self._cond:
-                # the program's own outputs are the next launch's operands
-                self._dev.update(tok=nxt, pos=pos, keys=keys)
-                self._keys_host = None
+            # the program's own outputs are the next launch's operands
+            launch = _Launch(active, nxt, {"tok": nxt, "pos": pos,
+                                           "keys": keys},
+                             t0, self._launches)
+        # this launch is queued: now the one before it is read back
+        self._settle(events)
+        self._inflight = launch
+
+    def _must_upload(self):
+        """Whether the next decode launch uploads an operand."""
+        with self._cond:
+            return not self._stale.isdisjoint(self._operand_names)
+
+    def _leaves_with(self, flight):
+        """Whether a row of the launch in flight gets its request's last
+        token from it (a length finish, which the host knows a launch
+        ahead; an end-of-sequence token it learns at the read-back)."""
+        return any(self._slots[s] is r and r.state == "running"
+                   and len(r.tokens) + 1 >= r.max_new_tokens
+                   for s, r in flight.rows)
+
+    def _settle(self, events):
+        """Read the decode launch in flight back and emit its tokens into
+        ``events``; nothing to do when none is in flight.  The one place a
+        launch's tokens reach the host.  A row's mirrors move on and it
+        emits only where the request the launch ran it for still holds
+        the slot and runs: a row whose request finished at the launch
+        before (an end-of-sequence token) ran once more, past its live
+        positions, and that token is dropped.  The read-back stamps the
+        drain only when no launch of the engine is queued behind it."""
+        flight, self._inflight = self._inflight, None
+        if flight is None:
+            return
         with span("serving.decode.wait") as w:    # the one read-back
-            nxt = np.asarray(nxt)
+            nxt = np.asarray(flight.nxt)
         t1 = time.perf_counter_ns()
-        self._drained(w, t1)
+        if flight.seq == self._launches:
+            self._drained(w, t1)
         with span("serving.decode.emit"):
-            if tr_on:
-                for _s, r in active:
-                    if r.trace is not None:
-                        r.trace.add_span("decode.iter", t0, t1,
-                                         batch=len(active))
-            # one token emitted per active slot this launch
-            self._note_decode(len(active), (t1 - t0) * 1e-9)
-            counters.inc("serving.decode_steps")
-            counters.inc("serving.decode.sampled_steps", int(sampled))
-            counters.inc("serving.decode.upload_steps", int(uploaded))
-            counters.inc("serving.decode_tokens", len(active))
+            kept = [(s, r) for s, r in flight.rows
+                    if self._slots[s] is r and r.state == "running"]
+            with self._cond:
+                # what the launch carried forward is the mirrors' now
+                self._dev.update(flight.carried)
+                if self._keys_host is not None:
+                    if "keys" in self._stale:
+                        # a row written since the dispatch keeps its key
+                        moved = np.asarray(flight.carried["keys"])
+                        for s, _ in kept:
+                            self._keys_host[s] = moved[s]
+                    else:
+                        self._keys_host = None
+                for s, _ in kept:
+                    self._tok[s] = nxt[s]
+                    self._pos[s] += 1
+            # the launch's time: from its operands, or from the read-back
+            # before it if it was queued behind that launch
+            t0 = max(flight.t0, self._read_ns)
+            self._read_ns = t1
+            if kept:
+                if rtrace.enabled():
+                    for _s, r in kept:
+                        if r.trace is not None:
+                            r.trace.add_span("decode.iter", t0, t1,
+                                             batch=len(kept))
+                self._note_decode(len(kept), (t1 - t0) * 1e-9)
+            counters.inc("serving.decode_tokens", len(kept))
             if self.kv_dtype:
-                counters.inc("serving.kv.quant.decode_tokens", len(active))
-            # the mirrors of what the program carried forward itself
-            for s, req in active:
-                self._tok[s] = nxt[s]
-                self._pos[s] += 1
+                counters.inc("serving.kv.quant.decode_tokens", len(kept))
+            for s, req in kept:
                 self._emit(req, nxt[s], events)
 
     # -- KV migration (disaggregated prefill/decode fleet) -------------------
@@ -1893,7 +1997,12 @@ class LLMEngine(_RequestLifecycle):
         (``cache_spec()["step_state"]``: an expert model's load counts),
         fetched to the host: ``{name: array}``, empty for a model that
         names none.  The one place they are read (a decode launch keeps
-        its one read-back); to be called between steps."""
+        its one read-back); to be called between steps.  A decode launch
+        in flight is read back first, so that the arrays count what the
+        tokens handed out so far were made with; its tokens join the
+        events of the step that launched it (the list it returned)."""
+        if self._step_spec:
+            self._settle(self._events)
         return {name: np.asarray(self.arena.get("state." + name))
                 for name in self._step_spec}
 
@@ -1964,11 +2073,29 @@ class LLMEngine(_RequestLifecycle):
         return done
 
     # -- scheduling ----------------------------------------------------------
+    def _sweep(self, events):
+        """The lifecycle's sweep; a row it evicts while a decode launch is
+        in flight first gets that launch's token, as it would have had the
+        launch been read back in the step that made it."""
+        flight = self._inflight
+        if flight is not None:
+            now = time.monotonic()
+            if any(r._cancel or (r.deadline is not None and now > r.deadline)
+                   for _, r in flight.rows):
+                self._settle(events)
+        super()._sweep(events)
+
+    def has_work(self):
+        """Queued or slotted requests, or a decode launch still to read
+        back."""
+        return self._inflight is not None or super().has_work()
+
     def step(self):
         """One scheduler iteration: sweep cancels/deadlines, admit from
         the queue (prefix match + block reservation only — no model
-        launches), advance every mid-prefill request by ONE chunk, run
-        ONE decode launch for all running slots, re-admit into anything
+        launches), advance every mid-prefill request by ONE chunk, enqueue
+        ONE decode launch for all running slots and read the one before
+        it back (its tokens are this step's), re-admit into anything
         freed this step."""
         with span("serving.step") as sp:
             events = []
@@ -1998,6 +2125,7 @@ class LLMEngine(_RequestLifecycle):
                 if self._host_tier is not None:
                     counters.set_gauge("serving.kv.tier.host_blocks",
                                        self._host_tier.resident)
+        self._events = events
         return events
 
     def _blocks_live(self):

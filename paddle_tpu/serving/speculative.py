@@ -752,6 +752,8 @@ class SpeculativeLLMEngine(LLMEngine):
         counters.inc("serving.decode.sampled_steps", int(ds_eff.any()))
         # a round uploads its rows' state from the host mirrors, every time
         counters.inc("serving.decode.upload_steps")
+        # and reads its tokens back before the next round
+        counters.inc("serving.decode.overlapped_steps", 0)
         emitted = int(sum(int(n_emit[s]) for s, _ in active))
         self._note_decode(emitted, time.perf_counter() - t0)
         counters.inc("serving.decode_tokens", emitted)

@@ -347,6 +347,11 @@ def run():
     violations.update({f"paged:{k}": (psteady.get(k, 0), want)
                        for k, want in pinvariants.items()
                        if psteady.get(k, 0) != want})
+    # a launch on which no slot changed hands is enqueued before the one
+    # in flight is read back
+    if not psteady.get("serving.decode.overlapped_steps", 0) > 0:
+        violations["paged:serving.decode.overlapped_steps"] = (
+            psteady.get("serving.decode.overlapped_steps", 0), ">0")
     for h in phs:   # paged output must equal sequential generate
         pref = np.asarray(smodel.generate(
             paddle.to_tensor(np.asarray([list(h.prompt)])),

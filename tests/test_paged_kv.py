@@ -917,7 +917,7 @@ class TestResidentDecodeState:
         dst.step()                     # the launch that sees the adoption
         d = counters.delta(before)
         assert (d[_STEPS], d[_UPLOADS]) == (1, 1)
-        assert len(new.tokens) == 2
+        assert len(new.tokens) == 1    # its token is the next step's
         _device_is_the_host(dst)
         _run(dst, [new, busy])
         assert list(new.tokens) == _alone(kind, ph, **kw)
@@ -1012,11 +1012,11 @@ class TestUploadStepsCounter:
         short = eng.add_request(rng.integers(0, 64, size=4).tolist(),
                                 max_new_tokens=3)
         eng.step()                      # admitted, prefilled, first launch
+        assert ups() == 1 and len(short.tokens) == 1
+        eng.step()                      # its second token
         assert ups() == 1 and len(short.tokens) == 2
-        eng.step()                      # its last token: the row finishes
-        assert ups() == 1 and short.is_finished
-        eng.step()                      # the launch that sees the row gone
-        assert ups() == 2
+        eng.step()                      # its last: the row finishes, and
+        assert ups() == 2 and short.is_finished    # the launch is without it
         eng.step()
         assert ups() == 2 and not long.is_finished
         assert counters.delta(before)[_STEPS] == 5

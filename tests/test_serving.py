@@ -179,7 +179,9 @@ class TestSlots:
         h.deadline = 0.0  # force expiry; next sweep evicts
         _run(eng, [h])
         assert h.finish_reason == "deadline"
-        assert len(h.tokens) == first  # sweep runs before decode
+        # sweep runs before decode: the one launch made before the
+        # deadline is read back first, and no launch follows it
+        assert len(h.tokens) == first + 1
         assert eng.stats()["free_slots"] == eng.max_slots
 
     def test_cancel_active_and_queued(self):
