@@ -41,7 +41,9 @@ them from what the code can observe (the house pattern of
 Routing is DeepSeek-V2's group-limited greedy top-k (arXiv:2405.04434,
 section 2.2.2): softmax over the experts in float32, the best
 ``topk_group`` of ``n_group`` groups by their largest probability, the top
-``top_k`` probabilities of what is left, not renormalised.
+``top_k`` probabilities of what is left, not renormalised; or sigmoid
+scores with a per-expert bias that chooses and does not weigh
+(:func:`biased_sigmoid_top_k`).
 """
 
 from __future__ import annotations
@@ -91,6 +93,20 @@ def group_limited_top_k(logits, n_group, topk_group, top_k):
     kept = (groups[:, :, None] == jnp.arange(n_group)).any(1)
     p = jnp.where(jnp.repeat(kept, E // n_group, axis=1), p, 0.0)
     return _top_k(p, top_k)
+
+
+def biased_sigmoid_top_k(logits, bias, top_k, scale):
+    """``logits [N, E]``, ``bias [E]`` -> ``(weight [N, top_k] float32,
+    expert [N, top_k] int32)``: sigmoid scores in float32, the experts
+    CHOSEN by the top ``top_k`` of score plus bias (ties to the lower
+    index) and WEIGHED by their scores alone, renormalised over the chosen
+    and times ``scale`` (auxiliary-loss-free balancing, arXiv:2408.15664:
+    the bias steers the choice and never the sum)."""
+    sigma = jax.nn.sigmoid(logits.astype(jnp.float32))
+    _, expert = _top_k(sigma + bias.astype(jnp.float32), top_k)
+    chosen = jnp.take_along_axis(sigma, expert, -1)
+    return (scale * chosen / (chosen.sum(-1, keepdims=True) + 1e-20),
+            expert)
 
 
 def kernel_mode(D, F, dtype):

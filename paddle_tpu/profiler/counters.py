@@ -58,6 +58,9 @@ Well-known names (see README "Observability" for the full table):
       serving/block_decode.py: running rows of each decode launch, one
       pass of a row's block each / blocks committed / masked positions
       revealed; serving.decode_tokens counts tokens EMITTED there)
+  serving.kv.window_blocks_recycled (an engine of a model with window
+      layers alone: window-ring entries a row took over as it passed the
+      window, counted at each chunk and each decode read-back)
   serving.retraces (serving program compiles; 0 in steady state)
   serving.queue_wait_ns
   serving.deadline_expired (queued past-deadline, evicted pre-prefill)

@@ -26,7 +26,7 @@ from .block_decode import BlockDecodeLLMEngine  # noqa: F401
 from .engine import (BlockDecodeUnsupported,  # noqa: F401
                      EngineBackpressure, EngineClosed,
                      LatentCacheUnsupported, RecurrentStateUnsupported,
-                     Request, bucket_length)
+                     Request, WindowCacheUnsupported, bucket_length)
 from .fleet import FleetRequest, Replica, ServingFleet  # noqa: F401
 from .kvcache import (BlockPool, BlockPoolExhausted,  # noqa: F401
                       PrefixCache, blocks_for_tokens)
@@ -38,7 +38,8 @@ from .speculative import SpeculativeLLMEngine  # noqa: F401
 __all__ = ["LLMEngine", "SpeculativeLLMEngine", "BlockDecodeLLMEngine",
            "Request", "EngineBackpressure", "EngineClosed",
            "RecurrentStateUnsupported", "LatentCacheUnsupported",
-           "BlockDecodeUnsupported", "bucket_length",
+           "WindowCacheUnsupported", "BlockDecodeUnsupported",
+           "bucket_length",
            "filter_logits", "sample_tokens", "residual_sample",
            "ServingFleet", "FleetRequest", "Replica", "FleetAutoscaler",
            "Router", "RetryAfter", "BlockPool", "BlockPoolExhausted",

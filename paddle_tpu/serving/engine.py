@@ -8,7 +8,8 @@ token per step regardless of arrival time, and evicted on EOS /
 rehandable.  This module holds that half and nothing of the device: the
 ``Request`` handle, the refusals (``EngineBackpressure``,
 ``EngineClosed``, ``RecurrentStateUnsupported``,
-``LatentCacheUnsupported``, ``BlockDecodeUnsupported``), and
+``LatentCacheUnsupported``, ``WindowCacheUnsupported``,
+``BlockDecodeUnsupported``), and
 ``_RequestLifecycle`` — the bounded queue and ``add_request``, the finish
 compare-and-set, the sweep of cancelled and late requests, token
 emission with its TTFT / ITL histograms, ``generate`` / ``drain`` and
@@ -88,6 +89,18 @@ class LatentCacheUnsupported(RuntimeError):
     ``draft_model=``, and by ``export_request`` / ``adopt_migration``
     (the prefix cache resolves to off instead: its copy-on-write clone
     copies blocks by head)."""
+
+
+class WindowCacheUnsupported(RuntimeError):
+    """Refused for a model whose window layers keep only the last positions
+    of a row (``cache_spec()["window"]``): the engine holds them in a second
+    pool whose blocks a row reuses as a ring, and the feature stores,
+    shards, copies or adopts K/V blocks of one pool, and would serve such
+    a model silently wrong.  Raised at construction for ``kv_dtype=``,
+    ``host_kv_blocks=``, ``adapter_slots=``, ``mesh=`` and
+    ``draft_model=``, and by ``export_request`` / ``adopt_migration``
+    (the prefix cache resolves to off instead: a hit would adopt blocks of
+    the full layers only)."""
 
 
 class BlockDecodeUnsupported(RuntimeError):

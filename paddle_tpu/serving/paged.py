@@ -87,6 +87,7 @@ from jax.sharding import NamedSharding, PartitionSpec
 
 from ..kernels import mla_attention as _mla
 from ..kernels import paged_attention as _pa
+from ..kernels import window_attention as _wa
 from ..profiler import counters
 from ..profiler import devicetime as _devicetime
 from ..profiler import flight
@@ -96,7 +97,8 @@ from ..profiler.host_tracer import span
 from .arena import StateArena
 from .engine import (EngineBackpressure, EngineClosed,
                      LatentCacheUnsupported, RecurrentStateUnsupported,
-                     Request, _RequestLifecycle, bucket_length)
+                     Request, WindowCacheUnsupported, _RequestLifecycle,
+                     bucket_length)
 from .kvcache import (TRASH_BLOCK, BlockPool, BlockPoolExhausted,
                       HostKVTier, HostTierLost, PrefixCache,
                       blocks_for_tokens)
@@ -210,6 +212,11 @@ class LLMEngine(_RequestLifecycle):
     * ``spill_idle_steps`` — scheduler steps a held request sits idle
       before its private KV spills to the host tier (default 0: held
       requests never spill).
+
+    A model with window layers (``cache_spec()["window"]``) gets a second
+    pool for them of ``max_slots * window_entries + 1`` blocks: each slot
+    owns ``window_entries = ceil((W + prefill_chunk) / bs) + 1`` of them,
+    which its row reuses as a ring as it advances past the window.
     """
 
     def __new__(cls, *args, **kw):
@@ -261,15 +268,21 @@ class LLMEngine(_RequestLifecycle):
         self.slot_state = dict(cache["slot_state"])
         self._step_spec = dict(cache.get("step_state", {}))
         self.kv_row = int(cache.get("kv_row", 0))
-        if self.slot_state or self.kv_row:
+        # window layers: their rows go to a second pool, a ring a row
+        self.window = dict(cache.get("window") or {})
+        if self.slot_state or self.kv_row or self.window:
             asked = {"kv_dtype=": kv_dtype is not None,
                      "host_kv_blocks=": int(host_kv_blocks or 0) > 0,
                      "adapter_slots=": int(adapter_slots or 0) > 0,
                      "mesh=": mesh is not None}
             if any(asked.values()):
                 refusal, what = (
-                    (RecurrentStateUnsupported,
-                     "keeps recurrent state per request") if self.slot_state
+                    (WindowCacheUnsupported,
+                     "keeps its window layers' K/V in a ring of blocks of "
+                     "a second pool") if self.window
+                    else (RecurrentStateUnsupported,
+                          "keeps recurrent state per request")
+                    if self.slot_state
                     else (LatentCacheUnsupported,
                           "caches one latent row per token, with no head "
                           "axis"))
@@ -279,7 +292,8 @@ class LLMEngine(_RequestLifecycle):
                     + " cannot carry yet")
             # a prefix hit adopts K/V blocks and would skip the tokens
             # that built the recurrent state; its copy-on-write clone
-            # copies a block by head, which a latent row has not
+            # copies a block by head, which a latent row has not; and it
+            # adopts blocks of one pool, where window layers keep a second
             prefix_cache = False
         S = int(max_seq_len or c.max_seq_len)
         if not c.use_rope and S > c.max_seq_len:
@@ -322,6 +336,7 @@ class LLMEngine(_RequestLifecycle):
 
     def _init_kv(self, c, B, S, nh, hd, dt):
         bs = self.block_size
+        self.window_entries, self._wblock_bytes = 0, 0
         if not 1 <= bs <= S:
             raise ValueError(f"block_size {bs} outside [1, {S}]")
         self.max_blocks = blocks_for_tokens(S, bs)
@@ -343,8 +358,8 @@ class LLMEngine(_RequestLifecycle):
             nhp, hd = 1, _mla.pool_row(self.kv_row)
             self.arena.declare(
                 "pool_k", jnp.zeros((L, self.n_blocks, bs, hd), adt))
-            self.arena.declare("pool_v", None)
             self._block_bytes = L * bs * hd * jnp.dtype(adt).itemsize
+            self._init_window(B, bs, hd, adt)
         else:
             # one chip's pool stores whole (8, 128) tiles of heads where
             # that lets the block-table walk run (30 heads of 128 as 32);
@@ -402,7 +417,9 @@ class LLMEngine(_RequestLifecycle):
         # holds, and baked into the program-cache key.  Pools left
         # replicated on a mesh (indivisible heads) keep the twin: GSPMD
         # partitions it, and cannot partition a Mosaic call
-        if self.kv_row:
+        if self.window:
+            self.kv_kernel = _wa.kernel_mode(c.head_dim, hd)
+        elif self.kv_row:
             self.kv_kernel = _mla.kernel_mode(c.num_heads, hd)
         elif self.arena.kv_head_axis:
             self.kv_kernel = _pa.kernel_mode(
@@ -421,7 +438,8 @@ class LLMEngine(_RequestLifecycle):
         # which names the array in ``_stale`` so that the next launch
         # uploads it again.  A launch with nothing stale uploads nothing.
         key_size = jax.random.key_data(jax.random.key(0)).shape[0]
-        self._bt = np.zeros((B, self.max_blocks), np.int32)
+        self._bt = np.zeros((B, self.max_blocks + self.window_entries),
+                            np.int32)
         self._tok = np.zeros(B, np.int32)
         self._pos = np.zeros(B, np.int32)
         self._running = np.zeros(B, np.bool_)
@@ -493,6 +511,28 @@ class LLMEngine(_RequestLifecycle):
         self.kv_pool_exhausted_events = 0
         self.kv_tier_spilled = 0
         self.kv_tier_restored = 0
+
+    def _init_window(self, B, bs, row, adt):
+        """The window layers' pool (``pool_v`` of a row cache, which has
+        no second pool otherwise) and each slot's ring in it: a chunk
+        reads keys from ``W - 1`` before its first position to its last,
+        so ``ceil((W + prefill_chunk) / bs) + 1`` entries hold every key
+        still needed while the row writes the next block into the entry
+        of the block ``window_entries`` behind it.  Slot ``s`` owns blocks
+        ``1 + s * n .. (s + 1) * n`` for good: no request ever waits for
+        the window pool, and nothing is allocated or freed in it."""
+        if not self.window:
+            self.arena.declare("pool_v", None)
+            return
+        n = self.window_entries = blocks_for_tokens(
+            self.window["size"] + self.prefill_chunk, bs) + 1
+        self._ring = (TRASH_BLOCK + 1
+                      + np.arange(B * n, dtype=np.int32).reshape(B, n))
+        Lw = int(self.window["layers"])
+        # every slot's whole ring, and the trash block
+        self.arena.declare("pool_v",
+                           jnp.zeros((Lw, B * n + 1, bs, row), adt))
+        self._wblock_bytes = Lw * bs * row * jnp.dtype(adt).itemsize
 
     # the block pools (+ scale pools) live in the StateArena; the
     # donated-program outputs rebind through the setters, so every
@@ -775,6 +815,10 @@ class LLMEngine(_RequestLifecycle):
         # trailing operands (never donated — the gather reads
         # them); donation indices are untouched
         lora = self.adapters is not None
+        # a model with window layers is told how many entries of each
+        # row's table are its ring
+        wkw = ({"window_entries": self.window_entries} if self.window
+               else {})
 
         if self._state_names:
             def pchunk(w, ids, start, length, bt, pk, pv, st, slot,
@@ -782,7 +826,7 @@ class LLMEngine(_RequestLifecycle):
                 counters.inc("serving.retraces")  # trace-time only
                 pk, pv, st, logits = model.prefill_paged(
                     w, ids, start, length, bt, pk, pv, st, slot,
-                    kernel=mode)
+                    kernel=mode, **wkw)
                 tok, new_key = LLMEngine._first_token(
                     logits, key_data, do_sample, temp, top_k, top_p)
                 return pk, pv, st, tok, new_key
@@ -846,6 +890,8 @@ class LLMEngine(_RequestLifecycle):
         # as arena.operand uploads them
         rep = self.arena.mesh
         lora = self.adapters is not None
+        wkw = ({"window_entries": self.window_entries} if self.window
+               else {})
 
         # every variant takes the rows' UNMASKED state and masks
         # it itself, and returns, after the tokens and the pools,
@@ -859,7 +905,7 @@ class LLMEngine(_RequestLifecycle):
                     running, bt, pos, do_sample)
                 logits, pk, pv, st = model.decode_paged(
                     w, tok, pos_e, bt_e, pk, pv, st, running,
-                    kernel=mode)
+                    kernel=mode, **wkw)
                 nxt, pos, keys_data = _sample_and_carry(
                     rep, logits, running, tok, pos, keys_data, ds_e,
                     temp, top_k, top_p)
@@ -1453,8 +1499,8 @@ class LLMEngine(_RequestLifecycle):
                 self.kv_prefix_misses += 1
                 counters.inc("serving.kv.prefix_misses")
             self._slot_blocks[slot] = table
-            self._write_slot(slot, bt=self._table_row(table), aid=aslot,
-                             running=False)
+            self._write_slot(slot, bt=self._table_row(table, slot),
+                             aid=aslot, running=False)
             req.state = "prefilling"
             req.slot = slot
             self._slots[slot] = req
@@ -1464,9 +1510,13 @@ class LLMEngine(_RequestLifecycle):
         events.append({"type": "admitted", "request": req})
         return True
 
-    def _table_row(self, table):
-        row = np.zeros(self.max_blocks, np.int32)
+    def _table_row(self, table, slot):
+        """Slot ``slot``'s table: its full-pool blocks, then (a model with
+        window layers) the slot's own ring in the window pool."""
+        row = np.zeros(self.max_blocks + self.window_entries, np.int32)
         row[:len(table)] = table
+        if self.window:
+            row[self.max_blocks:] = self._ring[slot]
         return row
 
     def _admit(self, events):
@@ -1567,6 +1617,13 @@ class LLMEngine(_RequestLifecycle):
             tr.add_span("prefill.chunk", t0_tr, time.perf_counter_ns(),
                         chunk=C, start=start, take=take_n)
         counters.inc("serving.kv.prefill_chunks")
+        if self.window:
+            # window-ring entries this chunk took over from a block the
+            # row had passed: blocks it began at or past the ring's end
+            bs = self.block_size
+            begun = max(-(-start // bs), self.window_entries)
+            counters.inc("serving.kv.window_blocks_recycled",
+                         max(0, (start + take_n - 1) // bs - begun + 1))
         if self.kv_dtype:
             counters.inc("serving.kv.quant.prefill_tokens", take_n)
         st["done"] = start + take_n
@@ -1734,6 +1791,13 @@ class LLMEngine(_RequestLifecycle):
                             self._keys_host[s] = moved[s]
                     else:
                         self._keys_host = None
+                if self.window:
+                    # a launch that wrote a block's first position at or
+                    # past the ring's end took over that entry
+                    bs, n = self.block_size, self.window_entries
+                    counters.inc("serving.kv.window_blocks_recycled", sum(
+                        self._pos[s] % bs == 0 and self._pos[s] // bs >= n
+                        for s, _ in kept))
                 for s, _ in kept:
                     self._tok[s] = nxt[s]
                     self._pos[s] += 1
@@ -1957,7 +2021,7 @@ class LLMEngine(_RequestLifecycle):
             self._slots[slot] = req
             self._slot_blocks[slot] = table
             self._write_slot(
-                slot, bt=self._table_row(table), aid=aslot, running=True,
+                slot, bt=self._table_row(table, slot), aid=aslot, running=True,
                 tok=int(mig["tok"]), pos=pos, keys=np.asarray(mig["key"]),
                 temp=req.temperature, topk=req.top_k, topp=req.top_p,
                 dosample=req.do_sample)
@@ -1982,6 +2046,10 @@ class LLMEngine(_RequestLifecycle):
         return req, info
 
     def _refuse_recurrent(self, what):
+        if self.window:
+            raise WindowCacheUnsupported(
+                f"{what} moves the blocks of one pool, and the request's "
+                "window layers live in a ring of a second")
         if self.slot_state:
             raise RecurrentStateUnsupported(
                 f"{what} moves K/V blocks and would leave the request's "
@@ -2111,10 +2179,19 @@ class LLMEngine(_RequestLifecycle):
             with span("serving.gauges"):
                 if sp.live:   # counted only for someone who is profiling
                     live = self._blocks_live()
+                    wlive, whole = self._window_blocks()
                     sp.note(blocks_live=live,
                             blocks_total=self.pool.capacity,
-                            kv_live_bytes=live * self._block_bytes,
+                            kv_live_bytes=(live * self._block_bytes
+                                           + wlive * self._wblock_bytes),
                             state_bytes=self._state_bytes)
+                    if self.window:
+                        sp.note(window_blocks_live=wlive,
+                                window_blocks_total=self._ring.size,
+                                window_kv_live_bytes=(
+                                    wlive * self._wblock_bytes),
+                                window_kv_unbounded_bytes=(
+                                    whole * self._wblock_bytes))
                 counters.set_gauge(
                     "serving.slot_occupancy",
                     sum(r is not None for r in self._slots) / self.max_slots)
@@ -2132,8 +2209,21 @@ class LLMEngine(_RequestLifecycle):
         """Distinct pool blocks in the tables of the requests that hold a
         slot.  ``pool.used_blocks`` also counts what the prefix tree
         retains after a request has finished; this does not."""
-        return int(np.count_nonzero(
-            np.bincount(self._bt.ravel(), minlength=1)[TRASH_BLOCK + 1:]))
+        return int(np.count_nonzero(np.bincount(
+            self._bt[:, :self.max_blocks].ravel(),
+            minlength=1)[TRASH_BLOCK + 1:]))
+
+    def _window_blocks(self):
+        """``(ring entries the requests that hold a slot can ever touch,
+        blocks they would touch if each kept its whole sequence)``: each
+        request's blocks, at most its ring, and all of them; ``(0, 0)``
+        for a model without window layers."""
+        if not self.window:
+            return 0, 0
+        need = [self._blocks_needed(int(r.prompt.shape[0]),
+                                    r.max_new_tokens)
+                for r in self._slots if r is not None]
+        return sum(min(b, self.window_entries) for b in need), sum(need)
 
     def stats(self):
         """The lifecycle's snapshot plus the block-pool / prefix-cache
@@ -2142,6 +2232,7 @@ class LLMEngine(_RequestLifecycle):
         with self._cond:
             st = super().stats()
             live = self._blocks_live()
+            wlive, _ = self._window_blocks()
             st.update({
                 "kv_dtype": self.kv_dtype,
                 "kv_kernel": self.kv_kernel,
@@ -2152,7 +2243,8 @@ class LLMEngine(_RequestLifecycle):
                 "blocks_free": self.pool.free_blocks,
                 "blocks_used": self.pool.used_blocks,
                 "blocks_live": live,
-                "kv_live_bytes": live * self._block_bytes,
+                "kv_live_bytes": (live * self._block_bytes
+                                  + wlive * self._wblock_bytes),
                 "state_bytes": self._state_bytes,
                 "prefix_cache": self.prefix is not None,
                 "block_utilization": (self.pool.used_blocks
@@ -2186,4 +2278,11 @@ class LLMEngine(_RequestLifecycle):
                 "adapters": (None if self.adapters is None
                              else self.adapters.stats()),
             })
+            if self.window:
+                st.update({
+                    "window_entries": self.window_entries,
+                    "window_blocks_total": self._ring.size,
+                    "window_blocks_live": wlive,
+                    "window_kv_live_bytes": wlive * self._wblock_bytes,
+                })
         return st

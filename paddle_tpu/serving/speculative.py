@@ -69,7 +69,7 @@ from ..profiler import flight
 from ..profiler import trace as rtrace
 from ..profiler.host_tracer import span
 from .engine import (BlockDecodeUnsupported, LatentCacheUnsupported,
-                     RecurrentStateUnsupported,
+                     RecurrentStateUnsupported, WindowCacheUnsupported,
                      bucket_length)
 from .kvcache import blocks_for_tokens
 from .paged import LLMEngine, _model_programs
@@ -183,6 +183,11 @@ class SpeculativeLLMEngine(LLMEngine):
             raise BlockDecodeUnsupported(
                 "draft_model= with a draft that decodes by blocks: a "
                 "proposal is one token a row and launch")
+        if any(m.cache_spec().get("window") for m in (model, draft)):
+            raise WindowCacheUnsupported(
+                "draft_model= with a target or a draft that has window "
+                "layers: verification rolls K/V back by position in one "
+                "pool, and their rows live in a ring of a second")
         if any(m.cache_spec().get("kv_row") for m in (model, draft)):
             raise LatentCacheUnsupported(
                 "draft_model= with a target or a draft that caches latent "

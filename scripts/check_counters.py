@@ -1822,6 +1822,45 @@ def run():
             (beng.stats()["prefix_cache"], [len(h.tokens) for h in bhs]),
             "(False, [3, 3])")
 
+    # ---- window gate: a ring of window blocks a row -----------------------
+    # A model with window layers (models/trinity.py) keeps their K/V in a
+    # second pool, at most ``window_entries`` blocks a row reused as a ring
+    # (``serving.kv.window_blocks_recycled`` counts the entries taken over
+    # as rows pass the window); the measure window must trace nothing, no
+    # earlier engine may have registered a ``serving.kv.window_*`` name,
+    # and both pools must be whole again once the requests finish.
+    from paddle_tpu.models.trinity import TrinityConfig, TrinityForCausalLM
+    if any(k.startswith("serving.kv.window_") for k in counters.snapshot()):
+        violations["window:registered_without_window"] = (True, False)
+    wmodel = TrinityForCausalLM(TrinityConfig(
+        vocab_size=64, hidden_size=32, intermediate_size=32,
+        moe_intermediate_size=16, num_layers=5, num_dense_layers=1,
+        num_heads=4, num_kv_heads=2, head_dim=8, sliding_window=8,
+        num_experts=8, num_experts_per_tok=2, max_seq_len=64))
+    wmodel.eval()
+    weng = LLMEngine(wmodel, max_slots=2, max_seq_len=32, min_bucket=4,
+                     block_size=4, prefill_chunk=8)
+    pserve(weng, (21, 25))
+    wbefore = counters.snapshot()
+    pserve(weng, (22, 26))
+    wsteady = counters.delta(wbefore)
+    # a ring of ceil((8 + 8) / 4) + 1 = 5 entries; the rows write up to
+    # positions 23 and 27 (3 new tokens): blocks 0-5 and 0-6, three
+    # entries taken over
+    want_window = {"serving.retraces": 0, "jit.traces": 0,
+                   "serving.kv.window_blocks_recycled": 3}
+    for k, want in want_window.items():
+        if wsteady.get(k, 0) != want:
+            violations[f"window:{k}"] = (wsteady.get(k, 0), want)
+    wst = weng.stats()
+    if not (wst["prefix_cache"] is False and weng.window_entries == 5
+            and wst["window_blocks_live"] == 0
+            and wst["blocks_free"] == wst["blocks_total"]):
+        violations["window:stats"] = (
+            (wst["prefix_cache"], weng.window_entries,
+             wst["window_blocks_live"], wst["blocks_free"]),
+            f"(False, 5, 0, {wst['blocks_total']})")
+
     result = {"metric": "steady_state_counter_violations",
               "value": len(violations),
               "unit": f"violations/{MEASURE} steps "

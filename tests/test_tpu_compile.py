@@ -259,13 +259,14 @@ def _experts_on_the_chip(monkeypatch):
     monkeypatch.setattr(moe, "on_tpu", lambda: True)
 
 
-def _held_products_are_the_kernel(text):
-    """Both grouped products of every expert layer are the Pallas kernel,
-    under the name the trace's readers look for, and the compiler's own
-    grouped matmul is gone."""
+def _held_products_are_the_kernel(text, layers=1):
+    """Both grouped products of every expert layer (``layers`` of them in
+    the program's text: a scan's body holds one period) are the Pallas
+    kernel, under the name the trace's readers look for, and the
+    compiler's own grouped matmul is gone."""
     assert len(re.findall(r"%ragged-dot-held[\w.]* = [^\n]*custom-call\("
                           r'[^\n]*custom_call_target="tpu_custom_call"',
-                          text)) == 2
+                          text)) == 2 * layers
     assert not re.search(r"%ragged-dot-(none|metadata)", text)
 
 
@@ -508,3 +509,102 @@ def test_block_prefill_chunk_compiles_in_place(chip, monkeypatch):
     assert mem.alias_size_in_bytes >= 6 * n_blocks * 16 * 1024 * 2, mem
     assert mem.argument_size_in_bytes < 10_200_000_000, mem   # no head
     assert mem.temp_size_in_bytes < 512 * 2 ** 20, mem
+
+
+# ---------------------------------------------------------------------------
+# Trinity at the longmix cell's shape: 32 rows, a full table of 4,160 blocks
+# of 16 tokens over 16,385 blocks (one full layer), a window ring of 385
+# entries over 12,321 blocks (four window layers), rows [k ; v] of 2,048
+# ---------------------------------------------------------------------------
+def _trinity(chip):
+    import json
+    from paddle_tpu.models import trinity as tr
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "trinity-large-preview.json")) as f:
+        hf = json.load(f)
+    real = tr.TrinityForCausalLM.__new__(tr.TrinityForCausalLM)
+    real.__dict__["config"] = c = tr.TrinityConfig.from_hf(
+        hf, experts_held=(hf["experts_held_first"], hf["num_experts"]),
+        num_experts=hf["published"]["num_experts"], dtype="bfloat16")
+    w = {n: _sds(chip, shape, dt)
+         for n, (shape, _, dt) in tr.param_shapes(c).items()}
+    assert w["expert_gu_w"].shape == (4, 32, 3072, 6144)
+    assert w["router_w"].shape == (4, 3072, 256)
+    st = {n: _sds(chip, tuple(shape), dt)
+          for n, (shape, dt) in real.cache_spec()["step_state"].items()}
+    pools = (_sds(chip, (1, 16385, 16, 2048), jnp.bfloat16),
+             _sds(chip, (4, 12321, 16, 2048), jnp.bfloat16))
+    return real, w, st, pools
+
+
+@pytest.mark.parametrize("entries", [385, 4160], ids=["window", "full"])
+def test_window_decode_attn_compiles(chip, entries):
+    """The banded walk alone: per K/V head a query tile of 6 heads (padded
+    to a sublane tile) over the band of a ring of 385 entries or of a full
+    table of 4,160."""
+    from paddle_tpu.kernels import window_attention as wa
+    S = 32
+    _compile(lambda q, pool, layer, table, pos, lo: wa.window_decode_attn(
+        q, pool, layer, table, pos, lo, 8),
+        _sds(chip, (S, 8, 6, 128), jnp.bfloat16),
+        _sds(chip, (4, 12321, 16, 2048), jnp.bfloat16),
+        _sds(chip, (), jnp.int32), _sds(chip, (S, entries), jnp.int32),
+        _sds(chip, (S,), jnp.int32), _sds(chip, (S,), jnp.int32),
+        kernels=["window_decode_attn"])
+
+
+def test_window_decode_step_updates_both_pools_in_place(chip, monkeypatch):
+    """``TrinityForCausalLM.decode_paged`` at the longmix cell's shape: the
+    banded walk runs every layer (four window layers and the full one),
+    both pools and the expert counts alias through, no stacked weight is
+    copied for the scan, the grouped products are the kernel's."""
+    _experts_on_the_chip(monkeypatch)
+    real, w, st, (pool, wpool) = _trinity(chip)
+    B = 32
+
+    def decode(w, pool, wpool, st, bt, tok, pos, running):
+        return real.decode_paged(w, tok, pos, bt, pool, wpool, st, running,
+                                 kernel="pallas", window_entries=385)
+
+    compiled = jax.jit(decode, donate_argnums=(1, 2, 3)).lower(
+        w, pool, wpool, st, _sds(chip, (B, 4160 + 385), jnp.int32),
+        _sds(chip, (B,), jnp.int32), _sds(chip, (B,), jnp.int32),
+        _sds(chip, (B,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    # the dense window layer, and the period's window, full, window,
+    # window: one walk each
+    assert len(re.findall(r"%window_decode_attn(\.\d+)? = [^\n]*custom-call"
+                          r"\(", text)) == 5
+    _held_products_are_the_kernel(text, layers=4)
+    assert not re.search(r"bf16\[(4|5|32),\d{4,}[\d,]*\]\S* copy\(", text)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= (1 + 4 * 12321 / 16385) * (
+        16385 * 16 * 2048 * 2), mem
+    assert mem.temp_size_in_bytes < 256 * 2 ** 20, mem
+
+
+def test_window_prefill_chunk_compiles_in_place(chip, monkeypatch):
+    """The widest chunk of the longmix cell (2,048 tokens): both pools
+    alias through, the grouped products are the kernel's, and the
+    temporaries leave the weights and pools their 12.9 GB of the chip."""
+    _experts_on_the_chip(monkeypatch)
+    real, w, st, (pool, wpool) = _trinity(chip)
+
+    def chunk(w, pool, wpool, st, ids, start, length, bt, slot):
+        return real.prefill_paged(w, ids, start, length, bt, pool, wpool,
+                                  st, slot, kernel="pallas",
+                                  window_entries=385)
+
+    compiled = jax.jit(chunk, donate_argnums=(1, 2, 3)).lower(
+        w, pool, wpool, st, _sds(chip, (1, 2048), jnp.int32),
+        _sds(chip, (), jnp.int32), _sds(chip, (), jnp.int32),
+        _sds(chip, (4160 + 385,), jnp.int32),
+        _sds(chip, (), jnp.int32)).compile()
+    text = compiled.as_text()
+    _held_products_are_the_kernel(text, layers=4)
+    assert not re.search(r"bf16\[(4|5|32),\d{4,}[\d,]*\]\S* copy\(", text)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= (1 + 4 * 12321 / 16385) * (
+        16385 * 16 * 2048 * 2), mem
+    assert mem.temp_size_in_bytes < 2 * 2 ** 30, mem
