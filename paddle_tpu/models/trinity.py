@@ -53,16 +53,11 @@ from ..core.tensor import Tensor
 from ..kernels import moe as _moe
 from ..kernels import window_attention as _wa
 from ..kernels._shapes import NEG_INF
-from ..kernels.block_attention import _split
 from ..kernels.rms_norm import rms_norm_reference as _rms
 from ..nn.layer.layers import Layer
 from ..profiler import host_tracer as _trace
 
 SLIDING, FULL = "sliding_attention", "full_attention"
-
-#: cached positions a prefill chunk attends to per step of its walk over
-#: the live rows (a whole number of blocks)
-_KEY_TILE = 1024
 
 
 class TrinityConfig:
@@ -505,15 +500,15 @@ class TrinityForCausalLM(Layer):
         ring: logical block ``b`` at entry ``b mod window_entries``), both
         ``[layers, n_blocks, bs, row]``; ``state`` the engine's
         ``step_state`` arrays.  The chunk's rows are written first; its
-        queries then attend over the live rows a tile of keys at a time
-        into an online softmax: a full layer from the first tile, a window
-        layer from the tile of the band's first position.  Returns
-        ``(pool, wpool, state, logits [1, V])`` read at the chunk's last
-        live token.  ``kernel`` is the engine's choice for the decode walk;
-        this fold has one form."""
+        queries then attend over the live rows of their band
+        (``kernels.window_attention``: a full layer from position 0, a
+        window layer from ``W - 1`` before each query); ``kernel="pallas"``
+        (the engine's choice, as for the decode walk) is the Pallas kernel,
+        otherwise the XLA twin.  Returns ``(pool, wpool, state, logits [1,
+        V])`` read at the chunk's last live token."""
         c = self.config
-        B, C = ids.shape
-        N, hd, W = c.num_kv_heads, c.head_dim, c.sliding_window
+        C = ids.shape[1]
+        W = c.sliding_window
         n_w = window_entries
         bs, row = pool.shape[2], pool.shape[3]
         tables = {FULL: bt[:-n_w], SLIDING: bt[-n_w:]}
@@ -523,11 +518,11 @@ class TrinityForCausalLM(Layer):
         off = tokpos % bs
         where = {FULL: jnp.where(valid, tables[FULL][lblk], 0),
                  SLIDING: jnp.where(valid, tables[SLIDING][lblk % n_w], 0)}
-        tile = min(_KEY_TILE, tables[FULL].shape[0] * bs)
-        nb_tile = tile // bs
-        first_tile = {FULL: 0,
-                      SLIDING: jnp.maximum(start - W + 1, 0) // tile}
-        end_tile = (start + length + tile - 1) // tile
+        window = {FULL: None, SLIDING: W}
+        if kernel not in (None, "off", "pallas"):
+            raise ValueError(f"kernel={kernel!r}")
+        fold = (_wa.window_prefill_attn if kernel == "pallas"
+                else _wa.window_prefill_attn_xla)
 
         def attend(carried, i, kind, q, k, v):
             pools = dict(zip((FULL, SLIDING), carried))
@@ -535,39 +530,10 @@ class TrinityForCausalLM(Layer):
             line = jnp.where(valid[:, None], self._line(k[0], v[0], row), 0)
             p = p.at[i, where[kind], off].set(line.astype(p.dtype))
             qg = jnp.moveaxis(self._grouped(q[0]), 0, 2).astype(p.dtype)
-            table = tables[kind]                            # qg [N, G, C, hd]
-
-            def fold(t, st):
-                m, l, acc = st
-                lb = t * nb_tile + jnp.arange(nb_tile)
-                blocks = (table[lb % n_w] if kind == SLIDING
-                          else jnp.take(table, lb, mode="fill",
-                                        fill_value=0))
-                kt, vt = _split(p[i, blocks].reshape(tile, row), N, hd)
-                s = jnp.einsum("ngqd,knd->ngqk", qg, kt,
-                               preferred_element_type=jnp.float32)
-                kpos = t * tile + jnp.arange(tile)
-                seen = kpos[None, :] <= tokpos[:, None]
-                if kind == SLIDING:
-                    seen = seen & (kpos[None, :] > tokpos[:, None] - W)
-                s = jnp.where(seen, s, NEG_INF)
-                m_new = jnp.maximum(m, s.max(-1, keepdims=True))
-                e = jnp.exp(s - m_new)
-                alpha = jnp.exp(m - m_new)
-                acc = acc * alpha + jnp.einsum(
-                    "ngqk,knd->ngqd", e.astype(vt.dtype), vt,
-                    preferred_element_type=jnp.float32)
-                return m_new, l * alpha + e.sum(-1, keepdims=True), acc
-
-            G = c.num_heads // N
-            _, l, acc = jax.lax.fori_loop(
-                first_tile[kind], end_tile, fold,
-                (jnp.full((N, G, C, 1), NEG_INF, jnp.float32),
-                 jnp.zeros((N, G, C, 1), jnp.float32),
-                 jnp.zeros((N, G, C, hd), jnp.float32)))
-            o = jnp.moveaxis(acc / l, 2, 0)                # [C, N, G, hd]
+            o = fold(qg, p, i, tables[kind], start, length, window[kind])
             pools[kind] = p
-            return (o.reshape(1, C, c.num_heads, hd),
+            return (jnp.moveaxis(o, 2, 0).reshape(1, C, c.num_heads,
+                                                  c.head_dim),
                     (pools[FULL], pools[SLIDING]))
 
         h, ((pool, wpool), counts, tokens) = self._layers(

@@ -58,6 +58,11 @@ Well-known names (see README "Observability" for the full table):
       serving/block_decode.py: running rows of each decode launch, one
       pass of a row's block each / blocks committed / masked positions
       revealed; serving.decode_tokens counts tokens EMITTED there)
+  kernels.window_attention.prefill.pallas /
+  kernels.window_attention.prefill.xla (a prefill chunk's attention over
+      its band traced with the Pallas kernel / with the XLA twin, one a
+      traced call; counted where chunk programs are built, a steady state
+      counts nothing)
   serving.kv.window_blocks_recycled (an engine of a model with window
       layers alone: window-ring entries a row took over as it passed the
       window, counted at each chunk and each decode read-back)

@@ -1828,7 +1828,12 @@ def run():
     # (``serving.kv.window_blocks_recycled`` counts the entries taken over
     # as rows pass the window); the measure window must trace nothing, no
     # earlier engine may have registered a ``serving.kv.window_*`` name,
-    # and both pools must be whole again once the requests finish.
+    # and both pools must be whole again once the requests finish.  Which
+    # form a chunk's attention took is counted where the chunk programs
+    # are traced (``kernels.window_attention.prefill.pallas`` / ``.xla``,
+    # one a traced call): here, with no TPU, every one is the XLA twin,
+    # and the measure window traces none; an engine under the interpret
+    # hook traces the Pallas kernel alone and serves the twin's tokens.
     from paddle_tpu.models.trinity import TrinityConfig, TrinityForCausalLM
     if any(k.startswith("serving.kv.window_") for k in counters.snapshot()):
         violations["window:registered_without_window"] = (True, False)
@@ -1840,15 +1845,25 @@ def run():
     wmodel.eval()
     weng = LLMEngine(wmodel, max_slots=2, max_seq_len=32, min_bucket=4,
                      block_size=4, prefill_chunk=8)
+    wtraced = counters.snapshot()
     pserve(weng, (21, 25))
     wbefore = counters.snapshot()
+    wwarm = counters.delta(wtraced, wbefore)
+    if not (wwarm.get("kernels.window_attention.prefill.xla", 0) > 0
+            and wwarm.get("kernels.window_attention.prefill.pallas", 0) == 0):
+        violations["window:prefill_attn_body"] = (
+            (wwarm.get("kernels.window_attention.prefill.xla", 0),
+             wwarm.get("kernels.window_attention.prefill.pallas", 0)),
+            "(>0, 0)")
     pserve(weng, (22, 26))
     wsteady = counters.delta(wbefore)
     # a ring of ceil((8 + 8) / 4) + 1 = 5 entries; the rows write up to
     # positions 23 and 27 (3 new tokens): blocks 0-5 and 0-6, three
     # entries taken over
     want_window = {"serving.retraces": 0, "jit.traces": 0,
-                   "serving.kv.window_blocks_recycled": 3}
+                   "serving.kv.window_blocks_recycled": 3,
+                   "kernels.window_attention.prefill.xla": 0,
+                   "kernels.window_attention.prefill.pallas": 0}
     for k, want in want_window.items():
         if wsteady.get(k, 0) != want:
             violations[f"window:{k}"] = (wsteady.get(k, 0), want)
@@ -1860,6 +1875,32 @@ def run():
             (wst["prefix_cache"], weng.window_entries,
              wst["window_blocks_live"], wst["blocks_free"]),
             f"(False, 5, 0, {wst['blocks_total']})")
+
+    def wserve(eng_):
+        hs = [eng_.add_request(p, max_new_tokens=3)
+              for p in (list(range(3, 24)), list(range(60, 35, -1)))]
+        while not all(h.is_finished for h in hs):
+            eng_.step()
+        return [list(h.tokens) for h in hs]
+
+    wtwin = wserve(weng)
+    _pa._INTERPRET[0] = True
+    try:
+        wkbefore = counters.snapshot()
+        wkernel = wserve(LLMEngine(wmodel, max_slots=2, max_seq_len=32,
+                                   min_bucket=4, block_size=4,
+                                   prefill_chunk=8))
+        wkwarm = counters.delta(wkbefore)
+    finally:
+        _pa._INTERPRET[0] = False
+    if not (wkwarm.get("kernels.window_attention.prefill.pallas", 0) > 0
+            and wkwarm.get("kernels.window_attention.prefill.xla", 0) == 0):
+        violations["window:prefill_attn_kernel"] = (
+            (wkwarm.get("kernels.window_attention.prefill.xla", 0),
+             wkwarm.get("kernels.window_attention.prefill.pallas", 0)),
+            "(0, >0)")
+    if wkernel != wtwin:
+        violations["window:prefill_attn_kernel_tokens"] = (wkernel, wtwin)
 
     result = {"metric": "steady_state_counter_violations",
               "value": len(violations),

@@ -554,6 +554,26 @@ def test_window_decode_attn_compiles(chip, entries):
         kernels=["window_decode_attn"])
 
 
+@pytest.mark.parametrize("C", [2048, 8])
+@pytest.mark.parametrize("entries", [385, 4160], ids=["window", "full"])
+def test_window_prefill_attn_compiles(chip, entries, C):
+    """The chunk's banded attention alone, at the widest and the smallest
+    bucket: 8 K/V heads of 6 query heads of 128, the window layers' ring of
+    385 entries over their pool or the full layer's table of 4,160 over
+    its own."""
+    from paddle_tpu.kernels import window_attention as wa
+    window, layers, blocks = ((4096, 4, 12321) if entries == 385
+                              else (None, 1, 16385))
+    _compile(lambda q, pool, layer, table, start, length:
+             wa.window_prefill_attn(q, pool, layer, table, start, length,
+                                    window),
+             _sds(chip, (8, 6, C, 128), jnp.bfloat16),
+             _sds(chip, (layers, blocks, 16, 2048), jnp.bfloat16),
+             _sds(chip, (), jnp.int32), _sds(chip, (entries,), jnp.int32),
+             _sds(chip, (), jnp.int32), _sds(chip, (), jnp.int32),
+             kernels=["window_prefill_attn"])
+
+
 def test_window_decode_step_updates_both_pools_in_place(chip, monkeypatch):
     """``TrinityForCausalLM.decode_paged`` at the longmix cell's shape: the
     banded walk runs every layer (four window layers and the full one),
@@ -585,9 +605,10 @@ def test_window_decode_step_updates_both_pools_in_place(chip, monkeypatch):
 
 
 def test_window_prefill_chunk_compiles_in_place(chip, monkeypatch):
-    """The widest chunk of the longmix cell (2,048 tokens): both pools
-    alias through, the grouped products are the kernel's, and the
-    temporaries leave the weights and pools their 12.9 GB of the chip."""
+    """The widest chunk of the longmix cell (2,048 tokens): every layer's
+    attention is the banded prefill kernel, both pools alias through, the
+    grouped products are the kernel's, and the temporaries leave the
+    weights and pools their 12.9 GB of the chip."""
     _experts_on_the_chip(monkeypatch)
     real, w, st, (pool, wpool) = _trinity(chip)
 
@@ -602,6 +623,8 @@ def test_window_prefill_chunk_compiles_in_place(chip, monkeypatch):
         _sds(chip, (4160 + 385,), jnp.int32),
         _sds(chip, (), jnp.int32)).compile()
     text = compiled.as_text()
+    assert len(re.findall(r"%window_prefill_attn(\.\d+)? = [^\n]*custom-call"
+                          r"\(", text)) == 5
     _held_products_are_the_kernel(text, layers=4)
     assert not re.search(r"bf16\[(4|5|32),\d{4,}[\d,]*\]\S* copy\(", text)
     mem = compiled.memory_analysis()

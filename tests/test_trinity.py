@@ -300,6 +300,36 @@ def test_prefill_then_decode_is_the_reference_across_the_window(
         np.testing.assert_allclose(logits, want[p], atol=TOL, rtol=TOL)
 
 
+def test_the_prefill_kernel_serves_the_twins_tokens(cfg):
+    """An engine whose chunks attend through the Pallas kernel (interpret
+    mode) serves the greedy tokens an engine through the XLA twin serves:
+    chunks of two bucket widths (16 and a short last one of 8), rings
+    wrapped.  A fresh model, so that both engines trace their programs and
+    count the form they took."""
+    m = _model(cfg)
+    r = np.random.default_rng(6)
+    prompts = [r.integers(0, 512, n).astype(np.int32) for n in (37, 12, 26)]
+
+    def serve(interpret):
+        pa._INTERPRET[0] = interpret
+        try:
+            before = counters.snapshot()
+            eng = _engine(m, prefill_chunk=16)
+            reqs = [eng.add_request(p, max_new_tokens=12) for p in prompts]
+            _drain(eng)
+            return [list(q.tokens) for q in reqs], counters.delta(before)
+        finally:
+            pa._INTERPRET[0] = False
+
+    twin, by_twin = serve(False)
+    kernel, by_kernel = serve(True)
+    assert kernel == twin
+    assert by_twin.get("kernels.window_attention.prefill.xla", 0) > 0
+    assert not by_twin.get("kernels.window_attention.prefill.pallas")
+    assert by_kernel.get("kernels.window_attention.prefill.pallas", 0) > 0
+    assert not by_kernel.get("kernels.window_attention.prefill.xla")
+
+
 def test_served_tokens_are_the_references_first_choices(cfg, model,
                                                         interpret_mode):
     eng = _engine(model)
