@@ -21,7 +21,8 @@ K/V:
   ``beta = 0`` (how the caller marks the padded tail of a bucket) leaves the
   state untouched.
 * :func:`causal_conv` — the depthwise causal convolution in front of the
-  rule, over a chunk and the tail of inputs the previous chunk left.
+  rule (and of a Mamba layer's scan, ``models/jamba.py``, with a bias),
+  over a chunk and the tail of inputs the previous chunk left.
 
 Everything here is float32 at ``HIGHEST``: the triangular solve amplifies
 rounding (``beta`` reaches 2, so ``I + A`` is not diagonally dominant) and
@@ -153,16 +154,19 @@ def gdn_chunk(q, k, v, g, beta, state):
     return o.reshape(B, T, H, V), state
 
 
-def causal_conv(x, w, tail, length=None):
+def causal_conv(x, w, tail, length=None, bias=None):
     """Depthwise causal convolution of ``x [B, T, C]`` with the filter
-    ``w [W, C]`` (``y_t = sum_i w_i x_{t-W+1+i}``), the ``W - 1`` inputs
-    before ``x`` given by ``tail [B, W - 1, C]``.  Returns ``(y, new
-    tail)``: the last ``W - 1`` inputs up to ``length`` (default ``T``),
-    so the padded end of a bucket never reaches the next chunk."""
+    ``w [W, C]`` (``y_t = sum_i w_i x_{t-W+1+i}``, plus ``bias [C]`` where
+    given), the ``W - 1`` inputs before ``x`` given by ``tail [B, W - 1,
+    C]``.  Returns ``(y, new tail)``: the last ``W - 1`` inputs up to
+    ``length`` (default ``T``), so the padded end of a bucket never reaches
+    the next chunk."""
     W = w.shape[0]
     T = x.shape[1]
     xin = jnp.concatenate([tail.astype(x.dtype), x], axis=1)
     y = sum(w[i] * xin[:, i:i + T] for i in range(W))
+    if bias is not None:
+        y = y + bias
     if length is None:
         return y, xin[:, T:]
     return y, jax.lax.dynamic_slice_in_dim(xin, length, W - 1, axis=1)

@@ -63,6 +63,10 @@ Well-known names (see README "Observability" for the full table):
       its band traced with the Pallas kernel / with the XLA twin, one a
       traced call; counted where chunk programs are built, a steady state
       counts nothing)
+  kernels.selective_scan.pallas / kernels.selective_scan.xla (a Mamba
+      layer's selective scan traced with the Pallas kernel / with the
+      XLA twin, one a traced call; counted where programs are built, a
+      steady state counts nothing)
   serving.kv.window_blocks_recycled (an engine of a model with window
       layers alone: window-ring entries a row took over as it passed the
       window, counted at each chunk and each decode read-back)

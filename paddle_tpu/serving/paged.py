@@ -268,6 +268,9 @@ class LLMEngine(_RequestLifecycle):
         self.slot_state = dict(cache["slot_state"])
         self._step_spec = dict(cache.get("step_state", {}))
         self.kv_row = int(cache.get("kv_row", 0))
+        # the ``[k ; v]`` row is read by the banded walk
+        # (``kernels/window_attention.py``) even with no window
+        self._banded = bool(cache.get("banded_walk"))
         # window layers: their rows go to a second pool, a ring a row
         self.window = dict(cache.get("window") or {})
         if self.slot_state or self.kv_row or self.window:
@@ -417,7 +420,7 @@ class LLMEngine(_RequestLifecycle):
         # holds, and baked into the program-cache key.  Pools left
         # replicated on a mesh (indivisible heads) keep the twin: GSPMD
         # partitions it, and cannot partition a Mosaic call
-        if self.window:
+        if self.window or self._banded:
             self.kv_kernel = _wa.kernel_mode(c.head_dim, hd)
         elif self.kv_row:
             self.kv_kernel = _mla.kernel_mode(c.num_heads, hd)

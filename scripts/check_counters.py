@@ -1902,6 +1902,62 @@ def run():
     if wkernel != wtwin:
         violations["window:prefill_attn_kernel_tokens"] = (wkernel, wtwin)
 
+    # ---- selective-scan gate: a Mamba layer's state a slot ---------------
+    # A model with Mamba layers (models/jamba.py) scans each layer's
+    # diagonal state per slot; which form the scan took is counted where
+    # programs are traced (``kernels.selective_scan.pallas`` / ``.xla``,
+    # one a traced call): here, with no TPU, every one is the XLA twin,
+    # the measure window traces none, and an engine under the interpret
+    # hook traces the kernel alone and serves the twin's tokens.
+    from paddle_tpu.models.jamba import JambaConfig, JambaForCausalLM
+    smodel = JambaForCausalLM(JambaConfig(
+        vocab_size=64, hidden_size=32, intermediate_size=32, num_layers=3,
+        num_heads=2, num_kv_heads=1, attn_layer_offset=1,
+        attn_layer_period=3, dt_rank=4, max_seq_len=64))
+    smodel.eval()
+
+    def sserve(eng_):
+        hs = [eng_.add_request(p, max_new_tokens=3)
+              for p in (list(range(3, 24)), list(range(60, 35, -1)))]
+        while not all(h.is_finished for h in hs):
+            eng_.step()
+        return [list(h.tokens) for h in hs]
+
+    def sengine():
+        return LLMEngine(smodel, max_slots=2, max_seq_len=32, min_bucket=4,
+                         block_size=4, prefill_chunk=8)
+
+    straced = counters.snapshot()
+    seng = sengine()
+    stwin = sserve(seng)
+    swarm = counters.delta(straced)
+    sbefore = counters.snapshot()
+    sserve(seng)
+    ssteady = counters.delta(sbefore)
+    if not (swarm.get("kernels.selective_scan.xla", 0) > 0
+            and swarm.get("kernels.selective_scan.pallas", 0) == 0):
+        violations["scan:twin"] = (
+            (swarm.get("kernels.selective_scan.xla", 0),
+             swarm.get("kernels.selective_scan.pallas", 0)), "(>0, 0)")
+    for k in ("serving.retraces", "jit.traces", "kernels.selective_scan.xla",
+              "kernels.selective_scan.pallas"):
+        if ssteady.get(k, 0) != 0:
+            violations[f"scan:{k}"] = (ssteady.get(k, 0), 0)
+    _pa._INTERPRET[0] = True
+    try:
+        skbefore = counters.snapshot()
+        skernel = sserve(sengine())
+        skwarm = counters.delta(skbefore)
+    finally:
+        _pa._INTERPRET[0] = False
+    if not (skwarm.get("kernels.selective_scan.pallas", 0) > 0
+            and skwarm.get("kernels.selective_scan.xla", 0) == 0):
+        violations["scan:kernel"] = (
+            (skwarm.get("kernels.selective_scan.xla", 0),
+             skwarm.get("kernels.selective_scan.pallas", 0)), "(0, >0)")
+    if skernel != stwin:
+        violations["scan:kernel_tokens"] = (skernel, stwin)
+
     result = {"metric": "steady_state_counter_violations",
               "value": len(violations),
               "unit": f"violations/{MEASURE} steps "

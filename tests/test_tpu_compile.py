@@ -631,3 +631,33 @@ def test_window_prefill_chunk_compiles_in_place(chip, monkeypatch):
     assert mem.alias_size_in_bytes >= (1 + 4 * 12321 / 16385) * (
         16385 * 16 * 2048 * 2), mem
     assert mem.temp_size_in_bytes < 2 * 2 ** 30, mem
+
+
+@pytest.mark.parametrize("R,T", [pytest.param(1, 512, id="chunk"),
+                                 pytest.param(128, 1, id="decode")])
+def test_selective_scan_compiles_in_place(chip, R, T):
+    """The selective scan at the chat-burst cell's widths (5,120 channels,
+    a state of 16): a 512-token chunk of one row and a decode step of 128
+    rows, each over the 26-layer, 128-slot state array and the
+    convolution's rows beside it, which alias through: no copy of either,
+    and nothing of their size kept as a temporary."""
+    from paddle_tpu.kernels import selective_scan as ss
+    E, N = 5120, 16
+    f32 = lambda *shape: _sds(chip, shape, jnp.float32)       # noqa: E731
+    i32 = lambda *shape: _sds(chip, shape, jnp.int32)         # noqa: E731
+    args = (f32(R, T, E), f32(R, T, E), f32(R, T, N), f32(R, T, N),
+            f32(N, E), f32(E), f32(26, 128, N, E),
+            _sds(chip, (26, 128) + ss.tail_shape(3, E), jnp.bfloat16),
+            _sds(chip, (R,) + ss.tail_shape(3, E), jnp.bfloat16), i32(),
+            i32(R),
+            _sds(chip, (R,), jnp.bool_), _sds(chip, (R,), jnp.bool_))
+    _compile(ss.selective_scan, *args, kernels=["selective_scan"])
+    compiled = jax.jit(ss.selective_scan, donate_argnums=(6, 7)).lower(
+        *args).compile()
+    assert ss.tail_shape(3, E) == (8, 1920)
+    assert not re.search(r"(f32\[26,128,16,5120|bf16\[26,128,8,1920)\]\S*"
+                         r" copy\(",
+                         compiled.as_text())
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 26 * 128 * (N * 4 + 3 * 2) * E, mem
+    assert mem.temp_size_in_bytes < 64 * 2 ** 20, mem
