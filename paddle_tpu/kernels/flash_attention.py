@@ -281,7 +281,9 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
         dv_ref[0, hh] = dv.astype(dv_ref.dtype)
 
 
-def _flash_bwd(q, k, v, out, lse, do, causal, scale, dlse=None):
+def _flash_bwd(q, k, v, lse, delta, do, causal, scale):
+    """(dq, dk, dv), [b, h, s, d]; ``lse`` and ``delta`` are [b, h, s] fp32
+    and enter the kernels as [b, h, s, 1]."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -289,13 +291,8 @@ def _flash_bwd(q, k, v, out, lse, do, causal, scale, dlse=None):
     bh, block_q, block_k = _pick_blocks(h, s, d, q.dtype.itemsize)
     check_divides("flash_attention_bwd", heads=(h, bh),
                   seq_len_q=(s, block_q), seq_len_k=(s, block_k))
-    delta = jnp.sum(out.astype(jnp.float32) * do.astype(jnp.float32), axis=-1,
-                    keepdims=True)  # [b, h, s, 1] — lane-aligned like lse
-    if dlse is not None:
-        # A cotangent g on lse enters as ds_ij += g_i * p_ij (because
-        # d lse_i / d s_ij = p_ij); the kernels compute ds = p*(dp - delta),
-        # so folding it in as delta' = delta - g gives p*(dp - delta + g).
-        delta = delta - dlse.astype(jnp.float32)[..., None]
+    lse = lse[..., None]
+    delta = delta[..., None]
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
@@ -356,24 +353,55 @@ def _flash_bwd(q, k, v, out, lse, do, causal, scale, dlse=None):
     return dq, dk, dv
 
 
+def _swap(x):
+    """[B, S, H, D] <-> [B, H, S, D]."""
+    return jnp.swapaxes(x, 1, 2)
+
+
+def _saveable(out, lse):
+    """The kernel's output and its log-sum-exp (squeezed to a lane-dense
+    [b, h, s]: kept as [b, h, s, 1] fp32 it would be stored padded to 128
+    lanes) under the names a remat policy keeps them by.  A backward that
+    finds both saved reads them back and does not replay the forward
+    kernel (``models/gpt.py`` ``_sel_policy``)."""
+    from jax.ad_checkpoint import checkpoint_name
+    return (checkpoint_name(out, "flash_out"),
+            checkpoint_name(lse[..., 0], "flash_lse"))
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _flash_attention_bhsd(q, k, v, causal, scale):
-    out, _ = _flash_fwd(q, k, v, causal, scale)
-    return out
+def _flash_bshd(q, k, v, causal, scale):
+    out, _ = _flash_fwd(_swap(q), _swap(k), _swap(v), causal, scale)
+    return _swap(out)
 
 
 def _flash_vjp_fwd(q, k, v, causal, scale):
-    out, lse = _flash_fwd(q, k, v, causal, scale)
-    return out, (q, k, v, out, lse)
+    # The output is named with its heads merged, [B, S, H*D]: no layout
+    # of that pads a lane, where one with D minor stores a head size that
+    # is not whole 128-lane tiles padded (96 as 128).  What the caller
+    # goes on with is a reshape of the named array, so a policy that keeps
+    # "flash_out" keeps this one copy, and nothing downstream (the model's
+    # projection) needs the kernel again.
+    b, s, h, d = q.shape
+    out, lse = _flash_fwd(_swap(q), _swap(k), _swap(v), causal, scale)
+    out, lse = _saveable(_swap(out).reshape(b, s, h * d), lse)
+    return out.reshape(b, s, h, d), (q, k, v, out, lse)
 
 
 def _flash_vjp_bwd(causal, scale, res, do):
     q, k, v, out, lse = res
-    dq, dk, dv = _flash_bwd(q, k, v, out, lse, do, causal, scale)
-    return dq, dk, dv
+    b, s, h, d = q.shape
+    # delta, rowsum(out * dO) over each head, taken in the layout the
+    # output is kept in (heads merged): no relayout of the kept copy
+    prod = (out.astype(jnp.float32)
+            * do.reshape(out.shape).astype(jnp.float32))
+    delta = _swap(prod.reshape(b, s, h, d).sum(-1))
+    dq, dk, dv = _flash_bwd(_swap(q), _swap(k), _swap(v), lse, delta,
+                            _swap(do), causal, scale)
+    return _swap(dq), _swap(dk), _swap(dv)
 
 
-_flash_attention_bhsd.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
+_flash_bshd.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
@@ -383,21 +411,25 @@ def flash_attention_with_lse(q, k, v, causal, scale):
     lse is [B, H, S] fp32.  Used by ring attention (kernels/ring_attention.py)
     whose online-softmax merge needs the per-chunk LSE *and* gradients through
     both outputs — the lse cotangent folds into the flash backward via the
-    delta term (see _flash_bwd)."""
+    delta term (see _flash_lse_vjp_bwd)."""
     out, lse = _flash_fwd(q, k, v, causal, scale)
     return out, lse[..., 0]
 
 
 def _flash_lse_vjp_fwd(q, k, v, causal, scale):
-    out, lse = _flash_fwd(q, k, v, causal, scale)
-    return (out, lse[..., 0]), (q, k, v, out, lse)
+    out, lse = _saveable(*_flash_fwd(q, k, v, causal, scale))
+    return (out, lse), (q, k, v, out, lse)
 
 
 def _flash_lse_vjp_bwd(causal, scale, res, cot):
     do, dlse = cot
     q, k, v, out, lse = res
-    dq, dk, dv = _flash_bwd(q, k, v, out, lse, do, causal, scale, dlse=dlse)
-    return dq, dk, dv
+    # A cotangent g on lse enters as ds_ij += g_i * p_ij (because
+    # d lse_i / d s_ij = p_ij); the kernels compute ds = p*(dp - delta),
+    # so folding it in as delta' = delta - g gives p*(dp - delta + g).
+    delta = (jnp.sum(out.astype(jnp.float32) * do.astype(jnp.float32), -1)
+             - dlse.astype(jnp.float32))
+    return _flash_bwd(q, k, v, lse, delta, do, causal, scale)
 
 
 flash_attention_with_lse.defvjp(_flash_lse_vjp_fwd, _flash_lse_vjp_bwd)
@@ -433,14 +465,6 @@ def flash_attention_fwd(q, k, v, causal=False, scale=None):
         # (inside somebody's shard_map the caller has done the splitting)
         return _sharded_flash(mesh, q, k, v, causal, scale)
     return _flash_bshd(q, k, v, causal, scale)
-
-
-def _flash_bshd(q, k, v, causal, scale):
-    qt = jnp.swapaxes(q, 1, 2)
-    kt = jnp.swapaxes(k, 1, 2)
-    vt = jnp.swapaxes(v, 1, 2)
-    out = _flash_attention_bhsd(qt, kt, vt, causal, scale)
-    return jnp.swapaxes(out, 1, 2)
 
 
 def _sharded_flash(mesh, q, k, v, causal, scale):

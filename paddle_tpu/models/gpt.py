@@ -110,9 +110,13 @@ class GPTConfig:
 
 def _sel_policy(mode):
     """Remat policy for selective recompute: which checkpoint_name'd
-    activations survive to backward (the rest replay)."""
-    names = (("qkv", "attn_out") if mode == "selective_lean"
-             else ("qkv", "attn_out", "ffn_up"))
+    activations survive to backward (the rest replay).  The attention
+    output is kept once: as the flash kernel's own "flash_out", beside its
+    log-sum-exp "flash_lse", so the backward does not replay the kernel;
+    as "attn_out" where ring or reference attention produced it."""
+    names = ("qkv", "attn_out", "flash_out", "flash_lse")
+    if mode != "selective_lean":
+        names += ("ffn_up",)
     return jax.checkpoint_policies.save_only_these_names(*names)
 
 
@@ -281,14 +285,20 @@ class GPTForCausalLM(Layer):
                 from ..kernels.rope import apply_rope
                 q = apply_rope(q)
                 k = apply_rope(k)
-            if c.context_parallel and hybrid_degrees().get("sep", 1) > 1:
-                from ..kernels.ring_attention import ring_attention
-                o = ring_attention(q, k, v, causal=True)
-            elif use_flash:
+            ring = c.context_parallel and hybrid_degrees().get("sep", 1) > 1
+            if use_flash and not ring:
+                # the kernel names its own output ("flash_out") and lse:
+                # kept by the policy, they spare the backward a replay of
+                # the kernel, and the output is not kept again as attn_out
                 o = flash_attention_fwd(q, k, v, causal=True)
+                o = o.reshape(b, s, H)
             else:
-                o = reference_attention(q, k, v, causal=True)
-            o = checkpoint_name(o.reshape(b, s, H), "attn_out")
+                if ring:
+                    from ..kernels.ring_attention import ring_attention
+                    o = ring_attention(q, k, v, causal=True)
+                else:
+                    o = reference_attention(q, k, v, causal=True)
+                o = checkpoint_name(o.reshape(b, s, H), "attn_out")
             return jnp.matmul(o, lw["proj_w"], precision=matmul_precision()) \
                 + lw["proj_b"]
 
@@ -439,12 +449,12 @@ class GPTForCausalLM(Layer):
                 scan_body = body
                 if c.recompute in ("selective", "selective_lean"):
                     # Megatron-style selective recompute (reference:
-                    # fleet/recompute 'full' vs refined recompute): save only
-                    # the expensive matmul outputs; ln/gelu/flash replay in
-                    # bwd.  ~6% extra FLOPs for ~85% of full-remat's memory
-                    # saving.  'selective_lean' also drops the 4H-wide
-                    # ffn_up (halves saved bytes; fc1 replays in bwd,
-                    # ~+4% step FLOPs) — it buys a bigger batch at 760M+.
+                    # fleet/recompute 'full' vs refined recompute): save the
+                    # expensive matmul outputs and flash's output and lse;
+                    # ln/gelu and the output projection replay in bwd, the
+                    # flash forward does not.  'selective_lean' also drops
+                    # the 4H-wide ffn_up (fc1 replays in bwd, ~+4% step
+                    # FLOPs) — it buys a bigger batch at 760M+.
                     scan_body = jax.checkpoint(
                         body, policy=_sel_policy(c.recompute))
                 elif c.recompute:

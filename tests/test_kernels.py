@@ -76,14 +76,14 @@ class TestFlashAttentionTPULowering:
     def test_kernel_lowers_for_tpu(self):
         import jax
         import jax.numpy as jnp
-        from paddle_tpu.kernels.flash_attention import _flash_attention_bhsd
+        from paddle_tpu.kernels.flash_attention import _flash_bshd
 
-        b, h, s, d = 2, 12, 1024, 64
-        q = jax.ShapeDtypeStruct((b, h, s, d), jnp.bfloat16)
+        b, s, h, d = 2, 1024, 12, 64
+        q = jax.ShapeDtypeStruct((b, s, h, d), jnp.bfloat16)
 
         def fwd_bwd(q, k, v):
             out, vjp = jax.vjp(
-                lambda q, k, v: _flash_attention_bhsd(q, k, v, True, 0.125),
+                lambda q, k, v: _flash_bshd(q, k, v, True, 0.125),
                 q, k, v)
             return out, vjp(out)
 

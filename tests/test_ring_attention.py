@@ -106,6 +106,43 @@ class TestRingAttention:
             assert np.allclose(np.asarray(a), np.asarray(b), atol=5e-2), \
                 (n, np.abs(np.asarray(a) - np.asarray(b)).max())
 
+    def test_kept_flash_residuals_give_the_replays_grads(self, mesh_sep4):
+        """Each ring step's flash call names its output and log-sum-exp
+        (``flash_out``, ``flash_lse``).  Under a policy that keeps them the
+        backward reads them back; its gradients are the replay's (nothing
+        kept, the kernels run again) to float32 rounding."""
+        import jax
+        import jax.numpy as jnp
+        from paddle_tpu.distributed import get_mesh
+        from paddle_tpu.kernels import flash_attention as fa
+        from paddle_tpu.kernels.ring_attention import ring_attention
+
+        rng = np.random.default_rng(5)
+        B, S, H, D = 1, 512, 2, 64
+        q, k, v = (jnp.asarray(rng.normal(size=(B, S, H, D)), jnp.float32)
+                   for _ in range(3))
+        mesh = get_mesh()
+
+        def loss(q, k, v):
+            o = ring_attention(q, k, v, causal=True, mesh=mesh)
+            return jnp.sum(o.astype(jnp.float32) ** 2)
+
+        pol = jax.checkpoint_policies
+        keep = pol.save_only_these_names("flash_out", "flash_lse")
+        grads = []
+        fa._INTERPRET[0] = True
+        try:
+            with mesh:
+                for policy in (keep, pol.nothing_saveable):
+                    grads.append(jax.jit(jax.grad(
+                        jax.checkpoint(loss, policy=policy),
+                        argnums=(0, 1, 2)))(q, k, v))
+        finally:
+            fa._INTERPRET[0] = False
+        for a, b, n in zip(*grads, "qkv"):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-6, atol=1e-6, err_msg=n)
+
     def test_gpt_context_parallel_trains(self, mesh_sep4):
         """GPT with context_parallel=True trains on a sep=4 mesh."""
         from paddle_tpu.distributed import DistributedTrainStep

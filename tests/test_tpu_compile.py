@@ -86,21 +86,74 @@ _FLASH_SHAPES = [pytest.param(8, 1024, 16, 96, id="gpt760m"),
 
 @pytest.mark.parametrize("b,s,h,d", _FLASH_SHAPES)
 def test_flash_fwd_compiles(chip, b, s, h, d):
-    x = _sds(chip, (b, h, s, d), jnp.bfloat16)
-    _compile(lambda q, k, v: fa._flash_attention_bhsd(
+    x = _sds(chip, (b, s, h, d), jnp.bfloat16)
+    _compile(lambda q, k, v: fa._flash_bshd(
         q, k, v, True, d ** -0.5), x, x, x, kernels=["flash_fwd"])
 
 
 @pytest.mark.parametrize("b,s,h,d", _FLASH_SHAPES)
 def test_flash_fwd_bwd_compiles(chip, b, s, h, d):
-    x = _sds(chip, (b, h, s, d), jnp.bfloat16)
+    x = _sds(chip, (b, s, h, d), jnp.bfloat16)
 
     def loss(q, k, v):
-        out = fa._flash_attention_bhsd(q, k, v, True, d ** -0.5)
+        out = fa._flash_bshd(q, k, v, True, d ** -0.5)
         return jnp.sum(out.astype(jnp.float32))
 
     _compile(jax.grad(loss, argnums=(0, 1, 2)), x, x, x,
              kernels=["flash_fwd", "flash_dkv", "flash_dq"])
+
+
+def _gpt_block_step(chip, monkeypatch, L, B, S, policy=None):
+    """``jax.grad`` of an ``L``-layer GPT at GPT-760M's width (16 heads of
+    96) under ``selective_lean``, ``B`` x ``S`` tokens, compiled for the
+    chip; ``policy`` stands in for ``gpt._sel_policy``."""
+    import paddle_tpu as paddle
+    from paddle_tpu.core.tensor import Tensor
+    from paddle_tpu.jit import bind_layer_state, layer_state
+    from paddle_tpu.models import GPTConfig, GPTForCausalLM, gpt
+    monkeypatch.setattr(fa, "on_tpu", lambda: True)
+    if policy is not None:
+        monkeypatch.setattr(gpt, "_sel_policy", policy)
+    model = GPTForCausalLM(GPTConfig(
+        vocab_size=1024, hidden_size=1536, num_layers=L, num_heads=16,
+        max_seq_len=S, use_flash_attention=True, recompute="selective_lean",
+        dtype="bfloat16"))
+    params = layer_state(model)[0]
+
+    def loss(p, ids):
+        bind_layer_state(model, p, {})
+        try:
+            with paddle.no_grad():
+                logits = model(Tensor._wrap(ids))._data
+        finally:
+            bind_layer_state(model, params, {})
+        return jnp.mean(logits.astype(jnp.float32))
+
+    shapes = {k: _sds(chip, v.shape, v.dtype) for k, v in params.items()}
+    return jax.jit(jax.grad(loss)).lower(
+        shapes, _sds(chip, (B, S), jnp.int32)).compile()
+
+
+def test_gpt760m_block_step_runs_the_flash_forward_once(chip, monkeypatch):
+    """Under ``selective_lean`` the backward reads the flash forward's kept
+    output and log-sum-exp: one ``%flash_fwd`` Mosaic call, where the
+    replay (the policy without the flash names) has two.  The peak grows
+    by the kept residuals and no more: per layer the output with heads
+    merged (no padded lane) and the lane-dense fp32 log-sum-exp."""
+    L, B, S, H, D = 2, 4, 2048, 16, 96
+    fwd = (r"%flash_fwd(\.\d+)? = [^\n]*custom-call\("
+           r'[^\n]*custom_call_target="tpu_custom_call"')
+    kept = _gpt_block_step(chip, monkeypatch, L, B, S)
+    assert len(re.findall(fwd, kept.as_text())) == 1
+    replay = _gpt_block_step(
+        chip, monkeypatch, L, B, S, lambda mode:
+        jax.checkpoint_policies.save_only_these_names("qkv", "attn_out"))
+    assert len(re.findall(fwd, replay.as_text())) == 2
+    residuals = L * (B * S * H * D * 2 + B * H * S * 4)
+    grew = (kept.memory_analysis().peak_memory_in_bytes
+            - replay.memory_analysis().peak_memory_in_bytes)
+    # a few small bookkeeping buffers move by a KiB between the programs
+    assert grew <= residuals + 4096, (grew, residuals)
 
 
 # (rows, blocks a row, blocks, layers, heads, head_dim): the chat cell's
